@@ -31,7 +31,7 @@ apply_platform_overrides()
 import jax
 import jax.numpy as jnp
 import numpy as np
-from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from pytorch_distributed_rnn_tpu.models import ToyModel

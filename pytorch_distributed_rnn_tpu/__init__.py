@@ -11,12 +11,3 @@ reference capability they re-implement and the TPU-native design chosen.
 """
 
 __version__ = "0.1.0"
-
-# Version-compatibility shims (jax 0.4.x spellings of the >=0.9 API the
-# framework is written against) apply on any package import.  Guarded:
-# jax-free tools in the package (the AST linter) stay importable in
-# lint-only environments.
-try:
-    from pytorch_distributed_rnn_tpu.utils import compat as _compat  # noqa: F401
-except ImportError:  # pragma: no cover - jax-less lint environment
-    pass

@@ -21,7 +21,18 @@ from pytorch_distributed_rnn_tpu.evaluation.analysis import (
     parse_perf_lines,
     scaling_table,
 )
-from pytorch_distributed_rnn_tpu.evaluation.plots import plot_scaling
+
+
+def __getattr__(name):
+    # matplotlib loads (and writes its font cache under the home
+    # directory) only when a plot is asked for: the trainers import
+    # evaluation.collectives on every --metrics run
+    if name == "plot_scaling":
+        from pytorch_distributed_rnn_tpu.evaluation.plots import plot_scaling
+
+        return plot_scaling
+    raise AttributeError(name)
+
 
 __all__ = [
     "PERF_LINE_RE",

@@ -27,7 +27,8 @@ PERF_LINE_RE = re.compile(
 
 TRAIN_SIZE_RE = re.compile(r"Training set of size (\d+)")
 
-# The benchmark workload's training-set size (BASELINE.md): used to derive
+# The benchmark workload's training-set size (the reference's 7352
+# windows after its x96 truncation): used to derive
 # seq/s when a run's log does not state its dataset size.
 DEFAULT_NUM_SEQUENCES = 6912
 
@@ -167,7 +168,7 @@ def create_measurement_df(results) -> pd.DataFrame:
 
 def aggregate_measurements(df: pd.DataFrame) -> pd.DataFrame:
     """Mean over repeats of rank-0 rows, grouped by run configuration —
-    the number the reference reported (rank 0's line, BASELINE.md)."""
+    the number the reference reported (rank 0's line)."""
     if df.empty:
         return df
     rank0 = df[df["rank"] == 0]
@@ -189,8 +190,8 @@ def aggregate_measurements(df: pd.DataFrame) -> pd.DataFrame:
 def scaling_table(df: pd.DataFrame, baseline_trainer: str = "local") -> pd.DataFrame:
     """Scaling study: speedup and efficiency vs the 1-device baseline.
 
-    Mirrors the derived figures in BASELINE.md ("DDP scaling efficiency
-    1→8 nodes"): for each (trainer, batch_size), speedup = t_baseline / t_N
+    The reference's derived figures ("DDP scaling efficiency 1→8
+    nodes"): for each (trainer, batch_size), speedup = t_baseline / t_N
     and efficiency = speedup / N.  The baseline is the ``local`` trainer at
     the same batch size when present, else the trainer's own 1-device row.
     """

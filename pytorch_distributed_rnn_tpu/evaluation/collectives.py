@@ -2,8 +2,8 @@
 model, measured from the COMPILED programs instead of wall-clock.
 
 One chip (or a virtual CPU mesh) cannot measure scaling wall-clock - 8
-virtual devices share the same host cores, so the r2 "scaling study" had no
-scaling signal (VERDICT.md weak #3).  What the compiled program DOES pin
+virtual devices share the same host cores, so a "scaling study" there has
+no scaling signal.  What the compiled program DOES pin
 down exactly, on any backend, is how many bytes each training step moves
 through each collective: XLA's post-optimization HLO carries every
 ``all-reduce`` / ``all-gather`` / ``reduce-scatter`` /

@@ -163,12 +163,21 @@ def main(argv=None):
     if args.task == "collective-report":
         import json
 
-        # probe-first like bench.py (commit 8e3b014): a hung ambient
-        # plugin must fall back to a virtual CPU mesh, and a plain host
-        # needs the device count provisioned before first backend use
-        from pytorch_distributed_rnn_tpu.utils import ensure_usable_backend
+        # the report needs args.devices devices: on a plain host ask
+        # for the virtual CPU mesh (PDRNN_PLATFORM=cpu
+        # PDRNN_NUM_CPU_DEVICES=N); the platform is never switched here
+        from pytorch_distributed_rnn_tpu.utils import (
+            apply_platform_overrides,
+        )
 
-        ensure_usable_backend(min_devices=args.devices)
+        jax = apply_platform_overrides()
+        if len(jax.devices()) < args.devices:
+            raise SystemExit(
+                f"collective-report needs {args.devices} devices; "
+                f"{jax.default_backend()!r} has {len(jax.devices())} - "
+                f"for the virtual CPU mesh set PDRNN_PLATFORM=cpu "
+                f"PDRNN_NUM_CPU_DEVICES={args.devices}"
+            )
 
         from pytorch_distributed_rnn_tpu.evaluation.collectives import (
             report_programs,

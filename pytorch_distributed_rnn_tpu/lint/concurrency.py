@@ -224,12 +224,16 @@ def _methods(node: ast.ClassDef) -> list[ast.FunctionDef]:
 def _method_holds(mod: ModuleInfo, cls: ClassLocks,
                   method: ast.FunctionDef) -> frozenset[str]:
     """Locks the method's CALLER holds by contract: a ``# holds: lock``
-    trailing comment on the ``def`` line, or the ``_locked`` name
-    suffix (held for every class lock)."""
+    trailing comment on the ``def`` line (any line of a multi-line
+    signature), or the ``_locked`` name suffix (held for every class
+    lock)."""
     names: set[str] = set()
-    m = _HOLDS_RE.search(mod.line_text(method.lineno))
-    if m:
-        names = {a.strip() for a in m.group(1).split(",") if a.strip()}
+    last = max(method.lineno, method.body[0].lineno - 1)
+    for lineno in range(method.lineno, last + 1):
+        m = _HOLDS_RE.search(mod.line_text(lineno))
+        if m:
+            names |= {a.strip() for a in m.group(1).split(",")
+                      if a.strip()}
     if method.name.endswith("_locked"):
         names |= set(cls.locks)
     held: set[str] = set()

@@ -200,26 +200,25 @@ class TracedEntry:
 
     def source_of(self, eqn) -> tuple[str, int]:
         """(repo-relative path, line) of the best user frame for this
-        equation; falls back to the entry's declared file when the
-        provenance API is unavailable or every frame is library code."""
+        equation; falls back to the entry's declared file when every
+        frame is library code."""
         key = id(eqn)
         if key in self._sources:
             return self._sources[key]
         path, line = self.entry.path, 1
-        try:  # private API: degrade to entry-anchored findings if moved
-            from jax._src import source_info_util
+        # private API, spelled for the pinned jax (requirements.txt)
+        from jax._src import source_info_util
 
-            for frame in source_info_util.user_frames(eqn.source_info):
-                frame_path = Path(frame.file_name)
-                try:
-                    rel = frame_path.resolve().relative_to(
-                        self.root.resolve()).as_posix()
-                except (ValueError, OSError):
-                    continue
-                path, line = rel, int(frame.start_line)
-                break
-        except Exception:
-            pass
+        for frame in source_info_util.user_frames(
+                eqn.source_info.traceback):
+            frame_path = Path(frame.file_name)
+            try:
+                rel = frame_path.resolve().relative_to(
+                    self.root.resolve()).as_posix()
+            except (ValueError, OSError):
+                continue
+            path, line = rel, int(frame.start_line)
+            break
         self._sources[key] = (path, line)
         return path, line
 
@@ -316,7 +315,7 @@ class _Slicer:
 
 
 def _var_class(jaxpr):
-    from jax.core import Var
+    from jax.extend.core import Var
 
     return Var
 
@@ -388,7 +387,7 @@ def _check_gspmd_reduction(traced: TracedEntry) -> Iterator[Finding]:
             sharding = eqn.params.get("sharding")
             if _sharding_mentions(sharding, axis):
                 return
-        elif eqn.primitive.name == "pjit":
+        elif eqn.primitive.name == "jit":  # jax 0.9's name for pjit
             shardings = tuple(eqn.params.get("in_shardings") or ()) + tuple(
                 eqn.params.get("out_shardings") or ())
             if any(_sharding_mentions(s, axis) for s in shardings):

@@ -214,9 +214,8 @@ def build_parser() -> argparse.ArgumentParser:
         "--fuse-run", action="store_true",
         help="compile the whole multi-epoch training run into ONE device "
         "program (lax.scan over epochs) even with INFO logging on; "
-        "removes every per-epoch host round-trip (dominant on a "
-        "remote-attached chip) at the cost of per-epoch Start-Epoch "
-        "messages.  Needs --no-validation, no --checkpoint-every and "
+        "removes every per-epoch host round-trip at the cost of "
+        "per-epoch Start-Epoch messages.  Needs --no-validation, no --checkpoint-every and "
         "--grad-accum 1; rejected loudly otherwise",
     )
     parser.add_argument(
@@ -229,8 +228,8 @@ def build_parser() -> argparse.ArgumentParser:
         "--profile-steps", default=None, metavar="A:B",
         help="bound the --profile capture to optimizer steps [A, B) "
         "instead of tracing the whole run (steady-state steps without "
-        "the compile/warm-up noise); skipped gracefully on backends "
-        "without profiler support",
+        "the compile/warm-up noise); a profiler that cannot start is "
+        "skipped with a warning on the CPU and an error on an accelerator",
     )
     parser.add_argument(
         "--metrics", default=None, type=Path, metavar="PATH",

@@ -1,7 +1,8 @@
 """Character-level RNN language model: the LM stress family.
 
-BASELINE.json's stress configs name a toy char-RNN and a "stacked-LSTM
-language model 50M params (stress XLA scan + grad psum)"; the reference
+The stress configurations this build set itself name a toy char-RNN and
+a "stacked-LSTM language model 50M params (stress XLA scan + grad
+psum)"; the reference
 itself only ships the motion classifier (`/root/reference/src/motion/
 model.py:4-17`), so this family is the framework's coverage of the
 sequence-to-sequence-logits shape: embedding -> stacked LSTM/GRU (the same
@@ -145,7 +146,7 @@ class CharRNN:
 
 def char_rnn_50m(impl: str = "auto", precision: str = "f32",
                  remat: bool = False, unroll: int = 1) -> CharRNN:
-    """The BASELINE.json stress config: ~50M-param stacked-LSTM LM
+    """The LM stress configuration: ~50M-param stacked-LSTM LM
     (vocab 256, embed 512, 4 x 1280 hidden -> 49.9M params).
     ``precision="bf16"`` / ``remat=True`` are the intended levers for
     running this preset at depth on real hardware; ``unroll`` feeds the

@@ -277,7 +277,11 @@ def ledger_events(events: list[dict], path=None, peak: dict | None = None,
     peak_estimated = run_ledger.get("peak_flops_estimated")
     peak_device = run_ledger.get("device_kind")
     if flops_per_step is not None and wall_s > 0 and span_steps:
-        if peak_total is None:
+        # a run that RECORDED its peak is priced by it even when the
+        # record says "no peak" (a device off the utils/hw.py table);
+        # only sidecars without the block fall back to the reader's
+        # own hardware
+        if "peak_flops_total" not in run_ledger:
             if peak is None:
                 from pytorch_distributed_rnn_tpu.utils.hw import (
                     local_peak_flops,
@@ -287,14 +291,16 @@ def ledger_events(events: list[dict], path=None, peak: dict | None = None,
             peak_total = peak["peak_flops_total"]
             peak_estimated = peak["estimated"]
             peak_device = peak.get("device")
-        steps_advanced = max(0, span_steps - nan_total)
-        # the traced jaxpr counts EXECUTED flops (an HFU numerator);
-        # with no rematerialization in the tree it is also the model
-        # flop count, so the two utilizations coincide here
-        hfu_est = (
-            float(flops_per_step) * steps_advanced / (wall_s * peak_total)
-        )
-        mfu_est = hfu_est
+        if peak_total:
+            steps_advanced = max(0, span_steps - nan_total)
+            # the traced jaxpr counts EXECUTED flops (an HFU numerator);
+            # with no rematerialization in the tree it is also the model
+            # flop count, so the two utilizations coincide here
+            hfu_est = (
+                float(flops_per_step) * steps_advanced
+                / (wall_s * peak_total)
+            )
+            mfu_est = hfu_est
 
     return {
         "path": str(path) if path is not None else None,

@@ -122,11 +122,20 @@ actor_reconnect     worker_id, attempts, seq, version - an actor
                     with no push since as recovering, not stalled
 learner_summary     updates, final_version, rejoins + ingest counters
                     - the streaming learner's verdict line
+compile_fallback    batch_size, grad_accum_from, grad_accum_to, error -
+                    the trainer retried a train step the compiler
+                    refused at a smaller microbatch (training/base.py)
 run_summary         memory_mb, duration_s, device_peaks_mb, steps,
                     nan_skipped, faults_fired, ledger (the trainer's
                     efficiency block: model_flops_per_step, backend,
-                    device_kind/count, peak_flops_total,
-                    peak_flops_estimated - see obs/ledger.py); the
+                    device_kind/count, peak_flops_total - None for an
+                    accelerator off the utils/hw.py table -,
+                    peak_flops_estimated - see obs/ledger.py),
+                    grad_accum (what the run finished with), impl
+                    (requested / resolved / pallas_interpret), layout
+                    (SPMD: devices + shard shapes of batch, params,
+                    opt_state), compile_cache (dir, requests, hits,
+                    writes); the
                     PS master's variant
                     carries roster counts + rejoins + degraded_rounds;
                     the streaming learner's adds experience_batches,

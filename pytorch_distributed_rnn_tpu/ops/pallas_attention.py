@@ -50,11 +50,11 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pytorch_distributed_rnn_tpu.utils.compat import (
-    pallas_tpu_compiler_params as _compiler_params,
-)
 from pytorch_distributed_rnn_tpu.ops.pallas_rnn import (
+    _MATMUL_NT,
+    _MATMUL_TN,
     _interpret,
+    _mxu_dot,
     _round_up,
 )
 
@@ -127,10 +127,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _():
         q = q_ref[0]
         k = k_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+        s = _mxu_dot(q, k, _MATMUL_NT) * scale
         mask = _block_mask(qi, ki, q_off, k_off, block_q=block_q,
                            block_k=block_k, t_q=t_q, t_k=t_k, causal=causal)
         if mask is not None:
@@ -146,10 +143,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         corr = jnp.exp(m_prev - m_new)
         corr = jnp.where(jnp.isfinite(corr), corr, 0.0)
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc_scr[:] * corr + jax.lax.dot(
-            p.astype(v_ref.dtype), v_ref[0],
-            preferred_element_type=jnp.float32,
-        )
+        acc = acc_scr[:] * corr + _mxu_dot(p, v_ref[0])
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
         acc_scr[:] = acc
@@ -202,7 +196,7 @@ def _fwd_impl(q, k, v, offsets, causal, block_q, block_k, t_q, t_k):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
@@ -217,10 +211,7 @@ def _fwd_impl(q, k, v, offsets, causal, block_q, block_k, t_q, t_k):
 def _recompute_p(q, k, lse, mask, scale):
     """p = exp(s - lse) with masked entries (and their inf/nan fallout
     from padded rows' lse = -inf) scrubbed to zero."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    s = _mxu_dot(q, k, _MATMUL_NT) * scale
     p = jnp.exp(s - lse)
     if mask is not None:
         p = jnp.where(mask, p, 0.0)
@@ -248,15 +239,9 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         mask = _block_mask(qi, ki, q_off, k_off, block_q=block_q,
                            block_k=block_k, t_q=t_q, t_k=t_k, causal=causal)
         p = _recompute_p(q_ref[0], k_ref[0], lse_ref[0][:, :1], mask, scale)
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dp = _mxu_dot(do_ref[0], v_ref[0], _MATMUL_NT)
         ds = p * (dp - delta_ref[0][:, :1]) * scale
-        dq_scr[:] += jax.lax.dot(
-            ds.astype(k_ref.dtype), k_ref[0],
-            preferred_element_type=jnp.float32,
-        )
+        dq_scr[:] += _mxu_dot(ds, k_ref[0])
 
     @pl.when(ki == nk - 1)
     def _():
@@ -287,19 +272,10 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         p = _recompute_p(q_ref[0], k_ref[0], lse_ref[0][:, :1], mask, scale)
         do = do_ref[0]
         # dv += p^T @ do; dk += ds^T @ q - contract the block_q dim (0)
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dv_scr[:] += _mxu_dot(p, do, _MATMUL_TN)
+        dp = _mxu_dot(do, v_ref[0], _MATMUL_NT)
         ds = p * (dp - delta_ref[0][:, :1]) * scale
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dk_scr[:] += _mxu_dot(ds, q_ref[0], _MATMUL_TN)
 
     @pl.when(qi == nq - 1)
     def _():
@@ -326,7 +302,7 @@ def _bwd_impl(q, k, v, do, lse, delta, offsets, causal, block_q, block_k,
         out_specs=[q_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
@@ -347,7 +323,7 @@ def _bwd_impl(q, k, v, do, lse, delta, offsets, causal, block_q, block_k,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),

@@ -39,12 +39,40 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _interpret() -> bool:
-    """Pallas interpret mode off-TPU so the CPU test mesh runs the kernels."""
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on the CPU only, so the CPU test mesh runs
+    the kernels.  Every other backend compiles them or fails: a kernel
+    that silently ran interpreted on an accelerator would pass every
+    check at a fraction of the speed."""
+    return jax.default_backend() == "cpu"
 
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+_MATMUL = (((1,), (0,)), ((), ()))       # a @ b
+_MATMUL_NT = (((1,), (1,)), ((), ()))    # a @ b.T
+_MATMUL_TN = (((0,), (0,)), ((), ()))    # a.T @ b
+
+
+def _mxu_dot(a, b, dims=_MATMUL):
+    """In-kernel MXU matmul in ``b``'s dtype with f32 accumulation.
+
+    ``a`` (often an f32 carry or cotangent) is cast to ``b``'s dtype
+    first - the scan path's mixed-precision contract
+    (``ops/rnn.py:lstm_step``), and Mosaic has no mixed f32 x bf16
+    matmul.  For sub-f32 operands the ambient
+    ``jax_default_matmul_precision`` is overridden with DEFAULT: under
+    "highest" Pallas asks Mosaic for fp32 contract precision, which it
+    refuses for bf16 inputs ("Bad rhs type", measured on the v5e) and
+    which could not add precision to a bf16 x bf16 product anyway.
+    """
+    precision = (None if b.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot_general(
+        a.astype(b.dtype), b, dims, precision=precision,
+        preferred_element_type=jnp.float32,
+    )
 
 
 # Mosaic's default per-kernel scoped-VMEM budget is 16MB.  The backward
@@ -108,9 +136,7 @@ def _lstm_fwd_kernel(x_proj_ref, h0_ref, c0_ref, w_hh_t_ref,
 
     h = h_scr[:]
     c = c_scr[:]
-    gates = x_proj_ref[0] + jnp.dot(
-        h, w_hh_t_ref[:], preferred_element_type=jnp.float32
-    )
+    gates = x_proj_ref[0] + _mxu_dot(h, w_hh_t_ref[:])
     i, f, g, o = jnp.split(gates, 4, axis=-1)
     i = jax.nn.sigmoid(i)
     f = jax.nn.sigmoid(f)
@@ -188,9 +214,7 @@ def _lstm_bwd_kernel(x_proj_ref, h_prev_ref, c_prev_ref, c_t_ref,
     c_prev = jnp.where(tt_is_last, c0_ref[:], c_prev_ref[0])
 
     # Recompute the gates for this step (cheaper than saving 4H activations).
-    gates = x_proj_ref[0] + jnp.dot(
-        h_prev, w_hh_t_ref[:], preferred_element_type=jnp.float32
-    )
+    gates = x_proj_ref[0] + _mxu_dot(h_prev, w_hh_t_ref[:])
     i, f, g, o = jnp.split(gates, 4, axis=-1)
     i = jax.nn.sigmoid(i)
     f = jax.nn.sigmoid(f)
@@ -224,10 +248,7 @@ def _lstm_bwd_kernel(x_proj_ref, h_prev_ref, c_prev_ref, c_t_ref,
     # VMEM.  Shipping a second pre-transposed (4H, H) copy doubled the
     # resident weight footprint (both blocks double-buffered: 16MB at
     # H=512 f32) and overflowed the 16MB scoped-VMEM limit on real v5e.
-    dh_prev = jax.lax.dot_general(
-        d_gates, w_hh_t_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    dh_prev = _mxu_dot(d_gates, w_hh_t_ref[:], _MATMUL_NT)
     dc_prev = dc * f
     dh_scr[:] = dh_prev
     dc_scr[:] = dc_prev
@@ -384,9 +405,7 @@ def _gru_fwd_kernel(x_proj_ref, h0_ref, w_hh_t_ref, b_hh_ref, h_all_ref,
         h_scr[:] = h0_ref[:].astype(jnp.float32)
 
     h = h_scr[:]
-    h_proj = jnp.dot(
-        h, w_hh_t_ref[:], preferred_element_type=jnp.float32
-    ) + b_hh_ref[:]
+    h_proj = _mxu_dot(h, w_hh_t_ref[:]) + b_hh_ref[:]
     xr, xz, xn = jnp.split(x_proj_ref[0], 3, axis=-1)
     hr, hz, hn = jnp.split(h_proj, 3, axis=-1)
     r = jax.nn.sigmoid(xr + hr)
@@ -439,9 +458,7 @@ def _gru_bwd_kernel(x_proj_ref, h_prev_ref, dh_all_ref, dh_T_ref,
         jnp.float32
     )
     # recompute this step's gates (cheaper than saving 3H activations)
-    h_proj = jnp.dot(
-        h_prev, w_hh_t_ref[:], preferred_element_type=jnp.float32
-    ) + b_hh_ref[:]
+    h_proj = _mxu_dot(h_prev, w_hh_t_ref[:]) + b_hh_ref[:]
     xr, xz, xn = jnp.split(x_proj_ref[0], 3, axis=-1)
     hr, hz, hn = jnp.split(h_proj, 3, axis=-1)
     r = jax.nn.sigmoid(xr + hr)
@@ -463,10 +480,7 @@ def _gru_bwd_kernel(x_proj_ref, h_prev_ref, dh_all_ref, dh_T_ref,
 
     # d_hgates @ w_hh_t^T via transposed contraction dims - one resident
     # weight array instead of two (see the LSTM backward note)
-    dh_prev = dh * z + jax.lax.dot_general(
-        d_hgates, w_hh_t_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    dh_prev = dh * z + _mxu_dot(d_hgates, w_hh_t_ref[:], _MATMUL_NT)
     dh_scr[:] = dh_prev
 
     @pl.when(tt_is_last)

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import optax
 from jax.sharding import PartitionSpec as P
-from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from pytorch_distributed_rnn_tpu.parallel.collectives import (
     broadcast_from,

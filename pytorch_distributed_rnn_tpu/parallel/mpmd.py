@@ -551,8 +551,9 @@ def _tree_add(acc, grads):
 
 
 def _spawn_entry(args, stage_id, worker_id=None, rejoin=False):
-    # force CPU in spawned children: each stage would otherwise race to
-    # claim the single local accelerator (same rule as the PS world)
+    # force CPU in spawned children: a chip belongs to one process, so
+    # the stages cannot share the local accelerator (same rule as the PS
+    # world; the parent says so at start-up - announce_cpu_world)
     import jax as _jax
 
     _jax.config.update("jax_platforms", "cpu")
@@ -572,8 +573,10 @@ def run(args) -> None:
     from pytorch_distributed_rnn_tpu.obs.live import resolve_event_push
     from pytorch_distributed_rnn_tpu.obs.recorder import MetricsRecorder
     from pytorch_distributed_rnn_tpu.resilience.faults import FaultSchedule
+    from pytorch_distributed_rnn_tpu.utils.worlds import announce_cpu_world
 
     logging.basicConfig(level=args.log)
+    announce_cpu_world("MPMD pipeline world")
     cfg = PipelineConfig.from_args(args)
     faults = FaultSchedule.resolve(args)
     if faults is not None:

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def ring_relay_from_root(x, mesh, axis: str = "dp", root: int = 0):

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from pytorch_distributed_rnn_tpu.ops.rnn import (
     gru_input_proj,

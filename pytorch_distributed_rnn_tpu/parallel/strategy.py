@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from pytorch_distributed_rnn_tpu.ops.losses import cross_entropy_loss
 from pytorch_distributed_rnn_tpu.ops.rnn import dtype_of

@@ -366,8 +366,9 @@ def run_worker(args, rank: int, worker_id: int | None = None,
 
 
 def _spawn_entry(args, rank, worker_id=None, rejoin=False):
-    # force CPU in spawned children: each child would otherwise race to
-    # claim the single local accelerator
+    # force CPU in spawned children: a chip belongs to one process, so
+    # the world's processes cannot share the local accelerator (the
+    # parent says so at start-up - announce_cpu_world)
     import jax as _jax
 
     _jax.config.update("jax_platforms", "cpu")
@@ -464,6 +465,9 @@ def run(args):
         )
 
     # local mode: spawn the whole world (fake-cluster pattern)
+    from pytorch_distributed_rnn_tpu.utils.worlds import announce_cpu_world
+
+    announce_cpu_world("parameter-server spawn world")
     ctx = mp.get_context("spawn")
     if getattr(args, "elastic", False):
         return _run_elastic(args, ctx)

@@ -227,7 +227,11 @@ def serve_main(argv=None) -> int:
     # before any socket/thread/file exists, so every acquisition is seen
     leakcheck.maybe_install()
 
-    import jax
+    # platform knobs + the persistent compile cache: a server start
+    # compiles one program per bucket, all of them reusable next start
+    from pytorch_distributed_rnn_tpu.utils import apply_platform_overrides
+
+    jax = apply_platform_overrides()
 
     from pytorch_distributed_rnn_tpu.obs.recorder import MetricsRecorder
     from pytorch_distributed_rnn_tpu.resilience.faults import FaultSchedule

@@ -52,6 +52,7 @@ from pytorch_distributed_rnn_tpu.serving.loadgen import (
     run_load,
 )
 from pytorch_distributed_rnn_tpu.serving.protocol import ServingClient
+from pytorch_distributed_rnn_tpu.utils.worlds import refuse_chip_sharing
 
 log = logging.getLogger(__name__)
 
@@ -269,6 +270,8 @@ def spawn_fleet(replica_args: list[str], n: int, *,
     ``router_args`` extend the ``pdrnn-router`` invocation."""
     if n < 1:
         raise ValueError(f"a fleet needs >= 1 replica, got {n}")
+    # replicas inherit this environment, each its own JAX process
+    refuse_chip_sharing("serving fleet", n)
     with tempfile.TemporaryDirectory(prefix="pdrnn-fleet-") as tmp:
         tmpdir = Path(tmp)
         port_files = {

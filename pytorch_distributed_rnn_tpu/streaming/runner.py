@@ -31,8 +31,9 @@ log = logging.getLogger(__name__)
 
 
 def _spawn_entry(args, rank, worker_id=None, rejoin=False):
-    # force CPU in spawned children: each child would otherwise race to
-    # claim the single local accelerator
+    # force CPU in spawned children: a chip belongs to one process, so
+    # learner and actors cannot share the local accelerator (the parent
+    # says so at start-up - announce_cpu_world)
     import jax as _jax
 
     _jax.config.update("jax_platforms", "cpu")
@@ -60,8 +61,10 @@ def run(args):
     from pytorch_distributed_rnn_tpu.obs import MetricsRecorder
     from pytorch_distributed_rnn_tpu.obs.live import resolve_event_push
     from pytorch_distributed_rnn_tpu.resilience import FaultSchedule
+    from pytorch_distributed_rnn_tpu.utils.worlds import announce_cpu_world
 
     logging.basicConfig(level=args.log)
+    announce_cpu_world("streaming actor/learner world")
     num_actors = int(args.actors)
     if num_actors < 1:
         raise SystemExit("pdrnn-stream needs --actors >= 1")

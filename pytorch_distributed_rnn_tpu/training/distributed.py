@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from pytorch_distributed_rnn_tpu.data.sampler import DistributedSampler
@@ -145,6 +146,38 @@ class SpmdTrainer(Trainer):
                                                      mesh=self.mesh)
         return super()._init_opt_state()
 
+    def _layout_block(self) -> dict:
+        """How the run is laid out over the mesh, for run_summary and
+        the end-of-run log line: the devices and per-device shard of
+        the live params and optimizer state (read off the arrays the
+        SPMD program returned) and of each global index batch (the
+        sharding the step program declares for it - the batch itself is
+        gathered on device from that shard)."""
+        def describe(tree):
+            leaf = max(jax.tree.leaves(tree), key=lambda a: a.size)
+            sharding = getattr(leaf, "sharding", None)
+            if sharding is None:  # host arrays: restored, never stepped
+                return None
+            return {
+                "devices": sorted(d.id for d in sharding.device_set),
+                "global_shape": list(leaf.shape),
+                "shard_shape": list(sharding.shard_shape(leaf.shape)),
+            }
+
+        global_batch = max(1, self.batch_size // self.world_size) \
+            * self.world_size
+        batch = NamedSharding(self.mesh, P(self.axis))
+        return {
+            "mesh": dict(self.mesh.shape),
+            "batch": {
+                "devices": sorted(d.id for d in batch.device_set),
+                "global_shape": [global_batch],
+                "shard_shape": list(batch.shard_shape((global_batch,))),
+            },
+            "params": describe(self.params),
+            "opt_state": describe(self.opt_state),
+        }
+
     def _checkpoint_state(self):
         # checkpoints always carry the UNSHARDED layout so --resume,
         # the PS, serving, and streaming consumers are layout-agnostic
@@ -200,6 +233,25 @@ class SpmdTrainer(Trainer):
             with_key=self._dropout > 0.0,
             sharded=self._shard_update,
         )
+
+    def _build_eval_step(self):
+        """Evaluation replicated over the mesh: every device computes the
+        whole (full-dataset, single-batch) evaluation on its own copy of
+        the params - the reference's rank-0-only evaluation
+        (``distributed.py:20-22``) done once per device instead of once.
+
+        Inside ``shard_map`` on purpose.  The params live replicated on
+        every mesh device, so a plain ``jit`` would hand the program to
+        the GSPMD partitioner, and a Pallas kernel in it cannot be
+        partitioned: on four v5e chips the first validation pass died
+        with "NotImplementedError: Mosaic kernels cannot be automatically
+        partitioned. Please wrap the call in a shard_map" (CHANGES.md
+        PR 21; interpret mode on the CPU mesh never reaches Mosaic)."""
+        rep = P()
+        return jax.jit(shard_map(
+            self._loss_and_metrics, mesh=self.mesh, in_specs=(rep, rep),
+            out_specs=rep, check_vma=False,
+        ))
 
     def _build_run_fn(self):
         return make_spmd_run_fn(

@@ -213,6 +213,15 @@ class MeshTrainer(SpmdTrainer):
                 "--sp-schedule sequential or --dropout 0"
             )
 
+    # the mesh loss builders (parallel/strategy.py) pick their own inner
+    # steps and shard batch and state per layout; the pure-DP reports
+    # would describe a program this trainer does not run
+    def _resolved_impl(self):
+        return None
+
+    def _layout_block(self):
+        return None
+
     def _data_world_size(self) -> int:
         # moe shards batch rows over the FULL dp x ep product (every
         # device is a data shard for the backbone); everything else

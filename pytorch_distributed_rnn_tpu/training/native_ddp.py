@@ -773,15 +773,21 @@ def launch_world(world_size: int, cli_args, *, master_port: int = 29533,
     rank runs ``python -m pytorch_distributed_rnn_tpu.main <cli_args>
     distributed-native`` with the env rendezvous set.  ``backend="cpu"``
     forces each rank onto the CPU platform (the no-hardware path);
-    ``"native"`` leaves the ambient platform (attached accelerator) alone.
+    ``"native"`` leaves the ambient platform alone - refused on a TPU
+    host, where every rank would claim every chip.
     Returns ``(returncode, stdout, stderr)`` per rank in rank order;
     raises if any rank fails."""
     import os
     import sys
     from pathlib import Path
 
-    from pytorch_distributed_rnn_tpu.utils.worlds import spawn_world
+    from pytorch_distributed_rnn_tpu.utils.worlds import (
+        refuse_chip_sharing,
+        spawn_world,
+    )
 
+    if backend != "cpu":
+        refuse_chip_sharing("distributed-native world", world_size)
     repo_root = str(Path(__file__).resolve().parent.parent.parent)
     rank_cmds = []
     for rank in range(world_size):

@@ -6,16 +6,18 @@ from pytorch_distributed_rnn_tpu.utils.hw import (
 )
 from pytorch_distributed_rnn_tpu.utils.platform import (
     apply_platform_overrides,
-    ensure_usable_backend,
-    probe_backend,
+    compile_cache_dir,
+    compile_cache_stats,
+    enable_compile_cache,
 )
 
 __all__ = [
     "CPU_PEAK_FLOPS_ESTIMATE",
     "PEAK_FLOPS_TABLE",
     "apply_platform_overrides",
-    "ensure_usable_backend",
+    "compile_cache_dir",
+    "compile_cache_stats",
+    "enable_compile_cache",
     "local_peak_flops",
     "peak_flops",
-    "probe_backend",
 ]

@@ -39,7 +39,7 @@ def supports_spmd_ring_collectives() -> bool:
         ring_flash_attention,
     )
     from pytorch_distributed_rnn_tpu.parallel import make_mesh
-    from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     if len(jax.devices()) < 2:
         return False

@@ -1,0 +1,274 @@
+#!/usr/bin/env python
+"""Compile every shipped Pallas kernel on the TPU and compare it with its
+reference, forward and backward, at one caller shape each.
+
+    python scripts/chip_kernel_check.py [--only SUBSTR] [--provoke]
+                                        [--out chiprun_out/kernel_check.json]
+
+Cases (ISSUE 21, tentpole 6): the fused LSTM at the motion default
+(H=32, batch 1440, T=128, f32 - ``main.py``'s defaults), the fused LSTM
+and GRU at H=512 in f32 and bf16 (the largest hidden size ``auto``
+routes to the kernel; shape of the launcher's char-LM chip row: batch
+256, T=128), and flash attention forward / dQ / dK,dV at the attention
+family's CLI defaults (hidden 32 over 4 heads -> head_dim 8, batch 1440,
+T=128).  Everything runs under ``jax.default_matmul_precision("highest")``
+and the reference always computes in float32 - on the kernel's own
+inputs, upcast - so it is the exact side of the comparison also for the
+bf16 cases (a bf16 ``lax.scan`` reference accumulates its bias gradient
+over T steps in bf16 and is itself off by several percent).
+
+The script refuses to run without a TPU (a CPU run would interpret the
+kernels, which proves nothing about Mosaic) and exits non-zero when any
+case fails to compile or misses its tolerance.  ``--only`` keeps the
+cases whose name contains SUBSTR (``chip_smoke.py`` runs the motion
+case alone).  ``--provoke`` also makes the compiler refuse programs on
+purpose and records what it says - the text
+``Trainer._COMPILE_FAILURE_MARKS`` is based on.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+# max |kernel - reference| / max |reference| per compared array.  f32:
+# the kernels' measured error is <= 5e-5 (flash dQ), so 2e-4 leaves no
+# room for a bf16 pass.  bf16: 8 mantissa bits (eps 3.9e-3); measured
+# <= 8.0e-3 (flash dQ) and <= 5.2e-3 on the RNN kernels through 128
+# dependent steps, so 2e-2 is 2.5x the worst case seen.
+TOLERANCE = {"float32": 2e-4, "bfloat16": 2e-2}
+
+
+def _rel_err(got, want) -> float:
+    import jax.numpy as jnp
+
+    got = jnp.asarray(got, jnp.float32)
+    want = jnp.asarray(want, jnp.float32)
+    scale = float(jnp.max(jnp.abs(want))) or 1.0
+    return float(jnp.max(jnp.abs(got - want))) / scale
+
+
+def _compare(name, fused_fn, ref_fn, args, dtype):
+    """Run value+grad of both sides on the same inputs; returns the row."""
+    import jax
+    import jax.numpy as jnp
+
+    def scalarize(fn):
+        def loss(*a):
+            out = fn(*a)
+            # a fixed non-uniform cotangent so every output element's
+            # gradient path is exercised with a distinct weight
+            w = jnp.linspace(0.5, 1.5, out.size, dtype=jnp.float32)
+            return jnp.sum(out.astype(jnp.float32).reshape(-1) * w), out
+
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(len(args))), has_aux=True))
+
+    row = {"case": name, "dtype": dtype}
+    t0 = time.perf_counter()
+    (_, out_f), grads_f = jax.block_until_ready(scalarize(fused_fn)(*args))
+    row["fused_compile_and_run_s"] = round(time.perf_counter() - t0, 2)
+    ref_args = jax.tree.map(lambda a: a.astype(jnp.float32), args)
+    (_, out_r), grads_r = jax.block_until_ready(
+        scalarize(ref_fn)(*ref_args))
+    errs = {"out": _rel_err(out_f, out_r)}
+    flat_f, _ = jax.tree.flatten(grads_f)
+    flat_r, _ = jax.tree.flatten(grads_r)
+    for i, (gf, gr) in enumerate(zip(flat_f, flat_r)):
+        errs[f"grad{i}"] = _rel_err(gf, gr)
+    row["rel_err"] = {k: float(f"{v:.3e}") for k, v in errs.items()}
+    row["finite"] = bool(all(
+        bool(jnp.all(jnp.isfinite(jnp.asarray(g, jnp.float32))))
+        for g in [out_f, *flat_f]))
+    row["ok"] = row["finite"] and max(errs.values()) <= TOLERANCE[dtype]
+    return row
+
+
+def _rnn_case(cell, hidden, batch, seq, in_dim, dtype_name):
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_rnn_tpu.ops import pallas_rnn, rnn
+
+    dtype = jnp.dtype(dtype_name)
+    init = {"lstm": rnn.init_lstm_layer, "gru": rnn.init_gru_layer}[cell]
+    params = jax.tree.map(
+        lambda p: p.astype(dtype),
+        init(jax.random.PRNGKey(0), in_dim, hidden))
+    x = jax.random.normal(
+        jax.random.PRNGKey(1), (batch, seq, in_dim), jnp.float32
+    ).astype(dtype)
+    fused = {"lstm": pallas_rnn.lstm_layer_fused,
+             "gru": pallas_rnn.gru_layer_fused}[cell]
+    ref = {"lstm": rnn.lstm_layer, "gru": rnn.gru_layer}[cell]
+    name = f"{cell}_fused h{hidden} b{batch} t{seq} in{in_dim}"
+    row = _compare(name, lambda p, xx: fused(p, xx)[0],
+                   lambda p, xx: ref(p, xx)[0], (params, x), dtype_name)
+    row["block_b"] = pallas_rnn._pick_block_b(batch, hidden, dtype.itemsize)
+    return row
+
+
+def _flash_case(batch, heads, seq, head_dim, dtype_name):
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_rnn_tpu.ops.attention import mha_attention
+    from pytorch_distributed_rnn_tpu.ops.pallas_attention import (
+        flash_attention,
+    )
+
+    dtype = jnp.dtype(dtype_name)
+    q, k, v = (
+        jax.random.normal(jax.random.PRNGKey(i),
+                          (batch, heads, seq, head_dim),
+                          jnp.float32).astype(dtype)
+        for i in range(3)
+    )
+    name = f"flash_attention b{batch} h{heads} t{seq} d{head_dim}"
+    # grad0/grad1/grad2 = the dQ kernel and the two outputs of the dK,dV
+    # kernel
+    return _compare(name, flash_attention, mha_attention, (q, k, v),
+                    dtype_name)
+
+
+def _provoke_refusals():
+    """Make the installed compiler refuse programs and return what it
+    says, verbatim (first 1500 characters)."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_rnn_tpu.ops import pallas_rnn, rnn
+    from pytorch_distributed_rnn_tpu.training.base import Trainer
+
+    rows = []
+
+    def attempt(name, fn):
+        try:
+            jax.block_until_ready(fn())
+            rows.append({"provoked": name, "refused": False})
+        except Exception as exc:  # noqa: BLE001 - the message is the result
+            rows.append({
+                "provoked": name, "refused": True,
+                "type": type(exc).__name__,
+                "message": str(exc)[:1500],
+                "is_compile_failure": Trainer.is_compile_failure(exc),
+            })
+
+    # 1. Mosaic scoped-VMEM overflow: the fused LSTM backward at a batch
+    #    tile the VMEM model would never pick
+    params = rnn.init_lstm_layer(jax.random.PRNGKey(0), 512, 512)
+    x = jnp.zeros((1024, 16, 512), jnp.float32)
+    attempt("pallas scoped-vmem overflow (lstm bwd h512 f32 block_b 1024)",
+            lambda: jax.jit(jax.grad(
+                lambda p: jnp.sum(pallas_rnn.lstm_layer_fused(
+                    p, x, block_b=1024)[0])))(params))
+
+    # 2. XLA HBM exhaustion at compile time: three live 9 GiB matrices
+    #    on a 16 GiB chip.  Ahead-of-time from abstract shapes, so
+    #    nothing is allocated or executed - the refusal is the compiler's
+    spec = jax.ShapeDtypeStruct((49152, 49152), jnp.float32)
+    attempt("xla hbm exhaustion (3 x 9 GiB live, compile only)",
+            lambda: jax.jit(lambda a: (a @ a) @ (a.T @ a))
+            .lower(spec).compile() and None)
+
+    # 3. a kernel Mosaic itself rejects: fp32 contract precision with a
+    #    bf16 operand (what the fused RNN kernels asked for in bf16 under
+    #    "highest" before ops/pallas_rnn.py:_mxu_dot)
+    from jax.experimental import pallas as pl
+
+    def mixed_dot(a_ref, b_ref, o_ref):
+        o_ref[:] = jax.lax.dot_general(
+            a_ref[:], b_ref[:], (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    attempt("mosaic refusal (f32 x bf16 matmul at fp32 precision)",
+            lambda: pl.pallas_call(
+                mixed_dot,
+                out_shape=jax.ShapeDtypeStruct((128, 128), jnp.float32),
+            )(jnp.ones((128, 128), jnp.float32),
+              jnp.ones((128, 128), jnp.bfloat16)))
+    return rows
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(prog="chip_kernel_check.py")
+    parser.add_argument("--out", default="chiprun_out/kernel_check.json")
+    parser.add_argument("--only", default="", metavar="SUBSTR",
+                        help="run only the cases whose name contains "
+                        "SUBSTR")
+    parser.add_argument("--provoke", action="store_true",
+                        help="also provoke compile refusals and record "
+                        "the compiler's messages")
+    args = parser.parse_args(argv)
+
+    from pytorch_distributed_rnn_tpu.utils import apply_platform_overrides
+
+    jax = apply_platform_overrides()
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        print(f"chip_kernel_check: no TPU found (jax reports platform "
+              f"{device.platform!r}); the kernels would run interpreted",
+              file=sys.stderr)
+        return 1
+
+    from pytorch_distributed_rnn_tpu.ops.pallas_rnn import _interpret
+
+    assert not _interpret(), "interpret mode selected on a TPU"
+
+    cases = {
+        "lstm h32 f32 (motion default)":
+            lambda: _rnn_case("lstm", 32, 1440, 128, 9, "float32"),
+        "lstm h512 f32":
+            lambda: _rnn_case("lstm", 512, 256, 128, 512, "float32"),
+        "lstm h512 bf16":
+            lambda: _rnn_case("lstm", 512, 256, 128, 512, "bfloat16"),
+        "gru h512 f32":
+            lambda: _rnn_case("gru", 512, 256, 128, 512, "float32"),
+        "gru h512 bf16":
+            lambda: _rnn_case("gru", 512, 256, 128, 512, "bfloat16"),
+        "flash attention f32 (attention default)":
+            lambda: _flash_case(1440, 4, 128, 8, "float32"),
+        "flash attention bf16":
+            lambda: _flash_case(1440, 4, 128, 8, "bfloat16"),
+    }
+    cases = {k: v for k, v in cases.items() if args.only in k}
+    if not cases:
+        parser.error(f"--only {args.only!r} matches no case")
+    rows = []
+    with jax.default_matmul_precision("highest"):
+        for name, case in cases.items():
+            try:
+                rows.append(case())
+            except Exception as exc:  # noqa: BLE001 - recorded, fails the run
+                rows.append({"case": name, "ok": False,
+                             "type": type(exc).__name__,
+                             "error": str(exc)[:3000]})
+            print(json.dumps(rows[-1]), flush=True)
+    report = {
+        "device": {"platform": device.platform,
+                   "kind": device.device_kind,
+                   "count": len(jax.devices())},
+        "jax": jax.__version__,
+        "tolerance": TOLERANCE,
+        "cases": rows,
+    }
+    if args.provoke:
+        report["refusals"] = _provoke_refusals()
+        for row in report["refusals"]:
+            print(json.dumps(row), flush=True)
+    out = Path(args.out)
+    out.parent.mkdir(parents=True, exist_ok=True)
+    out.write_text(json.dumps(report, indent=1))
+    ok = all(row.get("ok") for row in rows)
+    print(json.dumps({"ok": ok, "device": report["device"]}))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

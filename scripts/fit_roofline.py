@@ -1,8 +1,8 @@
 #!/usr/bin/env python
-"""Fit the recurrent-scan roofline from banked bench rows.
+"""Fit the recurrent-scan roofline from a saved bench line.
 
-Reads the ``char_rnn_recurrent_roofline`` grid out of a banked bench
-line (default: ``results_bench_chip_r5.json``) and fits, per batch size,
+Reads the ``char_rnn_recurrent_roofline`` grid out of the JSON line
+``python bench.py --suite rnn`` printed on a TPU and fits, per batch size,
 
     t_pass = flops / eff_peak + (2 * seq) * tau
 
@@ -12,7 +12,7 @@ The tau estimate is the deep-vs-wide MFU gap's explanation candidate:
 deep (4 x 1280) runs 2x the sequential steps of wide (2 x 2048) per
 token at ~2.56x smaller per-step matmuls, so a fixed tau taxes it twice.
 
-Usage: python scripts/fit_roofline.py [results_bench_chip_r5.json]
+Usage: python scripts/fit_roofline.py BENCH_LINE.json
 """
 
 import json
@@ -42,9 +42,9 @@ def fit(rows):
 
 
 def main():
-    path = Path(sys.argv[1] if len(sys.argv) > 1
-                else "results_bench_chip_r5.json")
-    line = json.loads(path.read_text())
+    if len(sys.argv) != 2:
+        sys.exit(__doc__.strip().splitlines()[-1])
+    line = json.loads(Path(sys.argv[1]).read_text())
     grid = line["extra_metrics"]["char_rnn_recurrent_roofline"]
     cells = [v for v in grid.values() if isinstance(v, dict)]
     for batch in sorted({c["batch"] for c in cells}):
@@ -53,7 +53,7 @@ def main():
         out = fit(sub)
         print(f"B={batch}: cells="
               + ", ".join(f"H{c['hidden']}={c['ms_per_pass']}ms"
-                          f"({c['mfu_vs_v5e_bf16_peak']:.1%})"
+                          f"(mfu {c['mfu_vs_bf16_peak']})"
                           for c in sub)
               + (f" -> eff_peak={out['eff_peak_tflops']} TF/s, "
                  f"tau={out['tau_us_per_step']} us/step" if out else

@@ -1,7 +1,6 @@
 """HLO collective-traffic report (evaluation/collectives.py): the
 communication side of the scaling model, measured from compiled programs
-(VERDICT.md round-3 item 6 - what one chip/virtual mesh CAN measure
-honestly)."""
+(what one chip/virtual mesh CAN measure honestly)."""
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +51,7 @@ class TestCompiledPrograms:
         tree's bytes through all-reduce per step - the invariant the
         scaling model's communication term is built on."""
         from jax.sharding import PartitionSpec as P
-        from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+        from jax import shard_map
 
         mesh = make_mesh({"dp": 8})
         w = jnp.zeros((64, 64), jnp.float32)
@@ -78,7 +77,7 @@ class TestCompiledPrograms:
         depends on this; plain HLO parsing undercounts)."""
         from functools import partial
 
-        from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from pytorch_distributed_rnn_tpu.evaluation.collectives import (

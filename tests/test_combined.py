@@ -77,7 +77,7 @@ def test_3d_tp_indivisible_heads_raises(setup):
 
 class TestSpTpRnn:
     """The composed sp x tp RNN (gate-sharded cell inside the sp relay,
-    r4 - VERDICT r3 item 6): parity vs the unsharded stack, both cells,
+    r4): parity vs the unsharded stack, both cells,
     plus the char-LM loss fn on the full dp x sp x tp mesh."""
 
     B, T, IN, H = 4, 16, 5, 8
@@ -86,7 +86,7 @@ class TestSpTpRnn:
     def test_matches_unsharded_stack(self, cell):
         from functools import partial
 
-        from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from pytorch_distributed_rnn_tpu.ops.rnn import (
@@ -201,7 +201,7 @@ class TestSpTpRnn:
         output tracks the unsharded bf16 stack; remat is exact."""
         from functools import partial
 
-        from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from pytorch_distributed_rnn_tpu.ops.rnn import (

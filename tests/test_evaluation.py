@@ -1,8 +1,8 @@
 """Evaluation-layer tests: the notebooks' data contract survives.
 
 The regex, dataframe shape, and derived scaling figures mirror
-``/root/reference/evaluation/Experiments.ipynb`` (cell 2 regex; BASELINE.md
-derivations).  The round-trip test feeds results entries shaped exactly
+``/root/reference/evaluation/Experiments.ipynb`` (cell 2 regex and its
+derived figures).  The round-trip test feeds results entries shaped exactly
 like the launcher's output.
 """
 
@@ -139,7 +139,7 @@ def test_aggregate_means_over_repeats():
 
 def test_scaling_table_efficiency_vs_local():
     # local 1 dev: 144s; ddp 8 dev: 33s -> speedup 4.36, efficiency ~0.545
-    # (the BASELINE.md shape)
+    # (the reference's scaling-table shape)
     results = [
         _run("local", 1, 144.0, 700.0),
         _run("distributed", 8, 33.0, 220.0, ranks=1),

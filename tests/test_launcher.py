@@ -114,7 +114,7 @@ def test_run_hosts_spawn_path_trains_world(tmp_path, monkeypatch, capsys):
     a real 2-process ``jax.distributed`` world and trains - with ``ssh``
     stubbed to local exec, the in-suite stand-in for the reference's
     docker master/slave SSH pair (``/root/reference/docker-compose.yaml:
-    3-27``; VERDICT.md round-3 item 5: no sshd in this image)."""
+    3-27``; there is no sshd in this image)."""
     import os
     import sys as _sys
     from pathlib import Path

@@ -29,7 +29,7 @@ from pytorch_distributed_rnn_tpu.lint.trace_registry import (
     sds,
 )
 from pytorch_distributed_rnn_tpu.parallel.mesh import make_mesh
-from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+from jax import shard_map
 
 REPO_ROOT = Path(__file__).resolve().parents[1]
 PACKAGE = REPO_ROOT / "pytorch_distributed_rnn_tpu"

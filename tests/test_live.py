@@ -876,8 +876,7 @@ class TestLiveDrillEndToEnd:
         # the suite's persistent XLA compile cache flakily segfaults
         # chaos subprocess runs on XLA:CPU (see test_resilience.py) -
         # compile fresh
-        env.pop("JAX_COMPILATION_CACHE_DIR", None)
-        env.pop("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", None)
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "0"
         proc = subprocess.Popen(
             [sys.executable, "-m", "pytorch_distributed_rnn_tpu.main",
              "--dataset-path", "har", "--epochs", "2", "--batch-size",

@@ -253,8 +253,8 @@ class TestCharMesh:
 
     def test_mesh_char_tp_bf16_close_to_dp_bf16(self, tmp_path,
                                                 monkeypatch):
-        """bf16 threads through the tp gate-sharded stack since r4
-        (VERDICT round-3 item 4): a dp x tp bf16 char mesh reproduces the
+        """bf16 threads through the tp gate-sharded stack since r4:
+        a dp x tp bf16 char mesh reproduces the
         dp-only bf16 loss history to bf16 tolerance (the gate shards
         reorder the same bf16 matmuls)."""
         monkeypatch.chdir(tmp_path)
@@ -295,8 +295,8 @@ class TestCharMesh:
 
     def test_mesh_char_sp_bf16_close_to_dp_bf16(self, tmp_path,
                                                 monkeypatch):
-        """The flagship composition (long-context sp + mixed precision,
-        VERDICT.md round-3 item 3): a dp x sp bf16 char mesh reproduces
+        """The flagship composition (long-context sp + mixed
+        precision): a dp x sp bf16 char mesh reproduces
         the dp-only bf16 loss history to bf16 tolerance (the relay
         reorders the same bf16 matmuls, so histories differ only by
         rounding)."""

@@ -457,7 +457,7 @@ class TestGroupedRouting:
     def test_ep_group_size_zero_rejects_expert_router(self):
         """group_size=0 with router='expert' must be rejected as loudly
         as any other group_size - the old truthy guard let 0 slip
-        through as if the knob had not been passed (ADVICE r5)."""
+        through as if the knob had not been passed."""
         params = init_moe_ffn(jax.random.PRNGKey(0), D, E, HID)
         x = jax.random.normal(jax.random.PRNGKey(1), (N, D))
         with pytest.raises(ValueError, match="token-choice knob"):

@@ -1,5 +1,4 @@
-"""--model moe: the EP axis as a first-class CLI family (VERDICT.md
-round-3 item 4).
+"""--model moe: the EP axis as a first-class CLI family.
 
 Equivalence spine: the expert-parallel dp x ep mesh program
 (``make_moe_mesh_loss_fn``) is a re-layout of the dense-exact MoE forward

@@ -207,7 +207,7 @@ def test_global_batch_invariance_across_world_sizes(tmp_path):
 
 @pytest.mark.slow
 def test_char_family_two_rank_world(tmp_path):
-    """The char-LM over the C++ TCP transport (VERDICT r2 weak #6: the
+    """The char-LM over the C++ TCP transport (the
     strategy that rides the transport never saw the family that stresses
     it): 2-rank world trains with rank parity and per-rank perf lines."""
     (tmp_path / "corpus.txt").write_bytes(bytes(range(256)) * 40)
@@ -280,9 +280,7 @@ def test_sharded_world_kill_then_resume_keeps_rank_parity(
     # the suite's persistent XLA compile cache flakily SEGFAULTS resumed
     # runs on XLA:CPU (see test_resilience.TestKillAndResumeCLI) - the
     # chaos subprocesses compile fresh instead
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                       raising=False)
+    monkeypatch.setenv("JAX_ENABLE_COMPILATION_CACHE", "0")
     data_dir = _dataset(tmp_path)
     ref_dir = tmp_path / "ref"
     chaos_dir = tmp_path / "chaos"

@@ -472,10 +472,23 @@ class TestStepTraceCapture:
             jax.profiler, "start_trace",
             lambda *a, **k: (_ for _ in ()).throw(RuntimeError("no prof")),
         )
-        cap.on_step_start(0)  # must not raise
+        cap.on_step_start(0)  # the CPU test platform: must not raise
         cap.on_step_end(1)
         info = cap.close()
         assert info["captured"] is False
+
+    def test_profiler_failure_raises_on_an_accelerator(self, tmp_path,
+                                                       monkeypatch):
+        """The skip is the CPU's: on a chip the trace is what the run was
+        for, and silently having none would waste it."""
+        cap = StepTraceCapture(tmp_path / "trace", 0, 2)
+        monkeypatch.setattr(
+            jax.profiler, "start_trace",
+            lambda *a, **k: (_ for _ in ()).throw(RuntimeError("no prof")),
+        )
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="no prof"):
+            cap.on_step_start(0)
 
 
 # -- CLI exit codes ----------------------------------------------------------
@@ -807,7 +820,7 @@ class TestSubsystemHooks:
         assert summarize_events(events)["ps_degraded_rounds"] == 1
 
 
-# -- malformed-line taxonomy -------------------------------------------------
+# -- malformed-line classes --------------------------------------------------
 
 
 def test_load_events_tolerates_torn_final_line(tmp_path):

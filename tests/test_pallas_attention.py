@@ -133,7 +133,7 @@ class TestRingFlash:
     def _sharded(self, causal, t=256, sp=4):
         from functools import partial
 
-        from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from pytorch_distributed_rnn_tpu.ops.pallas_attention import (
@@ -187,7 +187,7 @@ class TestRingFlash:
         tile by BOTH blocks or tail keys silently drop from the softmax."""
         from functools import partial
 
-        from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from pytorch_distributed_rnn_tpu.ops.pallas_attention import (

@@ -567,8 +567,7 @@ class TestKillAndResumeCLI:
         # executables; reproducible at the pre-PR seed too, so an
         # upstream environment bug, not a resilience regression) - the
         # chaos subprocesses compile fresh instead
-        env.pop("JAX_COMPILATION_CACHE_DIR", None)
-        env.pop("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", None)
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "0"
         proc = subprocess.run(argv, cwd=cwd, env=env, capture_output=True,
                               text=True, timeout=240)
         if check:

@@ -77,7 +77,7 @@ class TestShardedUpdateLayer:
         where both flavors train the real model."""
         from functools import partial
 
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.flatten_util import ravel_pytree
 
         mesh = make_mesh({"dp": world})
@@ -95,14 +95,14 @@ class TestShardedUpdateLayer:
 
         @partial(shard_map, mesh=mesh,
                  in_specs=(P(), st_specs, gspec),
-                 out_specs=(P(), st_specs), check_rep=False)
+                 out_specs=(P(), st_specs), check_vma=False)
         def step_sh(p, st, gstack):
             grads = jax.tree.map(lambda l: l[0], gstack)
             return su.apply(p, grads, st)
 
         @partial(shard_map, mesh=mesh,
                  in_specs=(P(), P(), gspec),
-                 out_specs=(P(), P()), check_rep=False)
+                 out_specs=(P(), P()), check_vma=False)
         def step_rep(p, st, gstack):
             grads = jax.tree.map(
                 lambda l: jax.lax.pmean(l[0], "dp"), gstack
@@ -146,13 +146,13 @@ class TestShardedUpdateLayer:
         compilation cannot differ."""
         from functools import partial
 
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh = make_mesh({"dp": world})
         n = 12 * world
 
         @partial(shard_map, mesh=mesh, in_specs=P("dp"),
-                 out_specs=(P("dp"), P("dp")), check_rep=False)
+                 out_specs=(P("dp"), P("dp")), check_vma=False)
         def both(x):
             sc = jax.lax.psum_scatter(
                 x[0], "dp", scatter_dimension=0, tiled=True

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from functools import partial
-from pytorch_distributed_rnn_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from pytorch_distributed_rnn_tpu.models import MotionModel
