@@ -1,0 +1,1 @@
+"""The training benchmark: harness, yardstick and data files (see README.md)."""
