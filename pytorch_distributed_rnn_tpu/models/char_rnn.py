@@ -67,7 +67,8 @@ class CharRNN:
         from pytorch_distributed_rnn_tpu.ops.rnn import dtype_of
 
         compute_dtype = dtype_of(self.precision)
-        x = params["embed"][tokens]
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]
         outputs, _ = stacked_rnn(
             params["rnn"], x, self.cell, unroll=self.unroll, impl=self.impl,
             compute_dtype=compute_dtype, remat=self.remat,

@@ -62,5 +62,6 @@ class MotionModel:
             compute_dtype=compute_dtype, remat=self.remat,
             dropout=self.dropout, dropout_key=dropout_key,
         )
-        last = outputs[:, -1, :].astype(jnp.float32)
-        return last @ params["fc"]["weight"].T + params["fc"]["bias"]
+        with jax.named_scope("head"):
+            last = outputs[:, -1, :].astype(jnp.float32)
+            return last @ params["fc"]["weight"].T + params["fc"]["bias"]

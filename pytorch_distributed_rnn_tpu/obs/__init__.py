@@ -2,10 +2,13 @@
 
 - :mod:`.recorder`: rank-tagged JSONL event stream, buffered off the
   training hot path (:class:`MetricsRecorder` / :data:`NULL_RECORDER`).
-- :mod:`.spans`: the span primitives (``recorder.span`` / ``emit_span``
-  context-manager and deferred duration events).
+- :mod:`.spans`: the span primitives - ``recorder.emit_span`` (a
+  deferred JSONL duration event) and ``spans.span``, the program span
+  the trainer puts at its layer boundaries: profiler annotation,
+  in-process log with parents (``spans.log()``), and the JSONL event,
+  from one call site.
 - :mod:`.profile`: step-bounded ``jax.profiler`` capture
-  (``--profile-steps A:B``).
+  (``--profile-steps A:B``), which leaves the program as it is.
 - :mod:`.summary`: sidecar loading, summaries, diffs, stragglers,
   per-rank liveness (``rank_health``).
 - :mod:`.timeline`: cross-rank clock alignment, Chrome-trace/Perfetto

@@ -167,9 +167,11 @@ def ledger_events(events: list[dict], path=None, peak: dict | None = None,
         float(e.get("dur_s") or 0.0) for e in spans
         if e.get("name") == "fault_stall"
     )
+    # the evaluation spans themselves: their eval.launch / eval.fetch
+    # children ride the same lane and would count the time twice
     eval_s = sum(
         float(e.get("dur_s") or 0.0) for e in spans
-        if e.get("cat") == "eval"
+        if e.get("cat") == "eval" and e.get("name") == "eval"
     )
     checkpoint_s = sum(
         float(e.get("seconds") or 0.0) for e in events

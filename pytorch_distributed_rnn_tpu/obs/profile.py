@@ -7,7 +7,10 @@ the user wants to look at.  ``--profile-steps A:B`` bounds the capture
 to optimizer steps ``[A, B)``: the trace starts right before step A's
 dispatch and stops after step B-1's program completes (the trainer
 fences on the step's outputs before stopping, so the device work is in
-the trace).
+the trace).  The capture does not change the program: where an epoch
+runs as one scanned program, the trace starts before the first epoch
+that holds a step of the range and stops after the fetches of the last
+one (``Trainer._train_epoch``).
 
 On the CPU (the test platform, where a profiler may be absent) a
 capture that cannot start logs one warning and is skipped for the rest
@@ -82,10 +85,13 @@ class StepTraceCapture:
 
     # -- step hooks ----------------------------------------------------------
 
-    def on_step_start(self, step: int) -> None:
+    def on_step_start(self, step: int, count: int = 1) -> None:
+        """Before steps ``[step, step + count)`` are dispatched (the
+        scanned epoch dispatches its steps as one program): start the
+        capture if any of them lies in the range."""
         if self._disabled or self._active or self._captured:
             return
-        if step < self.start or step >= self.stop:
+        if step + count <= self.start or step >= self.stop:
             return
         import jax
 

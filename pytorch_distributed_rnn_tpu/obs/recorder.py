@@ -161,7 +161,6 @@ import threading
 import time
 from pathlib import Path
 
-from pytorch_distributed_rnn_tpu.obs.spans import NULL_SPAN, Span
 from pytorch_distributed_rnn_tpu.utils import threadcheck
 
 log = logging.getLogger(__name__)
@@ -207,11 +206,6 @@ class NullRecorder:
 
     def is_sample_step(self, step: int) -> bool:
         return False
-
-    def span(self, name: str, cat: str = "train", **attrs):
-        """Disabled tracing: the shared no-op context manager - no clock
-        reads, no allocation (the span half of the zero-overhead pin)."""
-        return NULL_SPAN
 
     def emit_span(self, name, tm_start, dur_s, cat="train",  # noqa: PD105
                   **attrs) -> None:
@@ -363,10 +357,6 @@ class MetricsRecorder:
             signal = len(self._buffer) >= self._flush_threshold
         if signal:
             self._wake.set()
-
-    def span(self, name: str, cat: str = "train", **attrs) -> Span:
-        """Context manager timing a ``span`` event (obs/spans.py)."""
-        return Span(self, name, cat, attrs)
 
     def emit_span(self, name, tm_start, dur_s, cat="train",
                   **attrs) -> None:

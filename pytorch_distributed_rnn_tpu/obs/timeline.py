@@ -271,11 +271,13 @@ class _TraceBuilder:
              dur_s: float, args: dict) -> dict:
         tid, cat = self._thread(pid, cat)
         ts = self._us(wall_start)
-        # the child-end clamp happens in the caller where nesting is
-        # known; here dur only needs non-negativity after rounding
+        # the END is rounded, not the duration: rounding is monotone, so
+        # a span recorded inside another (the trainer's eval.launch in
+        # its eval) stays inside it after rounding to whole us.  The
+        # clamp of synthesized children happens in the caller
         event = {
             "ph": "X", "pid": pid, "tid": tid, "name": name, "cat": cat,
-            "ts": ts, "dur": max(0, int(round(dur_s * _US))),
+            "ts": ts, "dur": max(0, self._us(wall_start + dur_s) - ts),
             "args": args,
         }
         self.events.append(event)

@@ -18,15 +18,15 @@ def cross_entropy_loss(logits, labels, reduction: str = "mean"):
     ``logits``: (N, C) float; ``labels``: (N,) int.  ``mean`` averages over
     the batch like torch's default ``CrossEntropyLoss``.
     """
-    log_probs = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(log_probs, labels[:, None].astype(jnp.int32), axis=-1)[
-        :, 0
-    ]
-    if reduction == "mean":
-        return jnp.mean(nll)
-    if reduction == "sum":
-        return jnp.sum(nll)
-    return nll
+    with jax.named_scope("loss"):
+        log_probs = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(
+            log_probs, labels[:, None].astype(jnp.int32), axis=-1)[:, 0]
+        if reduction == "mean":
+            return jnp.mean(nll)
+        if reduction == "sum":
+            return jnp.sum(nll)
+        return nll
 
 
 def mse_loss(pred, target, reduction: str = "mean"):

@@ -180,6 +180,7 @@ def _lstm_fwd_pallas(x_proj, h0, c0, w_hh_t, *, block_b):
             pltpu.VMEM((block_b, hidden), jnp.float32),
         ],
         interpret=_interpret(),
+        name="lstm_fwd",
     )(x_proj, h0, c0, w_hh_t)
     return h_all, c_all
 
@@ -338,12 +339,13 @@ def _fused_bwd(block_b, residuals, cotangents):
     # weight grad as one big MXU matmul over all (t, b) at once: for the
     # LSTM the emitted gate cotangents ARE dx_proj, so
     # dw_hh = sum_t d_gates[t]^T h_prev[t]  ->  (4H, H), f32 accumulate
-    h_prev_all = jnp.concatenate([h0[None], h_all[:-1]], axis=0)
-    dw_hh = jnp.einsum(
-        "tbg,tbh->gh", dx_proj, h_prev_all,
-        preferred_element_type=jnp.float32,
-    ).astype(x_proj.dtype)
-    return dx_proj, dw_hh.T, dh0, dc0
+    with jax.named_scope("recurrence_wgrad"):
+        h_prev_all = jnp.concatenate([h0[None], h_all[:-1]], axis=0)
+        dw_hh = jnp.einsum(
+            "tbg,tbh->gh", dx_proj, h_prev_all,
+            preferred_element_type=jnp.float32,
+        ).astype(x_proj.dtype)
+        return dx_proj, dw_hh.T, dh0, dc0
 
 
 fused_lstm_scan.defvjp(_fused_fwd, _fused_bwd)
@@ -354,9 +356,23 @@ fused_lstm_scan.defvjp(_fused_fwd, _fused_bwd)
 # ---------------------------------------------------------------------------
 
 
-def lstm_layer_fused(params, x, h0=None, c0=None, *, block_b=None):
+def lstm_layer_fused(params, x, h0=None, c0=None, *, block_b=None,
+                     scope: str = "lstm_layer"):
     """Drop-in replacement for ``ops.rnn.lstm_layer`` running the time loop
     as a fused Pallas kernel.  Same params (torch layout), same results.
+
+    ``scope`` names the XLA code around the kernels in a profiler trace.
+    The forward kernel names itself (``lstm_fwd``: ``jvp_lstm_fwd_.N`` on
+    the chip, ``lstm_fwd.N`` in evaluation and under ``jax.checkpoint``).
+    The backward kernel has no name and the call into it sits under no
+    scope, so it keeps the label of the name stack it is traced under
+    (``transpose_jvp___.N``): the chip's compiler names a Pallas call
+    after the INNERMOST scope, JAX wraps only the FIRST one in
+    ``transpose(jvp(``, and ``benchmarks/trace_reduce.py`` counts every
+    custom call whose label does not start with ``transpose_jvp`` as a
+    forward kernel - a bare ``lstm_bwd.N``, which any enclosing scope or
+    ``jax.checkpoint`` makes of a named one, would be counted twice
+    (tests/test_spans.py holds the rule; PERF.md section 7).
     """
     batch, _, _ = x.shape
     hidden = params["w_hh"].shape[1]
@@ -369,23 +385,26 @@ def lstm_layer_fused(params, x, h0=None, c0=None, *, block_b=None):
     from pytorch_distributed_rnn_tpu.ops.rnn import lstm_input_proj
 
     # to time-major after the shared one-big-matmul input projection
-    x_proj = jnp.swapaxes(lstm_input_proj(params, x), 0, 1)  # (T, B, 4H)
-    if batch_p != batch:
-        x_proj = jnp.pad(x_proj, ((0, 0), (0, batch_p - batch), (0, 0)))
+    with jax.named_scope(f"{scope}/input_proj"):
+        x_proj = jnp.swapaxes(lstm_input_proj(params, x), 0, 1)  # (T, B, 4H)
 
-    if h0 is None:
-        h0 = jnp.zeros((batch, hidden), dtype)
-    if c0 is None:
-        c0 = jnp.zeros((batch, hidden), dtype)
-    if batch_p != batch:
-        h0 = jnp.pad(h0, ((0, batch_p - batch), (0, 0)))
-        c0 = jnp.pad(c0, ((0, batch_p - batch), (0, 0)))
+    with jax.named_scope(f"{scope}/recurrence"):
+        if batch_p != batch:
+            x_proj = jnp.pad(x_proj, ((0, 0), (0, batch_p - batch), (0, 0)))
+        if h0 is None:
+            h0 = jnp.zeros((batch, hidden), dtype)
+        if c0 is None:
+            c0 = jnp.zeros((batch, hidden), dtype)
+        if batch_p != batch:
+            h0 = jnp.pad(h0, ((0, batch_p - batch), (0, 0)))
+            c0 = jnp.pad(c0, ((0, batch_p - batch), (0, 0)))
+        w_hh_t = params["w_hh"].T
 
-    h_all, (h_T, c_T) = fused_lstm_scan(
-        x_proj, params["w_hh"].T, h0, c0, block_b
-    )
-    outputs = jnp.swapaxes(h_all, 0, 1)[:batch]
-    return outputs, (h_T[:batch], c_T[:batch])
+    h_all, (h_T, c_T) = fused_lstm_scan(x_proj, w_hh_t, h0, c0, block_b)
+
+    with jax.named_scope(f"{scope}/recurrence"):
+        outputs = jnp.swapaxes(h_all, 0, 1)[:batch]
+        return outputs, (h_T[:batch], c_T[:batch])
 
 
 # ---------------------------------------------------------------------------
@@ -435,6 +454,7 @@ def _gru_fwd_pallas(x_proj, h0, w_hh_t, b_hh, *, block_b):
         out_shape=jax.ShapeDtypeStruct((seq_len, batch_p, hidden), dtype),
         scratch_shapes=[pltpu.VMEM((block_b, hidden), jnp.float32)],
         interpret=_interpret(),
+        name="gru_fwd",
     )(x_proj, h0, w_hh_t, b_hh)
 
 
@@ -547,19 +567,23 @@ def _gru_bwd(block_b, residuals, cotangents):
         x_proj, h_all, h0, w_hh_t, b_hh, dh_all, dh_T, block_b=block_b
     )
     # weight/bias grads as big MXU matmuls over all (t, b) at once
-    h_prev_all = jnp.concatenate([h0[None], h_all[:-1]], axis=0)
-    dw_hh = jnp.einsum("tbg,tbh->gh", dhgates, h_prev_all)  # (3H, H)
-    db_hh = jnp.sum(dhgates, axis=(0, 1))[None]             # (1, 3H)
-    return dx_proj, dw_hh.T, db_hh, dh0
+    with jax.named_scope("recurrence_wgrad"):
+        h_prev_all = jnp.concatenate([h0[None], h_all[:-1]], axis=0)
+        dw_hh = jnp.einsum("tbg,tbh->gh", dhgates, h_prev_all)  # (3H, H)
+        db_hh = jnp.sum(dhgates, axis=(0, 1))[None]             # (1, 3H)
+        return dx_proj, dw_hh.T, db_hh, dh0
 
 
 fused_gru_scan.defvjp(_gru_fwd, _gru_bwd)
 
 
-def gru_layer_fused(params, x, h0=None, *, block_b=None):
+def gru_layer_fused(params, x, h0=None, *, block_b=None,
+                    scope: str = "gru_layer"):
     """Drop-in replacement for ``ops.rnn.gru_layer`` running the time loop
     as a fused Pallas kernel.  Same params (torch layout, gate order
-    r, z, n), same results."""
+    r, z, n), same results.  ``scope`` as in :func:`lstm_layer_fused`:
+    the call into the kernels (``gru_fwd`` and its unnamed backward)
+    stays outside it."""
     batch, _, _ = x.shape
     hidden = params["w_hh"].shape[1]
     dtype = x.dtype
@@ -572,16 +596,19 @@ def gru_layer_fused(params, x, h0=None, *, block_b=None):
     from pytorch_distributed_rnn_tpu.ops.rnn import gru_input_proj
 
     # shared input projection (b_ih only; b_hh joins inside the kernel)
-    x_proj = jnp.swapaxes(gru_input_proj(params, x), 0, 1)  # (T, B, 3H)
-    if batch_p != batch:
-        x_proj = jnp.pad(x_proj, ((0, 0), (0, batch_p - batch), (0, 0)))
+    with jax.named_scope(f"{scope}/input_proj"):
+        x_proj = jnp.swapaxes(gru_input_proj(params, x), 0, 1)  # (T, B, 3H)
 
-    if h0 is None:
-        h0 = jnp.zeros((batch, hidden), dtype)
-    if batch_p != batch:
-        h0 = jnp.pad(h0, ((0, batch_p - batch), (0, 0)))
+    with jax.named_scope(f"{scope}/recurrence"):
+        if batch_p != batch:
+            x_proj = jnp.pad(x_proj, ((0, 0), (0, batch_p - batch), (0, 0)))
+        if h0 is None:
+            h0 = jnp.zeros((batch, hidden), dtype)
+        if batch_p != batch:
+            h0 = jnp.pad(h0, ((0, batch_p - batch), (0, 0)))
+        w_hh_t, b_hh = params["w_hh"].T, params["b_hh"][None]
 
-    h_all, h_T = fused_gru_scan(
-        x_proj, params["w_hh"].T, params["b_hh"][None], h0, block_b
-    )
-    return jnp.swapaxes(h_all, 0, 1)[:batch], h_T[:batch]
+    h_all, h_T = fused_gru_scan(x_proj, w_hh_t, b_hh, h0, block_b)
+
+    with jax.named_scope(f"{scope}/recurrence"):
+        return jnp.swapaxes(h_all, 0, 1)[:batch], h_T[:batch]

@@ -101,33 +101,39 @@ def lstm_step(w_hh_t, carry, xp_t):
     return (h, c), h.astype(xp_t.dtype)
 
 
-def lstm_layer(params, x, h0=None, c0=None, *, unroll: int = 1):
+def lstm_layer(params, x, h0=None, c0=None, *, unroll: int = 1,
+               scope: str = "lstm_layer"):
     """Run one LSTM layer over ``x`` of shape (B, T, in).
 
     Returns ``(outputs (B, T, H), (h_T, c_T))``.  The initial carry defaults
     to zeros, matching torch's ``nn.LSTM`` when no hidden state is passed.
+    ``scope`` names the layer in a profiler trace (``jax.named_scope``).
     """
     batch, _, _ = x.shape
     hidden = params["w_hh"].shape[1]
     dtype = x.dtype
 
-    x_proj = lstm_input_proj(params, x)
-    w_hh_t = params["w_hh"].T  # (H, 4H)
+    with jax.named_scope(f"{scope}/input_proj"):
+        x_proj = lstm_input_proj(params, x)
 
-    # carry lives in f32 regardless of compute dtype (lstm_step contract)
-    if h0 is None:
-        h0 = jnp.zeros((batch, hidden), jnp.float32)
-    if c0 is None:
-        c0 = jnp.zeros((batch, hidden), jnp.float32)
+    with jax.named_scope(f"{scope}/recurrence"):
+        w_hh_t = params["w_hh"].T  # (H, 4H)
 
-    # scan over time: move T to the leading axis.
-    (h_t, c_t), outputs = lax.scan(
-        lambda carry, xp_t: lstm_step(w_hh_t, carry, xp_t),
-        (h0.astype(jnp.float32), c0.astype(jnp.float32)),
-        jnp.swapaxes(x_proj, 0, 1),
-        unroll=unroll,
-    )
-    return jnp.swapaxes(outputs, 0, 1), (h_t.astype(dtype), c_t.astype(dtype))
+        # carry lives in f32 regardless of compute dtype (lstm_step contract)
+        if h0 is None:
+            h0 = jnp.zeros((batch, hidden), jnp.float32)
+        if c0 is None:
+            c0 = jnp.zeros((batch, hidden), jnp.float32)
+
+        # scan over time: move T to the leading axis.
+        (h_t, c_t), outputs = lax.scan(
+            lambda carry, xp_t: lstm_step(w_hh_t, carry, xp_t),
+            (h0.astype(jnp.float32), c0.astype(jnp.float32)),
+            jnp.swapaxes(x_proj, 0, 1),
+            unroll=unroll,
+        )
+        return (jnp.swapaxes(outputs, 0, 1),
+                (h_t.astype(dtype), c_t.astype(dtype)))
 
 
 def gru_step(w_hh_t, b_hh, h, xp_t):
@@ -152,31 +158,36 @@ def gru_step(w_hh_t, b_hh, h, xp_t):
     return h, h.astype(xp_t.dtype)
 
 
-def gru_layer(params, x, h0=None, *, unroll: int = 1):
+def gru_layer(params, x, h0=None, *, unroll: int = 1,
+              scope: str = "gru_layer"):
     """Run one GRU layer over ``x`` of shape (B, T, in).
 
     torch GRU semantics: ``n = tanh(x_n + b_in + r * (h @ w_hn.T + b_hn))``,
     ``h' = (1 - z) * n + z * h`` - note the hidden-side bias sits *inside*
     the ``r`` product, so it cannot be folded into the input projection.
+    ``scope`` names the layer in a profiler trace (``jax.named_scope``).
     """
     batch, _, _ = x.shape
     hidden = params["w_hh"].shape[1]
     dtype = x.dtype
 
-    x_proj = gru_input_proj(params, x)
-    w_hh_t = params["w_hh"].T  # (H, 3H)
-    b_hh = params["b_hh"]
+    with jax.named_scope(f"{scope}/input_proj"):
+        x_proj = gru_input_proj(params, x)
 
-    # carry in f32 (mixed-precision contract: matmuls in compute dtype,
-    # state accumulation in f32 - all casts no-ops in pure f32)
-    if h0 is None:
-        h0 = jnp.zeros((batch, hidden), jnp.float32)
+    with jax.named_scope(f"{scope}/recurrence"):
+        w_hh_t = params["w_hh"].T  # (H, 3H)
+        b_hh = params["b_hh"]
 
-    h_t, outputs = lax.scan(
-        lambda h, xp_t: gru_step(w_hh_t, b_hh, h, xp_t),
-        h0.astype(jnp.float32),
-        jnp.swapaxes(x_proj, 0, 1), unroll=unroll)
-    return jnp.swapaxes(outputs, 0, 1), h_t.astype(dtype)
+        # carry in f32 (mixed-precision contract: matmuls in compute dtype,
+        # state accumulation in f32 - all casts no-ops in pure f32)
+        if h0 is None:
+            h0 = jnp.zeros((batch, hidden), jnp.float32)
+
+        h_t, outputs = lax.scan(
+            lambda h, xp_t: gru_step(w_hh_t, b_hh, h, xp_t),
+            h0.astype(jnp.float32),
+            jnp.swapaxes(x_proj, 0, 1), unroll=unroll)
+        return jnp.swapaxes(outputs, 0, 1), h_t.astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -287,9 +298,6 @@ def stacked_rnn(
         layer_fn = gru_fn
     else:
         raise ValueError(f"unknown cell {cell!r}")
-    if remat:
-        layer_fn = jax.checkpoint(layer_fn)
-
     finals = []
     out = x
     if compute_dtype is not None:
@@ -299,7 +307,13 @@ def stacked_rnn(
             layer = jax.tree.map(
                 lambda p: p.astype(compute_dtype), layer
             )
-        out, final = layer_fn(layer, out)
+        # the layer names its own parts (`lstm_layer0/input_proj`, ...):
+        # the fused kernels must stay outside every scope but their own
+        # name (ops/pallas_rnn.py)
+        run_layer = partial(layer_fn, scope=f"{cell}_layer{idx}")
+        if remat:
+            run_layer = jax.checkpoint(run_layer)
+        out, final = run_layer(layer, out)
         finals.append(final)
         if dropout > 0.0 and dropout_key is not None and idx < len(layers) - 1:
             out, dropout_key = interlayer_dropout(out, dropout_key, dropout)
@@ -350,7 +364,8 @@ def head_logits(head, h):
     by the char/MoE model families and the serving adapters so batched
     serving can never drift from single-request ``generate`` numerics.
     ``head``: ``{"weight", "bias"}``; ``h``: (..., H) -> (..., vocab)."""
-    return h.astype(jnp.float32) @ head["weight"].T + head["bias"]
+    with jax.named_scope("head"):
+        return h.astype(jnp.float32) @ head["weight"].T + head["bias"]
 
 
 def interlayer_dropout(out, dropout_key, dropout: float):
@@ -358,7 +373,9 @@ def interlayer_dropout(out, dropout_key, dropout: float):
     by the unsharded stack above and the sp relay stacks
     (``parallel/sp.py``) - its placement/scaling being identical across
     paths is a tested contract.  Returns ``(masked_out, next_key)``."""
-    dropout_key, sub = jax.random.split(dropout_key)
-    keep = 1.0 - dropout
-    mask = jax.random.bernoulli(sub, keep, out.shape)
-    return jnp.where(mask, out / keep, 0.0).astype(out.dtype), dropout_key
+    with jax.named_scope("dropout"):
+        dropout_key, sub = jax.random.split(dropout_key)
+        keep = 1.0 - dropout
+        mask = jax.random.bernoulli(sub, keep, out.shape)
+        return (jnp.where(mask, out / keep, 0.0).astype(out.dtype),
+                dropout_key)

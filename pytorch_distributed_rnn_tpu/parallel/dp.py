@@ -68,7 +68,9 @@ def distributed_optimizer(optimizer, axis: str = "dp"):
         return optimizer.init(params)
 
     def update(grads, state, params=None):
-        return optimizer.update(pmean_tree(grads, axis), state, params)
+        with jax.named_scope("grad_reduce"):
+            grads = pmean_tree(grads, axis)
+        return optimizer.update(grads, state, params)
 
     return optax.GradientTransformation(init, update)
 
@@ -115,9 +117,11 @@ def _make_grad_step(loss_and_metrics, optimizer, axis: str, sync: str,
             loss_and_metrics, has_aux=True
         )(params, batch, *extra)
         if sync == "backward":
-            grads = pmean_tree(grads, axis)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+            with jax.named_scope("grad_reduce"):
+                grads = pmean_tree(grads, axis)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss, metrics
 
     return step
@@ -163,7 +167,7 @@ def make_spmd_train_step(
         out_specs=(rep, st, rep, rep),
         check_vma=False,
     )
-    def _step(params, opt_state, batch, *extra):
+    def train_step(params, opt_state, batch, *extra):
         params, opt_state, loss, metrics = grad_step(
             params, opt_state, batch, *extra
         )
@@ -174,7 +178,7 @@ def make_spmd_train_step(
             psum_tree(metrics, axis),
         )
 
-    return jax.jit(_step, donate_argnums=(0, 1) if donate else ())
+    return jax.jit(train_step, donate_argnums=(0, 1) if donate else ())
 
 
 def make_spmd_idx_train_step(
@@ -211,7 +215,7 @@ def make_spmd_idx_train_step(
         out_specs=(rep, st, rep, rep),
         check_vma=False,
     )
-    def _step(params, opt_state, features, labels, idx, *extra):
+    def train_step(params, opt_state, features, labels, idx, *extra):
         batch = (features[idx], labels[idx])
         params, opt_state, loss, metrics = grad_step(
             params, opt_state, batch, *extra
@@ -223,7 +227,7 @@ def make_spmd_idx_train_step(
             psum_tree(metrics, axis),
         )
 
-    return jax.jit(_step, donate_argnums=(0, 1) if donate else ())
+    return jax.jit(train_step, donate_argnums=(0, 1) if donate else ())
 
 
 def make_spmd_epoch_fn(
@@ -264,7 +268,7 @@ def make_spmd_epoch_fn(
         out_specs=(rep, st, rep, rep),
         check_vma=False,
     )
-    def _epoch(params, opt_state, features, labels, idx_mat, *key_mat):
+    def train_epoch(params, opt_state, features, labels, idx_mat, *key_mat):
         def body(carry, step_in):
             params, opt_state = carry
             idx = step_in[0] if with_key else step_in
@@ -287,7 +291,7 @@ def make_spmd_epoch_fn(
         )
         return params, opt_state, loss_sum, metrics_sum
 
-    return jax.jit(_epoch, donate_argnums=(0, 1) if donate else ())
+    return jax.jit(train_epoch, donate_argnums=(0, 1) if donate else ())
 
 
 def make_spmd_run_fn(
@@ -325,7 +329,8 @@ def make_spmd_run_fn(
         out_specs=(rep, st, rep, rep),
         check_vma=False,
     )
-    def _run(params, opt_state, features, labels, idx_mat, w_mat, *key_mat):
+    def train_run(params, opt_state, features, labels, idx_mat, w_mat,
+                  *key_mat):
         def body(carry, step_in):
             params, opt_state = carry
             idx, w = step_in[0], step_in[1]
@@ -351,7 +356,7 @@ def make_spmd_run_fn(
             jax.lax.psum(correct, axis),
         )
 
-    return jax.jit(_run, donate_argnums=(0, 1) if donate else ())
+    return jax.jit(train_run, donate_argnums=(0, 1) if donate else ())
 
 
 # ---------------------------------------------------------------------------

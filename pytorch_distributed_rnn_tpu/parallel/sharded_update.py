@@ -89,30 +89,37 @@ class ShardedUpdate:
         (local, unreduced) and ``opt_state`` in the sharded flat layout.
         Returns ``(params, opt_state)`` with params replicated again via
         the trailing allgather."""
-        flat_g, _ = ravel_pytree(grads)
-        flat_g = jnp.pad(flat_g, (0, self.padded - self.size))
-        # psum_scatter(tiled): this shard's slice of the summed gradient
-        # - the reduce-scatter half of what the allreduce used to move
-        g_shard = jax.lax.psum_scatter(
-            flat_g, self.axis, scatter_dimension=0, tiled=True
-        ) / self.world
-        if self.poison_nonfinite:
-            bad = jax.lax.psum(
-                (~jnp.all(jnp.isfinite(g_shard))).astype(jnp.float32),
-                self.axis,
+        # named for the profiler trace: grad_reduce / optimizer /
+        # param_gather are the three parts of the sharded schedule
+        with jax.named_scope("grad_reduce"):
+            flat_g, _ = ravel_pytree(grads)
+            flat_g = jnp.pad(flat_g, (0, self.padded - self.size))
+            # psum_scatter(tiled): this shard's slice of the summed
+            # gradient - the reduce-scatter half of what the allreduce
+            # used to move
+            g_shard = jax.lax.psum_scatter(
+                flat_g, self.axis, scatter_dimension=0, tiled=True
+            ) / self.world
+            if self.poison_nonfinite:
+                bad = jax.lax.psum(
+                    (~jnp.all(jnp.isfinite(g_shard))).astype(jnp.float32),
+                    self.axis,
+                )
+                g_shard = jnp.where(
+                    bad > 0, jnp.full_like(g_shard, jnp.nan), g_shard)
+        with jax.named_scope("optimizer"):
+            flat_p, unravel = ravel_pytree(params)
+            r = jax.lax.axis_index(self.axis)
+            p_shard = jax.lax.dynamic_slice(
+                jnp.pad(flat_p, (0, self.padded - self.size)),
+                (r * self.shard,), (self.shard,),
             )
-            g_shard = jnp.where(bad > 0, jnp.full_like(g_shard, jnp.nan),
-                                g_shard)
-        flat_p, unravel = ravel_pytree(params)
-        r = jax.lax.axis_index(self.axis)
-        p_shard = jax.lax.dynamic_slice(
-            jnp.pad(flat_p, (0, self.padded - self.size)),
-            (r * self.shard,), (self.shard,),
-        )
-        updates, opt_state = self.optimizer.update(g_shard, opt_state, p_shard)
-        p_shard = optax.apply_updates(p_shard, updates)
-        flat_new = jax.lax.all_gather(p_shard, self.axis, tiled=True)
-        return unravel(flat_new[: self.size]), opt_state
+            updates, opt_state = self.optimizer.update(
+                g_shard, opt_state, p_shard)
+            p_shard = optax.apply_updates(p_shard, updates)
+        with jax.named_scope("param_gather"):
+            flat_new = jax.lax.all_gather(p_shard, self.axis, tiled=True)
+            return unravel(flat_new[: self.size]), opt_state
 
     def abstract_opt_state(self):
         """Sharded-layout optimizer state as ``ShapeDtypeStruct`` leaves

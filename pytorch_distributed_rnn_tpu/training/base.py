@@ -33,6 +33,7 @@ import optax
 
 from pytorch_distributed_rnn_tpu.data.loader import DataLoader
 from pytorch_distributed_rnn_tpu.obs.recorder import NULL_RECORDER
+from pytorch_distributed_rnn_tpu.obs.spans import span
 from pytorch_distributed_rnn_tpu.data.prefetch import prefetch
 from pytorch_distributed_rnn_tpu.data.sampler import DistributedSampler
 from pytorch_distributed_rnn_tpu.ops.losses import cross_entropy_loss
@@ -303,19 +304,6 @@ class Trainer:
         correct = jnp.sum(jnp.argmax(logits, axis=1) == y)
         return loss, {"correct": correct}
 
-    def _weighted_loss_and_metrics(self, params, batch, w, key=None):
-        """Masked variant used by the fused whole-run program: ``w`` is a
-        0/1 weight per example.  With all-ones weights this equals
-        ``_loss_and_metrics`` exactly; with a zero-padded tail it equals
-        the reference's smaller final batch's mean (``base.py:46-51``).
-        Override together with ``_loss_and_metrics``."""
-        x, y = batch
-        logits = self._apply_model(params, x, key)
-        nll = cross_entropy_loss(logits, y, reduction="none")
-        loss = jnp.sum(nll * w) / jnp.sum(w)
-        correct = jnp.sum((jnp.argmax(logits, axis=1) == y) * (w > 0))
-        return loss, {"correct": correct}
-
     def _make_grad_step(self, loss_and_metrics):
         """The shared grad+update body: ``step(params, opt_state, batch,
         *extra) -> (params, opt_state, loss, metrics)``; ``*extra`` is
@@ -328,17 +316,18 @@ class Trainer:
         reassociation), at ~1/grad_accum the activation memory.  A dropout
         key in ``*extra`` is folded per microbatch (independent masks)."""
 
-        def single_shot(params, opt_state, batch, *extra):
+        def train_step(params, opt_state, batch, *extra):
             (loss, metrics), grads = jax.value_and_grad(
                 loss_and_metrics, has_aux=True
             )(params, batch, *extra)
-            updates, opt_state = self.optimizer.update(
-                grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = self.optimizer.update(
+                    grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, loss, metrics
 
         if self.grad_accum <= 1:
-            return single_shot
+            return train_step
 
         k_conf = self.grad_accum
 
@@ -366,7 +355,7 @@ class Trainer:
             # activations fit wherever the microbatched full ones did
             k = next(d for d in range(k_conf, 0, -1) if n % d == 0)
             if k == 1:
-                return single_shot(params, opt_state, batch, *extra)
+                return train_step(params, opt_state, batch, *extra)
             micro = jax.tree.map(
                 lambda a: a.reshape(k, n // k, *a.shape[1:]), batch
             )
@@ -400,12 +389,16 @@ class Trainer:
                 body, (zeros_g, jnp.zeros(()), zeros_m), xs
             )
             grads = jax.tree.map(lambda g: g / k, g_sum)
-            updates, opt_state = self.optimizer.update(
-                grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = self.optimizer.update(
+                    grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, l_sum / k, m_sum
 
         return accum_step
+
+    # One vocabulary names the jitted callables here and in parallel/dp.py:
+    # train_step, train_epoch, train_run, eval_step (`jit_<name>/` in a trace)
 
     def _build_train_step(self):
         """One fused XLA program: grad + update + metrics."""
@@ -414,19 +407,22 @@ class Trainer:
         )
 
     def _build_eval_step(self):
-        return jax.jit(self._loss_and_metrics)
+        def eval_step(params, batch):
+            return self._loss_and_metrics(params, batch)
+
+        return jax.jit(eval_step)  # noqa: PD103 - params are only read
 
     def _make_idx_train_step(self):
         """The un-jitted idx-gather step (sharding-aware subclasses re-jit
         it with layout constraints)."""
         grad_step = self._make_grad_step(self._loss_and_metrics)
 
-        def step(params, opt_state, features, labels, idx, *extra):
+        def train_step(params, opt_state, features, labels, idx, *extra):
             return grad_step(
                 params, opt_state, (features[idx], labels[idx]), *extra
             )
 
-        return step
+        return train_step
 
     def _build_idx_train_step(self):
         """Train step taking (params, opt_state, features, labels, idx,
@@ -439,7 +435,8 @@ class Trainer:
         grad_step = self._make_grad_step(self._loss_and_metrics)
         with_key = self._dropout > 0.0
 
-        def epoch(params, opt_state, features, labels, idx_mat, key_mat=None):
+        def train_epoch(params, opt_state, features, labels, idx_mat,
+                        key_mat=None):
             def body(carry, step_in):
                 idx = step_in[0] if with_key else step_in
                 extra = (step_in[1],) if with_key else ()
@@ -455,7 +452,7 @@ class Trainer:
             metrics_sum = jax.tree.map(lambda m: jnp.sum(m, axis=0), metrics)
             return params, opt_state, jnp.sum(losses), metrics_sum
 
-        return epoch
+        return train_epoch
 
     def _build_epoch_fn(self):
         """Whole-epoch program: ``lax.scan`` over the epoch's (num_batches,
@@ -468,8 +465,8 @@ class Trainer:
         grad_step = self._make_grad_step(self._weighted_loss_and_metrics)
         with_key = self._dropout > 0.0
 
-        def run(params, opt_state, features, labels, idx_mat, w_mat,
-                key_mat=None):
+        def train_run(params, opt_state, features, labels, idx_mat, w_mat,
+                      key_mat=None):
             def body(carry, step_in):
                 idx, w = step_in[0], step_in[1]
                 extra = (step_in[2],) if with_key else ()
@@ -484,7 +481,7 @@ class Trainer:
             )
             return params, opt_state, losses, correct
 
-        return run
+        return train_run
 
     def _build_run_fn(self):
         """The whole multi-epoch training run as ONE program: scan over
@@ -495,6 +492,9 @@ class Trainer:
 
     # -- dropout keys --------------------------------------------------------
 
+    # (benchmarks/tests/data's recorded trace names this def by its line,
+    # 498: test_trace_reduce.py fails if it moves, until the `benchmark`
+    # PR of ROADMAP S0 matches frames by name.  PERF.md section 7.)
     def _epoch_dropout_keys(self, epoch: int, num_batches: int):
         """Per-step dropout keys for one epoch, derived deterministically
         from (seed, epoch, batch index) so the batched scan path and the
@@ -527,16 +527,19 @@ class Trainer:
             features = np.asarray(self.training_set.features)
             labels = np.asarray(self.training_set.labels).reshape(-1)
             sharding = self._data_sharding()
-            if sharding is None:
-                self._device_data = (
-                    jax.device_put(features),
-                    jax.device_put(labels),
-                )
-            else:
-                self._device_data = (
-                    jax.device_put(features, sharding),
-                    jax.device_put(labels, sharding),
-                )
+            # not fenced: what of the copy outlasts the span is waited
+            # for by the first program that reads the arrays
+            with span("input.upload", self.recorder, split="train"):
+                if sharding is None:
+                    self._device_data = (
+                        jax.device_put(features),
+                        jax.device_put(labels),
+                    )
+                else:
+                    self._device_data = (
+                        jax.device_put(features, sharding),
+                        jax.device_put(labels, sharding),
+                    )
         return self._device_data
 
     def _epoch_index_batches(self):
@@ -548,6 +551,10 @@ class Trainer:
             indices[start : start + self.batch_size]
             for start in range(0, len(indices), self.batch_size)
         ]
+
+    def _steps_per_epoch(self) -> int:
+        """``len(self._epoch_index_batches())`` from the sizes alone."""
+        return -(-len(self.sampler) // self.batch_size)
 
     def _has_partial_batch(self) -> bool:
         """Whether epochs end in a smaller final batch (batch sizes are
@@ -664,6 +671,12 @@ class Trainer:
                 "pallas_interpret": interpret}
 
     def train(self, epochs: int):
+        # the root of the program's spans (obs/spans.py): everything a
+        # call does, test evaluation included, lies inside it
+        with span("train", epochs=epochs):
+            return self._train(epochs)
+
+    def _train(self, epochs: int):
         training_history: list[float] = []
         validation_history: list[float] = []
         formatter = self._get_formatter(epochs)
@@ -816,7 +829,7 @@ class Trainer:
             # at epoch (or step) boundaries
             and self._faults is None
             and self._start_epoch == 0
-            # step-bounded profiling addresses individual steps
+            # a step-bounded capture opens and closes between epochs
             and self._profile is None
             # per-step telemetry needs the host per epoch at least; an
             # EXPLICIT --fuse-run still wins (epoch-level events only)
@@ -852,28 +865,32 @@ class Trainer:
                     self.sampler.set_epoch(epoch)
                     self._epoch = epoch
                     logging.info(formatter.epoch_start_message(epoch))
-                    train_loss, train_acc = self._train_epoch(formatter)
-                    training_history.append(train_loss)
+                    with span("epoch", epoch=epoch,
+                              path=self._epoch_path()):
+                        train_loss, train_acc = self._train_epoch(formatter)
+                        training_history.append(train_loss)
 
-                    if (
-                        self.checkpoint_every
-                        and (epoch + 1) % self.checkpoint_every == 0
-                    ):
-                        self._save_checkpoint(epoch, train_loss, best=False)
-
-                    if self.validation_set is not None:
-                        validation_loss, _ = self._evaluate(
-                            self.validation_set, formatter, epoch
-                        )
-                        validation_history.append(validation_loss)
-                        if best_loss is None or best_loss > validation_loss:
-                            logging.info(
-                                f"New best model in epoch {epoch + 1}"
-                            )
-                            best_loss = validation_loss
+                        if (
+                            self.checkpoint_every
+                            and (epoch + 1) % self.checkpoint_every == 0
+                        ):
                             self._save_checkpoint(
-                                epoch, validation_loss, best=True
+                                epoch, train_loss, best=False)
+
+                        if self.validation_set is not None:
+                            validation_loss, _ = self._evaluate(
+                                self.validation_set, formatter, epoch
                             )
+                            validation_history.append(validation_loss)
+                            if (best_loss is None
+                                    or best_loss > validation_loss):
+                                logging.info(
+                                    f"New best model in epoch {epoch + 1}"
+                                )
+                                best_loss = validation_loss
+                                self._save_checkpoint(
+                                    epoch, validation_loss, best=True
+                                )
             finally:
                 # finally, and inside the timed region on purpose: an
                 # async sharded save that has not landed is training time
@@ -887,6 +904,19 @@ class Trainer:
         )
         self._last_device_peaks = device_peaks
         return memory, duration
+
+    def _weighted_loss_and_metrics(self, params, batch, w, key=None):
+        """Masked variant used by the fused whole-run program: ``w`` is a
+        0/1 weight per example.  With all-ones weights this equals
+        ``_loss_and_metrics`` exactly; with a zero-padded tail it equals
+        the reference's smaller final batch's mean (``base.py:46-51``).
+        Override together with ``_loss_and_metrics``."""
+        x, y = batch
+        logits = self._apply_model(params, x, key)
+        nll = cross_entropy_loss(logits, y, reduction="none")
+        loss = jnp.sum(nll * w) / jnp.sum(w)
+        correct = jnp.sum((jnp.argmax(logits, axis=1) == y) * (w > 0))
+        return loss, {"correct": correct}
 
     def _train_run_fused(self, epochs: int):
         """Run ``epochs`` epochs as one device program; returns the
@@ -913,17 +943,19 @@ class Trainer:
         w_mat = np.stack(w_rows)
         extra = (np.concatenate(key_rows),) if self._dropout > 0.0 else ()
 
-        self.params, self.opt_state, losses, correct = self._run_fn(
-            self.params, self.opt_state, features, labels, idx_mat, w_mat,
-            *extra,
-        )
+        with span("epoch.launch", program="train_run"):
+            self.params, self.opt_state, losses, correct = self._run_fn(
+                self.params, self.opt_state, features, labels, idx_mat,
+                w_mat, *extra,
+            )
         # the fused run's ONE host visit: the guard decides here - the
         # in-program apply_if_finite already rejected every non-finite
         # update, so the late check only delays the abort, never
         # corrupts state
         if self.guard is not None:
             self.guard.check(self.opt_state)
-        losses = np.asarray(losses).reshape(epochs, num_batches)
+        with span("epoch.fetch", program="train_run"):
+            losses = np.asarray(losses).reshape(epochs, num_batches)
         n = len(self.training_set)
         history = [float(losses[e].sum()) / n for e in range(epochs)]
         if self.recorder.enabled:
@@ -1035,46 +1067,78 @@ class Trainer:
         device-resident programs by design do not visit."""
         return self._faults is not None and self._faults.has_step_events
 
-    def _train_epoch(self, formatter):
+    def _epoch_path(self) -> str:
+        """Which loop :meth:`_train_epoch` runs an epoch in: ``host``
+        (materialized batches), ``step`` (one dispatch per batch from
+        device-resident data) or ``scan`` (one scanned program)."""
         if not self.DEVICE_DATA or self._chaos_host_loop():
-            return self._train_epoch_host(formatter)
-
+            return "host"
         # per-batch progress moved INFO -> DEBUG (conscious fix, PARITY.md):
         # each progress message needs loss/correct on host, serializing one
         # device round-trip per batch; at INFO the epoch runs as one
-        # scanned program and only epoch-level messages are emitted
+        # scanned program and only epoch-level messages are emitted.
+        # Telemetry also needs per-step dispatch (to time individual
+        # steps), but NOT per-step host values: losses stay device
+        # scalars until epoch end, and only the sampled fence cadence
+        # pays a device round-trip.  A --profile-steps capture needs
+        # neither: on the scan path it opens and closes between epochs.
+        if (logging.getLogger().isEnabledFor(logging.DEBUG)
+                or self.recorder.enabled):
+            return "step"
+        return "scan"
+
+    def _fetch(self, value, name: str, **attrs) -> float:
+        """A device scalar as a host float, under a ``*.fetch`` span:
+        ``float`` waits for the program that computes the value, so the
+        span holds what is left of that program's run time."""
+        with span(name, self.recorder, **attrs):
+            return float(value)
+
+    def _train_epoch(self, formatter):
+        epoch_path = self._epoch_path()
+        if epoch_path == "host":
+            return self._train_epoch_host(formatter)
+
         log_progress = logging.getLogger().isEnabledFor(logging.DEBUG)
-        # telemetry and step-bounded profiling also need per-step dispatch
-        # (to address/time individual steps), but NOT per-step host values:
-        # losses stay device scalars until epoch end, and only the sampled
-        # fence cadence pays a device round-trip
         recording = self.recorder.enabled
+        # run-relative step addresses, matching the host loop's
+        # convention (and _steps_done's documented contract): a
+        # resumed run's telemetry and --profile-steps ranges count
+        # steps EXECUTED THIS RUN on every strategy
+        step_base = self._steps_done
+        if self._profile is not None and epoch_path == "scan":
+            # the scanned epoch dispatches its steps as one program: a
+            # --profile-steps capture opens before the first epoch that
+            # holds one of its steps and closes after the last one's
+            # fetches, which are the fence
+            self._profile.on_step_start(
+                step_base, count=self._steps_per_epoch())
         features, labels = self._device_train_data()
-        batches = self._epoch_index_batches()
-        keys = (
-            self._epoch_dropout_keys(self._epoch, len(batches))
-            if self._dropout > 0.0
-            else None
-        )
+        with span("epoch.indices", self.recorder):
+            batches = self._epoch_index_batches()
+            if epoch_path == "scan":
+                # all equal-size batches as ONE index matrix, the final
+                # partial batch (if any) as one extra step
+                full, remainder = batches, None
+                if len(batches) > 1 and len(batches[-1]) != len(batches[0]):
+                    full, remainder = batches[:-1], batches[-1]
+                idx_mat = np.stack(full) if full else None
+        keys = None
+        if self._dropout > 0.0:
+            with span("epoch.dropout_keys", self.recorder):
+                keys = self._epoch_dropout_keys(self._epoch, len(batches))
         # host-side accumulators: each program's loss/metrics outputs are
         # replicated over the (possibly multi-process) mesh, so fetching
         # them immediately is legal on every rank - while accumulating
         # into a process-LOCAL device zero can land the sum on a device
-        # other controllers cannot address.  Cost: at most two fetches per
-        # epoch on the fast path (whole-epoch program + optional remainder
+        # other controllers cannot address.  Cost: two fetches per program
+        # on the fast path (whole-epoch program + optional remainder
         # step), values the host needs for history/logging anyway.
         total_loss = 0.0
         total_correct = 0.0
         t_epoch = time.perf_counter()
-        epoch_path = "scan"
 
-        if log_progress or recording or self._profile is not None:
-            epoch_path = "step"
-            # run-relative step addresses, matching the host loop's
-            # convention (and _steps_done's documented contract): a
-            # resumed run's telemetry and --profile-steps ranges count
-            # steps EXECUTED THIS RUN on every strategy
-            step_base = self._steps_done
+        if epoch_path == "step":
             losses, corrects, raw = [], [], []
             for batch_idx, idx in enumerate(batches):
                 step = step_base + batch_idx
@@ -1122,8 +1186,9 @@ class Trainer:
                     corrects.append(metrics["correct"])
                 if recording:
                     raw.append((step, t0, dispatch_s, fenced_s))
-            total_loss = sum(float(l) for l in losses)
-            total_correct = sum(float(c) for c in corrects)
+            with span("epoch.fetch", self.recorder, program="train_step"):
+                total_loss = sum(float(l) for l in losses)
+                total_correct = sum(float(c) for c in corrects)
             if recording:
                 # step events are emitted AFTER the loop: the deferred
                 # float() fetches here are the same epoch-end fetch the
@@ -1140,34 +1205,42 @@ class Trainer:
                         data_wait_s=0.0, fenced_s=fenced_s, tm=t0,
                     )
         else:
-            # fast path: all equal-size batches as ONE scanned program,
-            # the final partial batch (if any) as one extra step
-            full = batches
-            remainder = None
-            if len(batches) > 1 and len(batches[-1]) != len(batches[0]):
-                full, remainder = batches[:-1], batches[-1]
+            # fast path: one scanned program and at most one extra step
+            # (never with a recorder on: these spans reach the log and
+            # the profiler only)
             if full:
-                idx_mat = np.stack(full)
                 extra = (keys[: len(full)],) if keys is not None else ()
-                (
-                    self.params,
-                    self.opt_state,
-                    loss_sum,
-                    metrics_sum,
-                ) = self._epoch_fn(
-                    self.params, self.opt_state, features, labels, idx_mat,
-                    *extra,
-                )
-                total_loss += float(loss_sum)
-                total_correct += float(metrics_sum["correct"])
+                with span("epoch.launch", program="train_epoch"):
+                    (
+                        self.params,
+                        self.opt_state,
+                        loss_sum,
+                        metrics_sum,
+                    ) = self._epoch_fn(
+                        self.params, self.opt_state, features, labels,
+                        idx_mat, *extra,
+                    )
+                total_loss += self._fetch(
+                    loss_sum, "epoch.fetch", program="train_epoch")
+                total_correct += self._fetch(
+                    metrics_sum["correct"], "epoch.fetch",
+                    program="train_epoch")
             if remainder is not None:
                 extra = (keys[-1],) if keys is not None else ()
-                self.params, self.opt_state, loss, metrics = self._idx_step_fn(
-                    self.params, self.opt_state, features, labels, remainder,
-                    *extra,
-                )
-                total_loss += float(loss)
-                total_correct += float(metrics["correct"])
+                with span("epoch.launch", program="train_step"):
+                    (
+                        self.params, self.opt_state, loss, metrics,
+                    ) = self._idx_step_fn(
+                        self.params, self.opt_state, features, labels,
+                        remainder, *extra,
+                    )
+                total_loss += self._fetch(
+                    loss, "epoch.fetch", program="train_step")
+                total_correct += self._fetch(
+                    metrics["correct"], "epoch.fetch", program="train_step")
+            self._steps_done = step_base + len(batches)
+            if self._profile is not None:
+                self._profile.on_step_end(self._steps_done - 1)
 
         # parity quirk kept: sum of batch-mean losses / dataset size
         train_loss = total_loss / len(self.training_set)
@@ -1376,18 +1449,28 @@ class Trainer:
         # cache holds (dataset, batch): the strong reference keeps id()
         # stable (a collected dataset's id could be reused by a new one)
         key = id(dataset)
+        split = "validation" if dataset is self.validation_set else "test"
         cached = self._eval_data_cache.get(key)
         if cached is None or cached[0] is not dataset:
             features, labels = dataset[np.arange(len(dataset))]
-            cached = (dataset, self._prepare_batch(features, labels))
+            with span("input.upload", self.recorder, split=split):
+                batch = self._prepare_batch(features, labels)
+            cached = (dataset, batch)
             self._eval_data_cache[key] = cached
         batch = cached[1]
-        # the float() fetch below fences the eval program, so the span's
+        # the fetches below fence the eval program, so the span's
         # extent is the honest wall time of the whole evaluation
-        with self.recorder.span("eval", cat="eval", epoch=epoch):
-            loss, metrics = self._eval_step_fn(self.params, batch)
-            eval_loss = float(loss)  # one batch -> already the mean
-            total_correct = float(metrics["correct"])
+        with span("eval", self.recorder, cat="eval", epoch=epoch,
+                  split=split):
+            with span("eval.launch", self.recorder, cat="eval",
+                      program="eval_step"):
+                loss, metrics = self._eval_step_fn(self.params, batch)
+            # one batch -> already the mean
+            eval_loss = self._fetch(
+                loss, "eval.fetch", cat="eval", program="eval_step")
+            total_correct = self._fetch(
+                metrics["correct"], "eval.fetch", cat="eval",
+                program="eval_step")
         num_examples = len(dataset)
         accuracy = total_correct / num_examples
         self.recorder.record(
@@ -1434,7 +1517,8 @@ class Trainer:
         if self.checkpoint_dir is None:
             return
         t0 = time.perf_counter()
-        self._write_checkpoint(epoch, loss, best)
+        with span("checkpoint.save"):
+            self._write_checkpoint(epoch, loss, best)
         self.recorder.record(
             "checkpoint_save", epoch=epoch, best=bool(best),
             seconds=time.perf_counter() - t0,
@@ -1479,7 +1563,10 @@ class Trainer:
         """Block until the in-flight async sharded save (if any) is
         durable; called before the next save and at train end."""
         if self._pending_ckpt is not None:
-            self._pending_ckpt.wait()
+            # in the log and the profiler trace only: inside a save the
+            # `checkpoint_save` event already carries this time
+            with span("checkpoint.drain"):
+                self._pending_ckpt.wait()
             self._pending_ckpt = None
 
     def resume_from(self, checkpoint_path, advance_epoch: bool = False):

@@ -248,8 +248,12 @@ class SpmdTrainer(Trainer):
         partitioned. Please wrap the call in a shard_map" (CHANGES.md
         PR 21; interpret mode on the CPU mesh never reaches Mosaic)."""
         rep = P()
+
+        def eval_step(params, batch):
+            return self._loss_and_metrics(params, batch)
+
         return jax.jit(shard_map(
-            self._loss_and_metrics, mesh=self.mesh, in_specs=(rep, rep),
+            eval_step, mesh=self.mesh, in_specs=(rep, rep),
             out_specs=rep, check_vma=False,
         ))
 
@@ -280,6 +284,10 @@ class SpmdTrainer(Trainer):
             shards[:, start : start + per_rank_bs].reshape(-1)
             for start in range(0, num_samples, per_rank_bs)
         ]
+
+    def _steps_per_epoch(self) -> int:
+        per_rank_bs = max(1, self.batch_size // self.world_size)
+        return -(-len(self.sampler) // per_rank_bs)
 
     def _pad_batch(self, b, full_size):
         """Rank-major padding: each rank's chunk is padded independently so

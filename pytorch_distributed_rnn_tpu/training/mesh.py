@@ -335,12 +335,12 @@ class MeshTrainer(SpmdTrainer):
             self._mesh_loss_fn(weighted=False), self.optimizer
         )
 
-        def step(params, opt_state, features, labels, idx, *extra):
+        def train_step(params, opt_state, features, labels, idx, *extra):
             return grad_step(
                 params, opt_state, (features[idx], labels[idx]), *extra
             )
 
-        return self._jit_replicated(step)
+        return self._jit_replicated(train_step)
 
     def _build_epoch_fn(self):
         grad_step = make_mesh_grad_step(
@@ -348,8 +348,8 @@ class MeshTrainer(SpmdTrainer):
         )
         with_key = self._dropout > 0.0
 
-        def epoch(params, opt_state, features, labels, idx_mat,
-                  key_mat=None):
+        def train_epoch(params, opt_state, features, labels, idx_mat,
+                        key_mat=None):
             def body(carry, step_in):
                 idx = step_in[0] if with_key else step_in
                 extra = (step_in[1],) if with_key else ()
@@ -367,7 +367,7 @@ class MeshTrainer(SpmdTrainer):
             )
             return params, opt_state, jax.numpy.sum(losses), metrics_sum
 
-        return self._jit_replicated(epoch)
+        return self._jit_replicated(train_epoch)
 
     def _build_run_fn(self):
         grad_step = make_mesh_grad_step(
@@ -375,8 +375,8 @@ class MeshTrainer(SpmdTrainer):
         )
         with_key = self._dropout > 0.0
 
-        def run(params, opt_state, features, labels, idx_mat, w_mat,
-                key_mat=None):
+        def train_run(params, opt_state, features, labels, idx_mat, w_mat,
+                      key_mat=None):
             def body(carry, step_in):
                 idx, w = step_in[0], step_in[1]
                 extra = (step_in[2],) if with_key else ()
@@ -391,7 +391,7 @@ class MeshTrainer(SpmdTrainer):
             )
             return params, opt_state, losses, correct
 
-        return self._jit_replicated(run)
+        return self._jit_replicated(train_run)
 
 
 def mesh_trainer_factory(args):

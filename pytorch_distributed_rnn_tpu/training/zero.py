@@ -107,12 +107,13 @@ class ZeroTrainer(SpmdTrainer):
 
     def _build_eval_step(self):
         # eval shards the full-dataset batch too (parallel evaluation)
-        def eval_fn(params, batch, *extra):
+        def eval_step(params, batch, *extra):
             return self._loss_and_metrics(
                 params, self._shard_batch(batch), *extra
             )
 
-        return jax.jit(eval_fn)
+        # evaluation reads the params: nothing to donate
+        return jax.jit(eval_step)  # noqa: PD103
 
     # -- checkpointing -------------------------------------------------------
 

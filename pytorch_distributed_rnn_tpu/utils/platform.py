@@ -28,6 +28,15 @@ _DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 # (process-global like the cache itself); read via compile_cache_stats()
 _CACHE_EVENTS: Counter = Counter()
 _CACHE_EVENT_PREFIX = "/jax/compilation_cache/"
+# jax.monitoring's duration events for what the compile cache cannot
+# save (tracing, lowering) and for reading it -> the program span each is
+# logged as (obs/spans.py).  Tracing of an inner jit nests in its
+# caller's, so durations of one name overlap and are not to be summed.
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_read",
+}
 _listening = False
 
 
@@ -90,6 +99,8 @@ def enable_compile_cache() -> None:
 
     if not _listening:
         jax.monitoring.register_event_listener(_count_cache_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            _note_compile_span)
         _listening = True
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
@@ -109,6 +120,17 @@ def enable_compile_cache() -> None:
 def _count_cache_event(event: str, **_) -> None:
     if event.startswith(_CACHE_EVENT_PREFIX):
         _CACHE_EVENTS[event[len(_CACHE_EVENT_PREFIX):]] += 1
+
+
+def _note_compile_span(event: str, duration_s: float, **attrs) -> None:
+    name = _COMPILE_SPANS.get(event)
+    if name is not None:
+        # lazily: obs/ imports this package's utils
+        from pytorch_distributed_rnn_tpu.obs import spans
+
+        # the event fires as the phase ends, on the thread that ran it:
+        # the span open there (a launch, mostly) is what caused it
+        spans.note_finished(name, duration_s, **attrs)
 
 
 def compile_cache_stats() -> dict:

@@ -120,8 +120,10 @@ class TestRecorder:
 
 class TestSpansAndHeartbeats:
     def test_span_context_manager_emits_dual_stamped_event(self, tmp_path):
+        from pytorch_distributed_rnn_tpu.obs.spans import span
+
         rec = MetricsRecorder(tmp_path / "m.jsonl")
-        with rec.span("eval", cat="eval", epoch=3):
+        with span("eval", rec, cat="eval", epoch=3):
             time.sleep(0.02)
         rec.close()
         spans = [
@@ -152,13 +154,15 @@ class TestSpansAndHeartbeats:
         assert spans[0]["tm"] == pytest.approx(t0)
         assert spans[0]["dur_s"] == pytest.approx(0.25)
 
-    def test_null_recorder_span_is_shared_noop(self):
-        from pytorch_distributed_rnn_tpu.obs.spans import NULL_SPAN
+    def test_null_recorder_span_emits_nothing(self, monkeypatch):
+        from pytorch_distributed_rnn_tpu.obs.spans import span
 
-        s1 = NULL_RECORDER.span("anything", cat="ps", step=1)
-        assert s1 is NULL_SPAN and s1 is NULL_RECORDER.span("other")
-        with s1:
-            pass
+        with monkeypatch.context() as patch:
+            patch.setattr(
+                type(NULL_RECORDER), "emit_span",
+                lambda *a, **k: pytest.fail("a disabled recorder was called"))
+            with span("anything", NULL_RECORDER, cat="ps", step=1):
+                pass
         NULL_RECORDER.emit_span("x", 0.0, 1.0)  # no-op, no file
         NULL_RECORDER.note_progress(7)
 
