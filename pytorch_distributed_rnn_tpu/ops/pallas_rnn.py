@@ -96,11 +96,23 @@ def _bwd_vmem_bytes(block_b: int, hidden: int, itemsize: int) -> int:
 
 def _pick_block_b(batch: int, hidden: int = 32, itemsize: int = 4) -> int:
     """Batch tile: large enough to keep the MXU/VPU busy, small enough that
-    the backward kernel's working set fits the scoped-VMEM budget.  When
-    the VMEM cap does not bind, tiles waste at most 7 padded rows (e.g.
-    1440 -> 3 tiles of 480, not 3 tiles of 512); when it does, the tile
-    count rises and padding can exceed that (1440 at H=512 f32 -> 7 tiles
-    of 208 = 16 padded rows)."""
+    the backward kernel's working set fits the scoped-VMEM budget, and
+    where possible a divisor of ``batch``.
+
+    ``cap`` is the largest multiple of 8 (at most 512) whose backward
+    working set fits.  The largest multiple of 8 in ``[cap / 2, cap]``
+    that divides ``batch`` is taken when there is one (8640 at H=32 ->
+    18 tiles of 480; 1440 at H=512 f32 -> 9 tiles of 160): the layer
+    then pads nothing.  The lower limit keeps a batch like 8 x 541 from
+    getting 541 tiles of 8.  Otherwise the batch is split into
+    ``ceil(batch / cap)`` tiles rounded up to a multiple of 8, which
+    wastes up to 7 padded rows a TILE (4410 at H=32 -> 9 tiles of 496 =
+    54 rows).  The rows are the small part of what padding costs: any
+    padding at all makes the layer write a padded copy of the whole
+    ``(T, B, 4H)`` projection before the forward kernel and of the
+    ``(T, B, H)`` cotangent before the backward kernel (measured on the
+    v5e at batch 8640 -> 8704: 12 % of the step's device time, PERF.md
+    Findings PR 25)."""
     cap = 512
     while cap > 8 and _bwd_vmem_bytes(cap, hidden, itemsize) > _VMEM_BUDGET:
         cap -= 8
@@ -116,6 +128,9 @@ def _pick_block_b(batch: int, hidden: int = 32, itemsize: int = 4) -> int:
             f"{4 * hidden * hidden * itemsize / 2**20:.1f}MB); "
             "use impl='scan' for this size"
         )
+    for block_b in range(cap, cap // 2 - 1, -8):
+        if batch % block_b == 0:
+            return block_b
     num_tiles = -(-batch // cap)
     return min(cap, _round_up(-(-batch // num_tiles), 8))
 

@@ -109,7 +109,10 @@ def _rnn_case(cell, hidden, batch, seq, in_dim, dtype_name):
     name = f"{cell}_fused h{hidden} b{batch} t{seq} in{in_dim}"
     row = _compare(name, lambda p, xx: fused(p, xx)[0],
                    lambda p, xx: ref(p, xx)[0], (params, x), dtype_name)
-    row["block_b"] = pallas_rnn._pick_block_b(batch, hidden, dtype.itemsize)
+    block_b = pallas_rnn._pick_block_b(batch, hidden, dtype.itemsize)
+    row["block_b"] = block_b
+    # any padded row costs a padded copy of the layer's (T, B, 4H) arrays
+    row["padded_rows"] = -batch % block_b
     return row
 
 

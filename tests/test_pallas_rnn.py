@@ -230,9 +230,9 @@ def test_pick_block_b_respects_vmem_budget():
     assert _pick_block_b(256, 512, 2) == 256
     # the motion model's regime is unchanged: big tiles, tiny VMEM
     assert _pick_block_b(1440, 32, 4) == 480
-    # under the cap the tile still hugs ceil(batch/num_tiles): 7 tiles
-    # of 208 (16 padded rows), not e.g. 7 tiles of the 208-capped 512
-    assert _pick_block_b(1440, 512, 4) == 208
+    # under the cap (208) an exact tile wins: 9 tiles of 160, not 7 tiles
+    # of 208 with 16 padded rows and a padded copy of every array
+    assert _pick_block_b(1440, 512, 4) == 160
 
 
 def test_pick_block_b_unfittable_hidden_raises_on_tpu(monkeypatch):
@@ -249,3 +249,133 @@ def test_pick_block_b_unfittable_hidden_raises_on_tpu(monkeypatch):
     with pytest.raises(ValueError, match="impl='scan'"):
         pallas_rnn._pick_block_b(256, 1024, 4)
     assert pallas_rnn._pick_block_b(256, 512, 4) <= 128  # fittable unaffected
+
+
+# (batch, hidden, itemsize, tile, padded rows): who runs each is in
+# PERF.md, Findings PR 25
+PICKED_TILES = [
+    (8640, 32, 4, 480, 0),     # the HAR cells' training step: was 512 (64)
+    (4608, 32, 4, 512, 0),     # their remainder step
+    (4410, 32, 4, 496, 54),    # HAR validation: no multiple-of-8 divisor
+    (17682, 32, 4, 512, 238),  # HAR test set: the same
+    (2000, 512, 4, 200, 0),    # the LM cell's training step
+    (3250, 512, 4, 208, 78),   # LM validation / test
+    (1440, 32, 4, 480, 0),     # the reference's batch
+    (1440, 512, 4, 160, 0),    # was 208 (16)
+    (2048, 512, 4, 128, 0),    # was 208 (32), which the compiler refuses
+    (1024, 512, 4, 128, 0),    # was 208 (16)
+    (1760, 512, 4, 176, 0),    # was 200 (40)
+    (8 * 541, 32, 4, 488, 64),  # its only exact tile is 8: not taken
+]
+
+
+@pytest.mark.parametrize("batch,hidden,itemsize,tile,padded", PICKED_TILES)
+def test_pick_block_b_prefers_a_tile_that_divides_the_batch(
+        batch, hidden, itemsize, tile, padded):
+    from pytorch_distributed_rnn_tpu.ops.pallas_rnn import _pick_block_b
+
+    picked = _pick_block_b(batch, hidden, itemsize)
+    assert picked == tile
+    assert -batch % picked == padded
+
+
+@pytest.mark.parametrize("hidden,itemsize", [(32, 4), (512, 4), (512, 2)])
+def test_pick_block_b_contract_for_every_batch(hidden, itemsize):
+    """Every multiple of 8 up to 9,000: the tile is a multiple of 8 under
+    the VMEM cap, pads nothing whenever a multiple of 8 in [cap / 2, cap]
+    divides the batch, and is otherwise the ceil(batch / cap)-tiles split
+    the picker made before it looked for divisors (so never under half
+    of that split's tile)."""
+    from pytorch_distributed_rnn_tpu.ops.pallas_rnn import (
+        _bwd_vmem_bytes,
+        _pick_block_b,
+        _round_up,
+        _VMEM_BUDGET,
+    )
+
+    cap = max(b for b in range(8, 513, 8)
+              if _bwd_vmem_bytes(b, hidden, itemsize) <= _VMEM_BUDGET)
+    exact = 0
+    for batch in range(8, 9001, 8):
+        tile = _pick_block_b(batch, hidden, itemsize)
+        num_tiles = -(-batch // cap)
+        split = min(cap, _round_up(-(-batch // num_tiles), 8))
+        divisors = [b for b in range(8, cap + 1, 8)
+                    if 2 * b >= cap and batch % b == 0]
+        assert tile % 8 == 0 and 8 <= tile <= cap, (batch, tile)
+        assert 2 * tile >= split, (batch, tile, split)
+        if divisors:
+            assert tile == max(divisors), (batch, tile)
+            exact += 1
+        else:
+            assert tile == split, (batch, tile, split)
+    assert exact > 100  # the sweep did meet both branches
+
+
+def _fused_layer(cell):
+    from pytorch_distributed_rnn_tpu.ops import pallas_rnn, rnn
+
+    return {"lstm": (rnn.init_lstm_layer, pallas_rnn.lstm_layer_fused),
+            "gru": (rnn.init_gru_layer, pallas_rnn.gru_layer_fused)}[cell]
+
+
+def _jaxpr_dims(jaxpr):
+    """Every dimension of every array in ``jaxpr`` and the jaxprs under it
+    (kernel bodies included: their blocks are tiles, not batches)."""
+    from pytorch_distributed_rnn_tpu.lint.jaxpr_pass import _subjaxprs
+
+    dims = set()
+    for v in [*jaxpr.invars, *jaxpr.constvars,
+              *(o for e in jaxpr.eqns for o in e.outvars)]:
+        dims.update(getattr(v.aval, "shape", ()))
+    for eqn in jaxpr.eqns:
+        for sub in _subjaxprs(eqn):
+            dims |= _jaxpr_dims(sub)
+    return dims
+
+
+@pytest.mark.parametrize("cell", ["lstm", "gru"])
+@pytest.mark.parametrize("batch,padded_to", [(8640, None), (4410, 4464)])
+def test_fused_grad_pads_the_batch_only_without_an_exact_tile(
+        cell, batch, padded_to):
+    """The HAR cells' training batch, 8,640 at H 32, gets 18 tiles of 480:
+    no array of the gradient's program has a padded batch dimension (the
+    parent's 17 tiles of 512 made 8,704 of it, at a copy of the (T, B, 4H)
+    projection and of the cotangent each).  The validation batch 4,410 has
+    no exact tile and is still padded, to 9 tiles of 496."""
+    init, fused = _fused_layer(cell)
+    params = init(jax.random.PRNGKey(0), 4, 32)
+    x = jax.ShapeDtypeStruct((batch, 3, 4), jnp.float32)
+
+    def loss(p, x):
+        out, _ = fused(p, x)
+        return jnp.sum(out[:, -1] ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x)
+    batch_dims = {d for d in _jaxpr_dims(jaxpr.jaxpr) if d > 512}
+    assert batch_dims == ({batch} if padded_to is None
+                          else {batch, padded_to})
+
+
+@pytest.mark.parametrize("cell", ["lstm", "gru"])
+def test_fused_exact_tile_matches_a_padded_tile(cell):
+    """Rows are independent in the kernels and padded rows are zeros that
+    add zeros to the weight gradient: outputs and every gradient at the
+    picked tile (480, nothing padded) equal those at a forced 512 (8,704
+    rows) but for the order of the sums over the batch."""
+    init, fused = _fused_layer(cell)
+    params = init(jax.random.PRNGKey(1), 4, 32)
+    x = jax.random.normal(jax.random.PRNGKey(2), (8640, 3, 4), jnp.float32)
+
+    def run(block_b):
+        def loss(p, x):
+            out, finals = fused(p, x, block_b=block_b)
+            return (jnp.sum(out ** 2) + sum(
+                jnp.sum(f ** 2) for f in jax.tree.leaves(finals))) / 8640, out
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))(params, x)
+
+    picked, forced = run(None), run(512)
+    for a, b in zip(jax.tree.leaves(picked), jax.tree.leaves(forced)):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
