@@ -25,13 +25,17 @@ import numpy as np
 
 log = logging.getLogger(__name__)
 
-VOCAB_SIZE = 256  # byte-level
+VOCAB_SIZE = 256  # a byte corpus; what a dataset declares by default
 
 
 class TextDataset:
-    """``features``: (N, seq_length + 1) int32 token windows."""
+    """``features``: (N, seq_length + 1) int32 token windows.
 
-    def __init__(self, windows: np.ndarray):
+    ``vocab_size`` is what the data declares: the argument, else 256 (a
+    byte corpus), or more where the windows hold larger ids (windows of
+    another tokenizer's ids, handed in as arrays)."""
+
+    def __init__(self, windows: np.ndarray, vocab_size: int | None = None):
         windows = np.asarray(windows)
         if windows.ndim != 2 or windows.shape[1] < 2:
             raise ValueError(
@@ -40,7 +44,14 @@ class TextDataset:
         self.features = windows.astype(np.int32)
         self.labels = np.zeros(len(windows), np.int32)  # loader/sampler compat
         self.seq_length = self.features.shape[1] - 1
-        self.vocab_size = VOCAB_SIZE
+        largest = int(self.features.max()) if self.features.size else 0
+        if vocab_size is None:
+            vocab_size = max(VOCAB_SIZE, largest + 1)
+        elif largest >= vocab_size:
+            raise ValueError(
+                f"token id {largest} does not fit a vocabulary of "
+                f"{vocab_size}")
+        self.vocab_size = int(vocab_size)
 
     def __getitem__(self, index):
         return self.features[index], self.labels[index]
@@ -71,6 +82,7 @@ class TextDataset:
         test_fraction: float = 0.1,
         seed: int | None = None,
         synthetic_sequences: int = 2048,
+        vocab_size: int | None = None,
     ):
         """(train, validation, test) token-window datasets.
 
@@ -78,6 +90,9 @@ class TextDataset:
         ``corpus.txt``; otherwise the synthetic motif stream is generated
         (deterministic in ``seed``).  Windows are shuffled with ``seed``
         before the split so the three sets are i.i.d. slices of the corpus.
+        ``vocab_size`` (the ``--vocab-size`` flag) is what the three sets
+        declare, and the range the synthetic stream draws from; a corpus
+        file stays bytes.
         """
         corpus_file = cls.resolve_corpus(dataset_path)
         if corpus_file is None and dataset_path is not None:
@@ -112,7 +127,8 @@ class TextDataset:
             )
 
             windows = generate_char_tokens(
-                synthetic_sequences, seq_length, VOCAB_SIZE, seed=seed or 0
+                synthetic_sequences, seq_length, vocab_size or VOCAB_SIZE,
+                seed=seed or 0,
             )
 
         rng = np.random.RandomState(seed if seed is not None else 0)
@@ -121,7 +137,7 @@ class TextDataset:
         n = len(windows)
         n_test = max(1, int(n * test_fraction))
         n_valid = max(1, int(n * validation_fraction))
-        test = cls(windows[:n_test])
-        valid = cls(windows[n_test : n_test + n_valid])
-        train = cls(windows[n_test + n_valid :])
+        test = cls(windows[:n_test], vocab_size)
+        valid = cls(windows[n_test : n_test + n_valid], vocab_size)
+        train = cls(windows[n_test + n_valid :], vocab_size)
         return train, valid, test

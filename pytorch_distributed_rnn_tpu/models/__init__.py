@@ -5,6 +5,7 @@ from pytorch_distributed_rnn_tpu.models.char_rnn import (
     char_rnn_50m,
     num_params,
 )
+from pytorch_distributed_rnn_tpu.models.mla_moe_lm import MlaMoeLM
 from pytorch_distributed_rnn_tpu.models.moe import MoEClassifier
 from pytorch_distributed_rnn_tpu.models.moe_lm import MoELM
 from pytorch_distributed_rnn_tpu.models.motion import MotionModel
@@ -16,6 +17,7 @@ __all__ = [
     "CharRNN",
     "char_rnn_50m",
     "num_params",
+    "MlaMoeLM",
     "MoEClassifier",
     "MoELM",
     "MotionModel",

@@ -1,0 +1,372 @@
+"""Decoder LM of the DeepSeek-V3 kind, as ONE chip of an expert-parallel
+deployment trains it: latent attention, sigmoid top-k routing over experts
+of which this chip holds a share, a shared expert, and a multi-token
+prediction module (arXiv 2412.19437, sections 2.1 and 2.2; the key names of
+its public ``config.json`` are given beside each field).
+
+The layer equations (RMSNorm everywhere, no biases, ``x`` the residual
+stream):
+
+- attention: ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` -> heads of
+  ``[q_nope | q_rope]``; ``[c_kv | k_rope] = x W_kva`` (``k_rope`` shared by
+  all heads); ``[k_nope | v] = RMSNorm(c_kv) W_kvb`` per head; rotary
+  embedding on ``q_rope`` / ``k_rope`` over interleaved pairs ``(2i, 2i+1)``;
+  causal ``softmax(q k^T / sqrt(nope + rope)) v``; ``W_o``.  Pre-norm
+  residual.  ``q`` / ``k`` are ``nope + rope`` wide and ``v`` is ``v_dim``
+  wide: the flash kernels take a value width of their own
+  (``ops/pallas_attention.py``).
+- dense MLP (the first ``dense_layers`` layers): ``W_down(silu(x W_gate) *
+  x W_up)``.
+- expert layer: ``s = sigmoid(x W_r)`` in f32 over ALL ``num_experts``; the
+  ``num_selected`` largest of ``s + b`` picked (``b`` a buffer outside the
+  gradient); ``w = route_scale * s[picked] / sum(s[picked])``;
+  ``y = Shared(x) + sum over picked experts HELD HERE of w_e Expert_e(x)``.
+  The chip holds experts ``experts_first`` to ``experts_first +
+  experts_held - 1``; what the others would add is their chips' to add
+  (``ops/moe.py:held_experts_ffn``).  On one chip the layer runs without
+  its exchange; nothing stands in for the absent chips.
+- prediction module (``mtp_weight > 0``): ``h' = [RMSNorm(Emb(t_{i+1})) ;
+  RMSNorm(h_i)] W_eh``, one more block of the expert-layer kind, the main
+  model's embedding and head shared, a final RMSNorm of its own, logits
+  for ``t_{i+2}``; ``loss = CE_main + mtp_weight * CE_mtp``.  ``h_i`` is the
+  last layer's output before the main model's final norm.
+
+The vocabulary may be a slice too (``vocab_size`` rows of embedding and
+head): a sliced vocabulary is a smaller vocabulary, and the data draws its
+ids from it.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from pytorch_distributed_rnn_tpu.ops.moe import (
+    held_experts_ffn,
+    route_sigmoid_topk,
+)
+
+# what a device trace calls the attention kernels: mla_flash_fwd / _dq / _dkv
+KERNEL_NAME = "mla_flash"
+
+
+def rms_norm(x, weight, eps: float):
+    """A division by a square root, not ``lax.rsqrt``: the TPU's rsqrt is
+    an approximation (PERF.md, PR 28), and every gradient passes through
+    a norm."""
+    mean_square = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x / jnp.sqrt(mean_square + eps) * weight
+
+
+def rotary(x, theta: float):
+    """Rotary embedding over interleaved pairs: ``(x[2i], x[2i+1])`` of
+    position ``p`` turned by ``p * theta ** (-2i / d)``.  ``x``: (B, T, ...,
+    d), positions along axis 1.
+
+    The cosines and sines are constants of the program, made on the host
+    in float64: in float32 the angle of position 4,095 is off by 1e-3 rad
+    on the chip (a power and a product of rounded numbers, then a cosine
+    of a large argument), which two implementations round differently."""
+    d, t = x.shape[-1], x.shape[1]
+    inv_freq = float(theta) ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    angles = np.arange(t, dtype=np.float64)[:, None] * inv_freq
+    shape = (1, t) + (1,) * (x.ndim - 3) + (d // 2,)
+    cos = jnp.asarray(np.cos(angles).reshape(shape), x.dtype)
+    sin = jnp.asarray(np.sin(angles).reshape(shape), x.dtype)
+    even, odd = x[..., 0::2], x[..., 1::2]
+    turned = jnp.stack(
+        [even * cos - odd * sin, even * sin + odd * cos], axis=-1)
+    return turned.reshape(x.shape)
+
+
+def gated_mlp(p, x):
+    return (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
+
+
+@dataclass(frozen=True)
+class MlaMoeLM:
+    """``params = model.init(key)`` (made on the device, under ``jit``);
+    ``loss, stats = model.loss_and_stats(params, tokens)`` for (B, T + 1)
+    token windows; ``model.apply(params, tokens)`` gives the main model's
+    (B, T, vocab) next-token logits."""
+
+    vocab_size: int                 # rows held of `vocab_size`
+    hidden_dim: int = 2048          # hidden_size
+    layer_dim: int = 5              # num_hidden_layers kept
+    num_heads: int = 32             # num_attention_heads
+    q_rank: int = 1536              # q_lora_rank
+    kv_rank: int = 512              # kv_lora_rank
+    nope_dim: int = 128             # qk_nope_head_dim
+    rope_dim: int = 64              # qk_rope_head_dim
+    v_dim: int = 128                # v_head_dim
+    rope_theta: float = 32e6        # rope_theta
+    dense_ffn_dim: int = 7168       # intermediate_size
+    expert_ffn_dim: int = 768       # moe_intermediate_size
+    num_experts: int = 256          # n_routed_experts (the router's width)
+    num_selected: int = 8           # num_experts_per_tok
+    experts_first: int = 0          # the share held here: first expert ...
+    experts_held: int | None = None  # ... and how many (None: all)
+    shared_experts: int = 1         # n_shared_experts
+    dense_layers: int = 1           # first_k_dense_replace
+    route_scale: float = 2.5        # routed_scaling_factor
+    mtp_weight: float = 0.3         # 0: no prediction module
+    norm_eps: float = 1e-6          # rms_norm_eps
+    init_std: float = 0.02
+    # rows the grouped products compute while the held picks fit, as a
+    # multiple of what a uniform router sends here (never a drop: past it
+    # the layer computes every pick).  4: a router that has collapsed onto
+    # 8 experts for every token sends this chip N rows for each of them it
+    # holds, and two of them still fit
+    capacity_factor: float = 4.0
+    impl: str = "auto"              # attention: "flash" | "dense" | "auto"
+    remat: bool = False             # recompute each block in the backward
+
+    def __post_init__(self):
+        held = self.held
+        if not (0 <= self.experts_first
+                and self.experts_first + held <= self.num_experts
+                and held >= 1):
+            raise ValueError(
+                f"experts {self.experts_first}:{self.experts_first + held} "
+                f"are not a share of {self.num_experts}")
+        if self.num_selected > self.num_experts:
+            raise ValueError("more experts a token than experts")
+        if self.rope_dim % 2:
+            raise ValueError("rope_dim must be even")
+
+    @property
+    def held(self) -> int:
+        return (self.num_experts if self.experts_held is None
+                else self.experts_held)
+
+    # -- parameters ---------------------------------------------------------
+
+    def _block_shapes(self, dense: bool) -> dict:
+        d, h = self.hidden_dim, self.num_heads
+        attn = {
+            "w_qa": (d, self.q_rank),
+            "q_norm": (self.q_rank,),
+            "w_qb": (self.q_rank, h * (self.nope_dim + self.rope_dim)),
+            "w_kva": (d, self.kv_rank + self.rope_dim),
+            "kv_norm": (self.kv_rank,),
+            "w_kvb": (self.kv_rank, h * (self.nope_dim + self.v_dim)),
+            "w_o": (h * self.v_dim, d),
+        }
+
+        def mlp(width, *lead):
+            return {"w_gate": (*lead, d, width), "w_up": (*lead, d, width),
+                    "w_down": (*lead, width, d)}
+
+        if dense:
+            ffn = mlp(self.dense_ffn_dim)
+        else:
+            ffn = {
+                "router": (d, self.num_experts),
+                "router_bias": (self.num_experts,),
+                "shared": mlp(self.shared_experts * self.expert_ffn_dim),
+                "experts": mlp(self.expert_ffn_dim, self.held),
+            }
+        return {"attn_norm": (d,), "attn": attn, "ffn_norm": (d,),
+                "ffn": ffn}
+
+    def param_shapes(self) -> dict:
+        d = self.hidden_dim
+        shapes = {
+            "embed": (self.vocab_size, d),
+            "layers": [self._block_shapes(i < self.dense_layers)
+                       for i in range(self.layer_dim)],
+            "final_norm": (d,),
+            "head": (d, self.vocab_size),
+        }
+        if self.mtp_weight:
+            shapes["mtp"] = {
+                "embed_norm": (d,), "hidden_norm": (d,),
+                "w_eh": (2 * d, d),
+                "block": self._block_shapes(dense=False),
+                "final_norm": (d,),
+            }
+        return shapes
+
+    def init(self, key: jax.Array):
+        """Normal(0, ``init_std``) matrices, norm weights 1, the router's
+        bias buffer 0: one program on the device, nothing made on the
+        host (680 M parameters took a minute there)."""
+        return _init_on_device(self, key)
+
+    # -- forward ------------------------------------------------------------
+
+    def _attention(self, p, x):
+        from pytorch_distributed_rnn_tpu.ops.pallas_attention import (
+            resolve_attention_impl,
+        )
+
+        b, t, _ = x.shape
+        h, nope, rope = self.num_heads, self.nope_dim, self.rope_dim
+        with jax.named_scope("mla"):
+            c_q = rms_norm(x @ p["w_qa"], p["q_norm"], self.norm_eps)
+            q = (c_q @ p["w_qb"]).reshape(b, t, h, nope + rope)
+            kv_a = x @ p["w_kva"]
+            c_kv = rms_norm(
+                kv_a[..., : self.kv_rank], p["kv_norm"], self.norm_eps)
+            kv = (c_kv @ p["w_kvb"]).reshape(b, t, h, nope + self.v_dim)
+            q_rope = rotary(q[..., nope:], self.rope_theta)
+            k_rope = rotary(kv_a[..., self.kv_rank:], self.rope_theta)
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope[:, :, None, :], (b, t, h, rope))],
+                axis=-1)
+            q, k, v = (a.transpose(0, 2, 1, 3)
+                       for a in (q, k, kv[..., nope:]))
+            if resolve_attention_impl(self.impl) == "flash":
+                from pytorch_distributed_rnn_tpu.ops.pallas_attention import (
+                    flash_attention,
+                )
+
+                o = flash_attention(
+                    q, k, v, causal=True, name=KERNEL_NAME)
+            else:
+                from pytorch_distributed_rnn_tpu.ops.attention import (
+                    mha_attention,
+                )
+
+                o = mha_attention(q, k, v, causal=True)
+            o = o.transpose(0, 2, 1, 3).reshape(b, t, h * self.v_dim)
+            return o @ p["w_o"]
+
+    def _expert_ffn(self, p, x):
+        shape = x.shape
+        xt = x.reshape(-1, shape[-1])
+        picked, weights = route_sigmoid_topk(
+            p["router"], p["router_bias"], xt, self.num_selected,
+            self.route_scale)
+        num_picks = xt.shape[0] * self.num_selected
+        uniform = num_picks * self.held / self.num_experts
+        capacity = max(int(self.capacity_factor * uniform), 8 * self.held)
+        routed, counters = held_experts_ffn(
+            p["experts"], xt, picked, weights, first=self.experts_first,
+            capacity=-(-capacity // 128) * 128)
+        with jax.named_scope("shared_expert"):
+            shared = gated_mlp(p["shared"], xt)
+        return (shared + routed).reshape(shape), counters
+
+    def _block(self, p, x):
+        """One decoder block -> (x, the expert layer's counters or None)."""
+        x = x + self._attention(
+            p["attn"], rms_norm(x, p["attn_norm"], self.norm_eps))
+        y = rms_norm(x, p["ffn_norm"], self.norm_eps)
+        if "router" in p["ffn"]:
+            y, counters = self._expert_ffn(p["ffn"], y)
+        else:
+            y, counters = gated_mlp(p["ffn"], y), None
+        return x + y, counters
+
+    def _run_block(self, p, x):
+        block = jax.checkpoint(self._block) if self.remat else self._block
+        return block(p, x)
+
+    def hidden(self, params, tokens):
+        """tokens (B, T) -> (the last layer's output before the final
+        norm (B, T, D), one counters dict per expert layer)."""
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]
+        counters = []
+        for p in params["layers"]:
+            x, c = self._run_block(p, x)
+            if c is not None:
+                counters.append(c)
+        return x, counters
+
+    def apply(self, params, tokens):
+        """tokens (B, T) int32 -> the main model's logits (B, T, vocab)."""
+        x, _ = self.hidden(params, tokens)
+        return rms_norm(
+            x, params["final_norm"], self.norm_eps) @ params["head"]
+
+    def _mtp_hidden(self, params, h, next_tokens):
+        """The prediction module over every position: ``h`` (B, T, D) the
+        main model's hidden, ``next_tokens`` (B, T) the token AFTER each
+        position."""
+        p = params["mtp"]
+        with jax.named_scope("mtp"):
+            merged = jnp.concatenate(
+                [rms_norm(params["embed"][next_tokens], p["embed_norm"],
+                          self.norm_eps),
+                 rms_norm(h, p["hidden_norm"], self.norm_eps)], axis=-1)
+            return self._run_block(p["block"], merged @ p["w_eh"])
+
+    def loss_and_stats(self, params, tokens):
+        """(B, T + 1) token windows -> ``(loss, stats)``.
+
+        ``loss = CE_main + mtp_weight * CE_mtp``: the main model predicts
+        ``t_{i+1}`` at every position ``i < T``; the prediction module
+        predicts ``t_{i+2}`` at every ``i < T - 1`` (it runs over all T
+        positions so that every shape stays T long, and its last
+        position, which has no target, is left out of the mean; causal
+        attention keeps that position from reaching the others).
+
+        ``stats``: ``correct`` (the sum over sequences of the main
+        model's mean next-token accuracy) and the expert layers' routing
+        counters, summed over layers (``moe_rows_max``: the busiest held
+        expert of any layer)."""
+        inputs, targets = tokens[:, :-1], tokens[:, 1:]
+        h, counters = self.hidden(params, inputs)
+        nll, hit = _head_nll(
+            h, params["final_norm"], params["head"], targets, self.norm_eps)
+        loss = jnp.mean(nll)
+        if self.mtp_weight:
+            h_mtp, c = self._mtp_hidden(params, h, targets)
+            counters.append(c)
+            # position i < T - 1 predicts t_{i+2} = targets[i + 1]
+            nll_mtp, _ = _head_nll(
+                h_mtp[:, :-1], params["mtp"]["final_norm"], params["head"],
+                targets[:, 1:], self.norm_eps)
+            loss = loss + self.mtp_weight * jnp.mean(nll_mtp)
+        stats = {"correct": jnp.sum(jnp.mean(hit, axis=1))}
+        if counters:
+            stats.update(
+                moe_rows_max=functools.reduce(
+                    jnp.maximum, [c["rows_max"] for c in counters]),
+                moe_rows_sum=sum(c["rows_sum"] for c in counters),
+                moe_picks_absent=sum(c["picks_absent"] for c in counters),
+                moe_picks_dropped=sum(c["picks_dropped"] for c in counters),
+            )
+        return loss, stats
+
+@functools.partial(jax.checkpoint, static_argnums=(4,))
+def _head_nll(h, norm, head, targets, eps):
+    """Final norm, output head and per-position cross entropy (B, T),
+    with the hit of the arg max beside it.  Checkpointed: the backward
+    pass recomputes the (B, T, vocab) logits instead of keeping them, so
+    the main model's and the prediction module's never lie in memory
+    together."""
+    with jax.named_scope("head"):
+        logits = (rms_norm(h, norm, eps) @ head).astype(jnp.float32)
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(
+            logp, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
+    hit = (jnp.argmax(logits, axis=-1) == targets).astype(jnp.float32)
+    return nll, hit
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _init_on_device(model: MlaMoeLM, key):
+    leaves, tree = jax.tree_util.tree_flatten_with_path(
+        model.param_shapes(), is_leaf=lambda s: isinstance(s, tuple))
+    keys = jax.random.split(key, len(leaves))
+
+    def make(path, shape, k):
+        if jax.tree_util.keystr(path).endswith("router_bias']"):
+            return jnp.zeros(shape, jnp.float32)
+        if len(shape) == 1:  # a norm's weight
+            return jnp.ones(shape, jnp.float32)
+        return model.init_std * jax.random.normal(k, shape, jnp.float32)
+
+    return jax.tree.unflatten(
+        tree, [make(path, shape, k)
+               for (path, shape), k in zip(leaves, keys)])

@@ -322,3 +322,120 @@ def moe_ffn_dense(params, x, *, num_selected: int = 1):
     # assignment is what load balancing shapes)
     aux = load_balancing_loss(gates, experts[:, 0], e)
     return out.reshape(shape), aux
+
+
+# ---------------------------------------------------------------------------
+# Sigmoid top-k routing over experts this chip holds a share of
+# (DeepSeek-V3-style: arXiv 2412.19437 section 2.1.2)
+# ---------------------------------------------------------------------------
+
+
+def route_sigmoid_topk(router, bias, x, k: int, scale: float):
+    """Sigmoid scores over ALL experts, the ``k`` largest of score + bias
+    picked, the picked scores normalised to sum to 1 and scaled:
+    ``x (N, D)`` -> ``(picked (N, k) int32, weights (N, k) f32)``.
+
+    ``bias`` (the published ``e_score_correction_bias``) moves the pick
+    and not the weight, and stands outside the gradient.  Scores are
+    computed in f32 whatever ``x`` is: a pick must not quantize."""
+    with jax.named_scope("router"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router.astype(jnp.float32),
+            preferred_element_type=jnp.float32))
+        _, picked = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias), k)
+        weights = jnp.take_along_axis(scores, picked, axis=-1)
+        weights = scale * weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return picked, weights
+
+
+def _gated_rows(experts, rows, sizes, valid):
+    """``W_down(silu(x W_gate) * x W_up)`` of rows sorted by expert:
+    three grouped products (``jax.lax.ragged_dot``; XLA's own kernel on
+    the TPU, which keeps the ambient matmul precision).  What a product
+    writes in rows past the last group is not defined, so those rows are
+    zeroed (``valid``) wherever they could reach a result or a gradient."""
+    keep = valid[:, None]
+    rows = jnp.where(keep, rows, 0)
+    gate = jnp.where(keep, jax.lax.ragged_dot(
+        rows, experts["w_gate"], sizes), 0)
+    up = jnp.where(keep, jax.lax.ragged_dot(rows, experts["w_up"], sizes), 0)
+    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, experts["w_down"],
+                             sizes)
+    return jnp.where(keep, out, 0)
+
+
+def held_experts_ffn(experts, x, picked, weights, *, first: int,
+                     capacity: int):
+    """The routed part of an expert layer that THIS chip computes:
+    ``sum over picked experts held here of w_e * Expert_e(x)``.
+
+    ``experts`` holds ``count`` gated MLPs stacked on axis 0 (``w_gate``,
+    ``w_up`` (count, D, F), ``w_down`` (count, F, D)): experts ``first``
+    to ``first + count - 1`` of the layer.  ``picked`` / ``weights`` are
+    the router's picks over ALL experts; a pick that falls on an expert
+    held elsewhere adds nothing here (its chip adds it), and no code
+    stands in for that chip.
+
+    No pick is dropped, whatever the imbalance.  Picks are sorted by
+    expert, absent ones last; the held ones are the first ``total`` rows
+    of the sorted list and go through grouped products.  Shapes are
+    static, so the rows computed are ``capacity`` (the caller's guess:
+    some multiple of what a uniform router sends here) while ``total``
+    fits - ALWAYS ``capacity`` rows of work, the spare ones zeros, so that
+    the time is the same for every routing that fits - and the held ones
+    among ALL ``N * k`` picks when it does not: a ``lax.cond`` between two
+    programs of the same mathematics.
+
+    Returns ``(y (N, D), counters)``; the counters are f32 scalars:
+    ``rows_max`` / ``rows_sum`` (rows the busiest held expert / all held
+    experts received), ``picks_absent``, ``picks_dropped`` (held picks
+    that were not computed: 0 by construction, counted from the group
+    sizes the products really ran with)."""
+    count = experts["w_gate"].shape[0]
+    n, k = picked.shape
+    num_picks = n * k
+    with jax.named_scope("experts"):
+        local = picked.reshape(-1) - first
+        # an absent pick sorts behind every held one
+        group = jnp.where((local >= 0) & (local < count), local, count)
+        order = jnp.argsort(group, stable=True)
+        rows_per_expert = jnp.sum(
+            group[:, None] == jnp.arange(count)[None, :], axis=0,
+            dtype=jnp.int32)
+        total = jnp.sum(rows_per_expert)
+        token_of = order // k
+        weight_of = weights.reshape(-1)[order]
+        ends = jnp.cumsum(rows_per_expert)
+
+        def compute(rows: int, pad: bool):
+            tokens = token_of[:rows]
+            # group sizes as far as `rows` reaches (all of them whenever
+            # this branch is the one taken)
+            clipped = jnp.minimum(ends, rows)
+            sizes = jnp.diff(clipped, prepend=0)
+            valid = jnp.arange(rows) < clipped[-1]
+            if pad:
+                # the rows past the last held pick (zeroed, so they add
+                # nothing anywhere) join the last group: the products then
+                # do the same work whatever the router did, and a step's
+                # time does not follow the seed (PERF.md, PR 28)
+                sizes = sizes.at[-1].add(rows - clipped[-1])
+            out = _gated_rows(experts, x[tokens], sizes, valid)
+            out = out * weight_of[:rows, None].astype(out.dtype)
+            y = jnp.zeros_like(x).at[tokens].add(out)
+            return y, clipped[-1]
+
+        if capacity >= num_picks:
+            y, computed = compute(num_picks, pad=False)
+        else:
+            y, computed = jax.lax.cond(
+                total <= capacity, lambda: compute(capacity, pad=True),
+                lambda: compute(num_picks, pad=False))
+    f32 = jnp.float32
+    return y, {
+        "rows_max": jnp.max(rows_per_expert).astype(f32),
+        "rows_sum": total.astype(f32),
+        "picks_absent": (num_picks - total).astype(f32),
+        "picks_dropped": (total - computed).astype(f32),
+    }

@@ -10,7 +10,9 @@ attention at all - long-context is a first-class new capability here).
 
 Kernel layout (all three kernels share it):
 
-- Arrays are flattened to ``(B*H, T, D)``; the grid is
+- Arrays are flattened to ``(B*H, T, D)`` (``v``, the output and its
+  cotangent to ``(B*H, T, Dv)``: the value width is its own, as latent
+  attention needs it); the grid is
   ``(B*H, outer blocks, inner blocks)``.  The TPU grid is sequential over
   the trailing dimension, so VMEM scratch carries the running
   online-softmax state (forward) or gradient accumulators (backward)
@@ -31,6 +33,12 @@ Kernel layout (all three kernels share it):
   SMEM scalar, so causal masking works on *traced* offsets - a ring
   shard's offset is ``lax.axis_index``, unknown at trace time.  Blocks
   entirely above the causal diagonal skip their compute via ``pl.when``.
+- Each ``pallas_call`` carries a ``name`` (``<name>_fwd`` / ``_dq`` /
+  ``_dkv``, the caller's ``name`` or ``flash``): what a device trace and
+  the benchmark's kernel metrics call it.
+- Under ``jax.default_matmul_precision("highest")`` the exponentials are
+  the kernels' own (:func:`_exp`): the chip's ``exp`` is a fast
+  approximation, fifty times less exact than the float32 products.
 
 :func:`ring_flash_attention` composes the same kernels into the
 sequence-parallel ring (K/V blocks rotating via ``lax.ppermute``): the
@@ -60,6 +68,8 @@ from pytorch_distributed_rnn_tpu.ops.pallas_rnn import (
 
 _LANES = 128
 _NEG_INF = -jnp.inf
+# what a device trace calls the kernels of a caller that gives no name
+DEFAULT_NAME = "flash"
 
 
 def resolve_attention_impl(impl: str) -> str:
@@ -70,6 +80,40 @@ def resolve_attention_impl(impl: str) -> str:
     if impl == "auto":
         return "flash" if jax.default_backend() == "tpu" else "dense"
     return impl
+
+
+# exp(x) = 2^k * exp(r), r = x - k ln 2 in two parts (Cody and Waite; the
+# polynomial is Cephes' expf): 8e-8 of the true value, where the chip's own
+# exp is 5e-6 off (PERF.md, PR 28)
+_LOG2E = 1.4426950408889634
+_LN2_HI = 0.693359375  # eight significant bits: k * _LN2_HI is exact
+_LN2_LO = -2.12194440e-4
+_EXP_POLY = (1.9875691500e-4, 1.3981999507e-3, 8.3334519073e-3,
+             4.1665795894e-2, 1.6666665459e-1, 5.0000001201e-1)
+PRECISE_PRECISIONS = ("highest", "float32")
+
+
+def _exp_precise(x):
+    x = jnp.maximum(x, -87.0)  # 2^-126 is the smallest factor there is
+    k = jnp.round(x * _LOG2E)
+    r = (x - k * _LN2_HI) - k * _LN2_LO
+    p = jnp.full_like(r, _EXP_POLY[0])
+    for c in _EXP_POLY[1:]:
+        p = p * r + c
+    two_to_k = lax.bitcast_convert_type(
+        lax.shift_left(k.astype(jnp.int32) + 127, 23), jnp.float32)
+    return (p * (r * r) + r + 1.0) * two_to_k
+
+
+def _exp(x):
+    """The kernels' exponential, as exact as the products beside it: under
+    ``jax.default_matmul_precision("highest")`` the caller asks for float32
+    arithmetic, and the chip's exp (a fast approximation, 5e-6 off) would be
+    the least exact operation of the kernel by a factor of 50; at every
+    other precision the products are bf16 passes and the fast exp stands."""
+    if jax.config.jax_default_matmul_precision in PRECISE_PRECISIONS:
+        return _exp_precise(x)
+    return jnp.exp(x)
 
 
 def _block_mask(qi, ki, q_off, k_off, *, block_q, block_k, t_q, t_k,
@@ -135,12 +179,12 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_prev = m_scr[:, :1]
         l_prev = l_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        p = _exp(s - m_new)
         if mask is not None:
             # fully-masked rows have s = m_new = -inf -> exp(nan); the
             # where() both zeroes masked entries and scrubs those nans
             p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
+        corr = _exp(m_prev - m_new)
         corr = jnp.where(jnp.isfinite(corr), corr, 0.0)
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc = acc_scr[:] * corr + _mxu_dot(p, v_ref[0])
@@ -162,13 +206,18 @@ def _scalar_spec():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _fwd_impl(q, k, v, offsets, causal, block_q, block_k, t_q, t_k):
-    """q: (BH, Tq, D) padded to block multiples; ``t_q``/``t_k`` are the
-    actual (pre-padding) lengths the masks validate against; ``offsets``
-    is a (2,) int32 [q_offset, k_offset] (may be traced).  Returns
-    (o, lse) with lse lane-replicated (BH, Tq, 128) f32."""
+def _fwd_impl(q, k, v, offsets, causal, block_q, block_k, t_q, t_k,
+              name=DEFAULT_NAME):
+    """q: (BH, Tq, D) padded to block multiples, k: (BH, Tk, D), v:
+    (BH, Tk, Dv) - the value width is its own (latent attention: q/k
+    192 wide, v 128); ``t_q``/``t_k`` are the actual (pre-padding)
+    lengths the masks validate against; ``offsets`` is a (2,) int32
+    [q_offset, k_offset] (may be traced).  Returns (o (BH, Tq, Dv), lse)
+    with lse lane-replicated (BH, Tq, 128) f32.  The kernel shows in a
+    device trace as ``<name>_fwd``."""
     bh, t_q_pad, d = q.shape
     t_k_pad = k.shape[1]
+    d_v = v.shape[2]
     grid = (bh, t_q_pad // block_q, t_k_pad // block_k)
     kernel = functools.partial(
         _fwd_kernel, scale=d ** -0.5, causal=causal,
@@ -181,25 +230,26 @@ def _fwd_impl(q, k, v, offsets, causal, block_q, block_k, t_q, t_k):
             _scalar_spec(),
             pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda b, qi, ki: (b, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, block_q, d_v), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, block_q, _LANES), lambda b, qi, ki: (b, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t_q_pad, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t_q_pad, d_v), q.dtype),
             jax.ShapeDtypeStruct((bh, t_q_pad, _LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name=f"{name}_fwd",
     )(offsets, q, k, v)
 
 
@@ -212,7 +262,7 @@ def _recompute_p(q, k, lse, mask, scale):
     """p = exp(s - lse) with masked entries (and their inf/nan fallout
     from padded rows' lse = -inf) scrubbed to zero."""
     s = _mxu_dot(q, k, _MATMUL_NT) * scale
-    p = jnp.exp(s - lse)
+    p = _exp(s - lse)
     if mask is not None:
         p = jnp.where(mask, p, 0.0)
     return jnp.where(jnp.isfinite(p), p, 0.0)
@@ -284,20 +334,25 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_impl(q, k, v, do, lse, delta, offsets, causal, block_q, block_k,
-              t_q, t_k):
+              t_q, t_k, name=DEFAULT_NAME):
+    """dq, dk (D wide) and dv (Dv wide, as ``v`` and ``do`` are); the two
+    kernels show in a device trace as ``<name>_dq`` and ``<name>_dkv``."""
     bh, t_q_pad, d = q.shape
     t_k_pad = k.shape[1]
+    d_v = v.shape[2]
     common = dict(scale=d ** -0.5, causal=causal, t_q=t_q, t_k=t_k,
                   block_q=block_q, block_k=block_k)
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0))
+    do_spec = pl.BlockSpec((1, block_q, d_v), lambda b, qi, ki: (b, qi, 0))
     k_spec = pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0))
+    v_spec = pl.BlockSpec((1, block_k, d_v), lambda b, qi, ki: (b, ki, 0))
     row_spec = pl.BlockSpec((1, block_q, _LANES),
                             lambda b, qi, ki: (b, qi, 0))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **common),
         grid=(bh, t_q_pad // block_q, t_k_pad // block_k),
-        in_specs=[_scalar_spec(), q_spec, k_spec, k_spec, q_spec, row_spec,
+        in_specs=[_scalar_spec(), q_spec, k_spec, v_spec, do_spec, row_spec,
                   row_spec],
         out_specs=[q_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
@@ -306,27 +361,31 @@ def _bwd_impl(q, k, v, do, lse, delta, offsets, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name=f"{name}_dq",
     )(offsets, q, k, v, do, lse, delta)[0]
 
     # swapped grid: outer = K blocks, inner sweep = Q blocks
     q_spec_t = pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0))
+    do_spec_t = pl.BlockSpec((1, block_q, d_v), lambda b, ki, qi: (b, qi, 0))
     k_spec_t = pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0))
+    v_spec_t = pl.BlockSpec((1, block_k, d_v), lambda b, ki, qi: (b, ki, 0))
     row_spec_t = pl.BlockSpec((1, block_q, _LANES),
                               lambda b, ki, qi: (b, qi, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **common),
         grid=(bh, t_k_pad // block_k, t_q_pad // block_q),
-        in_specs=[_scalar_spec(), q_spec_t, k_spec_t, k_spec_t, q_spec_t,
+        in_specs=[_scalar_spec(), q_spec_t, k_spec_t, v_spec_t, do_spec_t,
                   row_spec_t, row_spec_t],
-        out_specs=[k_spec_t, k_spec_t],
+        out_specs=[k_spec_t, v_spec_t],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, d_v), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=_interpret(),
+        name=f"{name}_dkv",
     )(offsets, q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -344,26 +403,30 @@ def _delta_of(do, o):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, causal, q_offset, k_offset, block_q, block_k, t_q, t_k):
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, causal, q_offset, k_offset, block_q, block_k, t_q, t_k,
+           name):
     offs = jnp.array([q_offset, k_offset], jnp.int32)
-    o, _ = _fwd_impl(q, k, v, offs, causal, block_q, block_k, t_q, t_k)
+    o, _ = _fwd_impl(q, k, v, offs, causal, block_q, block_k, t_q, t_k,
+                     name)
     return o
 
 
 def _flash_fwd(q, k, v, causal, q_offset, k_offset, block_q, block_k,
-               t_q, t_k):
+               t_q, t_k, name):
     offs = jnp.array([q_offset, k_offset], jnp.int32)
-    o, lse = _fwd_impl(q, k, v, offs, causal, block_q, block_k, t_q, t_k)
+    o, lse = _fwd_impl(q, k, v, offs, causal, block_q, block_k, t_q, t_k,
+                       name)
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(causal, q_offset, k_offset, block_q, block_k, t_q, t_k,
-               res, do):
+               name, res, do):
     q, k, v, o, lse = res
     offs = jnp.array([q_offset, k_offset], jnp.int32)
     dq, dk, dv = _bwd_impl(q, k, v, do, lse, _delta_of(do, o), offs,
-                           causal, block_q, block_k, t_q, t_k)
+                           causal, block_q, block_k, t_q, t_k, name)
     return dq, dk, dv
 
 
@@ -390,11 +453,14 @@ def _flatten_pad(x, t_pad):
 
 def flash_attention(q, k, v, *, causal: bool = False, q_offset: int = 0,
                     k_offset: int = 0, block_q: int | None = None,
-                    block_k: int | None = None):
+                    block_k: int | None = None, name: str = DEFAULT_NAME):
     """Fused flash attention, drop-in for
     :func:`~pytorch_distributed_rnn_tpu.ops.attention.mha_attention`.
 
-    ``q``: (B, H, Tq, D), ``k``/``v``: (B, H, Tk, D) -> (B, H, Tq, D).
+    ``q``: (B, H, Tq, D), ``k``: (B, H, Tk, D), ``v``: (B, H, Tk, Dv)
+    -> (B, H, Tq, Dv); the scores are scaled by ``D ** -0.5``.  ``name``
+    is what a device trace calls the three kernels (``<name>_fwd``,
+    ``<name>_dq``, ``<name>_dkv``).
     ``q_offset``/``k_offset`` are static global positions of the first
     query/key so causal masking works on sequence chunks.  Differentiable
     via the flash backward (dQ + dK/dV kernels); O(T) memory - the score
@@ -403,15 +469,18 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset: int = 0,
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError("flash_attention wants (B, H, T, D) inputs, got "
                          f"{q.shape}/{k.shape}/{v.shape}")
-    b, h, t_q, d = q.shape
+    if k.shape[-1] != q.shape[-1] or v.shape[:3] != k.shape[:3]:
+        raise ValueError("flash_attention wants k as wide as q and v as "
+                         f"long as k, got {q.shape}/{k.shape}/{v.shape}")
+    b, h, t_q, _ = q.shape
     t_k = k.shape[2]
     block_q, block_k = _resolve_blocks(t_q, t_k, block_q, block_k)
     t_q_pad = _round_up(t_q, block_q)
     t_k_pad = _round_up(t_k, block_k)
     o = _flash(_flatten_pad(q, t_q_pad), _flatten_pad(k, t_k_pad),
                _flatten_pad(v, t_k_pad),
-               causal, q_offset, k_offset, block_q, block_k, t_q, t_k)
-    return o[:, :t_q].reshape(b, h, t_q, d)
+               causal, q_offset, k_offset, block_q, block_k, t_q, t_k, name)
+    return o[:, :t_q].reshape(b, h, t_q, v.shape[-1])
 
 
 # ---------------------------------------------------------------------------

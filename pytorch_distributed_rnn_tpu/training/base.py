@@ -1094,6 +1094,19 @@ class Trainer:
         with span(name, self.recorder, **attrs):
             return float(value)
 
+    def _fetch_correct(self, metrics, name: str, **attrs) -> float:
+        """``metrics["correct"]`` as :meth:`_fetch` brings it.  Whatever
+        else the step counted (an expert layer's routing counters,
+        ``training/lm.py:ModelLossMixin``) was computed by the same
+        program, so it is on the host after the same wait: it is noted
+        on the span, and no fetch is added."""
+        with span(name, self.recorder, **attrs) as fetch:
+            correct = float(metrics["correct"])
+            for key, value in metrics.items():
+                if key != "correct":
+                    fetch.attrs[key] = float(value)
+        return correct
+
     def _train_epoch(self, formatter):
         epoch_path = self._epoch_path()
         if epoch_path == "host":
@@ -1222,9 +1235,8 @@ class Trainer:
                     )
                 total_loss += self._fetch(
                     loss_sum, "epoch.fetch", program="train_epoch")
-                total_correct += self._fetch(
-                    metrics_sum["correct"], "epoch.fetch",
-                    program="train_epoch")
+                total_correct += self._fetch_correct(
+                    metrics_sum, "epoch.fetch", program="train_epoch")
             if remainder is not None:
                 extra = (keys[-1],) if keys is not None else ()
                 with span("epoch.launch", program="train_step"):
@@ -1236,8 +1248,8 @@ class Trainer:
                     )
                 total_loss += self._fetch(
                     loss, "epoch.fetch", program="train_step")
-                total_correct += self._fetch(
-                    metrics["correct"], "epoch.fetch", program="train_step")
+                total_correct += self._fetch_correct(
+                    metrics, "epoch.fetch", program="train_step")
             self._steps_done = step_base + len(batches)
             if self._profile is not None:
                 self._profile.on_step_end(self._steps_done - 1)

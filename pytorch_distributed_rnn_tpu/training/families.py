@@ -34,9 +34,41 @@ def require_family(args, allowed, strategy: str):
         )
 
 
+# families whose examples are (T + 1)-token windows (data/text.py)
+TOKEN_FAMILIES = ("char", "mla_moe")
+
+
+def _vocab_size(args, training_set) -> int:
+    """--vocab-size, else what the data declares; never fewer rows than
+    the data has ids."""
+    vocab = getattr(args, "vocab_size", None) or training_set.vocab_size
+    if vocab < training_set.vocab_size:
+        raise SystemExit(
+            f"--vocab-size {vocab} is smaller than the data's vocabulary "
+            f"({training_set.vocab_size})"
+        )
+    return vocab
+
+
+def _ints(args, flag: str, count: int, sep: str = ","):
+    """A flag that holds ``count`` whole numbers, e.g. ``--mla-ranks
+    1536,512``."""
+    text = getattr(args, flag.lstrip("-").replace("-", "_"))
+    try:
+        values = tuple(int(v) for v in text.split(sep))
+    except ValueError:
+        values = ()
+    if len(values) != count or min(values) < 0:
+        raise SystemExit(
+            f"{flag} wants {count} whole numbers separated by {sep!r}, "
+            f"got {text!r}"
+        )
+    return values
+
+
 def load_datasets(args):
     """(train, validation, test) for the selected family."""
-    if family_of(args) == "char":
+    if family_of(args) in TOKEN_FAMILIES:
         from pytorch_distributed_rnn_tpu.data.text import TextDataset
 
         seq_length = getattr(args, "seq_length", None)
@@ -51,11 +83,17 @@ def load_datasets(args):
             seq_length=seq_length,
             validation_fraction=args.validation_fraction,
             seed=args.seed,
+            vocab_size=getattr(args, "vocab_size", None),
         )
     if getattr(args, "seq_length", None) is not None:
         raise SystemExit(
-            "--seq-length only applies to --model char (motion/attention "
-            "sequence length is a property of the HAR data)"
+            "--seq-length only applies to --model char / mla_moe "
+            "(motion/attention sequence length is a property of the HAR "
+            "data)"
+        )
+    if getattr(args, "vocab_size", None) is not None:
+        raise SystemExit(
+            "--vocab-size only applies to --model char / mla_moe"
         )
     from pytorch_distributed_rnn_tpu.data import MotionDataset
 
@@ -77,7 +115,7 @@ def build_model(args, training_set):
         from pytorch_distributed_rnn_tpu.models import CharRNN
 
         return CharRNN(
-            vocab_size=training_set.vocab_size,
+            vocab_size=_vocab_size(args, training_set),
             embed_dim=args.hidden_units,
             hidden_dim=args.hidden_units,
             layer_dim=args.stacked_layer,
@@ -104,9 +142,16 @@ def build_model(args, training_set):
             precision=getattr(args, "precision", "f32"),
             remat=getattr(args, "remat", False),
         )
+    if fam == "mla_moe":
+        return _build_mla_moe(args, training_set)
     if fam == "moe":
         from pytorch_distributed_rnn_tpu.models import MoEClassifier
 
+        if getattr(args, "moe_top_k", 1) not in (1, 2):
+            raise SystemExit(
+                "--model moe does not support: --moe-top-k "
+                f"{args.moe_top_k} (1 = Switch, 2 = GShard)"
+            )
         if getattr(args, "dropout", 0.0):
             raise SystemExit(
                 "--model moe does not support: --dropout "
@@ -130,7 +175,7 @@ def build_model(args, training_set):
     if fam != "rnn":
         raise SystemExit(
             f"--model {fam} is not wired into this strategy - supported "
-            "here: rnn, char, attention, moe"
+            "here: rnn, char, attention, moe, mla_moe"
         )
     from pytorch_distributed_rnn_tpu.models import MotionModel
 
@@ -146,6 +191,58 @@ def build_model(args, training_set):
     )
 
 
+def _build_mla_moe(args, training_set):
+    """``--model mla_moe``: every flag it cannot honour is refused, and a
+    share that is no share of the layer too."""
+    from pytorch_distributed_rnn_tpu.models import MlaMoeLM
+
+    refused = [
+        flag for flag, bad in (
+            ("--dropout (pass --dropout 0: the family has none; the CLI "
+             "default 0.1 mirrors the reference surface)",
+             bool(getattr(args, "dropout", 0.0))),
+            ("--cell gru (no recurrent cell)",
+             getattr(args, "cell", "lstm") != "lstm"),
+            ("--precision bf16 (its bf16 path has not been brought up)",
+             getattr(args, "precision", "f32") != "f32"),
+            ("--moe-router expert (tokens pick experts here)",
+             getattr(args, "moe_router", "token") != "token"),
+            ("--moe-group-size (no capacity slots: no pick is dropped)",
+             getattr(args, "moe_group_size", None) is not None),
+            ("--fuse-run (its loss has no per-sequence weighted form)",
+             bool(getattr(args, "fuse_run", False))),
+        ) if bad
+    ]
+    if refused:
+        raise SystemExit(
+            "--model mla_moe does not support: " + "; ".join(refused))
+    q_rank, kv_rank = _ints(args, "--mla-ranks", 2)
+    nope_dim, rope_dim, v_dim = _ints(args, "--mla-head-dims", 3)
+    dense_ffn, expert_ffn = _ints(args, "--ffn-dims", 2)
+    first, held = 0, None
+    if getattr(args, "experts_held", None) is not None:
+        first, held = _ints(args, "--experts-held", 2, sep=":")
+    try:
+        return MlaMoeLM(
+            vocab_size=_vocab_size(args, training_set),
+            hidden_dim=args.hidden_units,
+            layer_dim=args.stacked_layer,
+            num_heads=getattr(args, "num_heads", 4),
+            q_rank=q_rank, kv_rank=kv_rank,
+            nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+            rope_theta=args.rope_theta,
+            dense_ffn_dim=dense_ffn, expert_ffn_dim=expert_ffn,
+            num_experts=getattr(args, "num_experts", 4),
+            num_selected=getattr(args, "moe_top_k", 1),
+            experts_first=first, experts_held=held,
+            route_scale=args.moe_route_scale,
+            mtp_weight=args.mtp_weight,
+            remat=getattr(args, "remat", False),
+        )
+    except ValueError as exc:
+        raise SystemExit(f"--model mla_moe: {exc}") from None
+
+
 def wrap_trainer(args, trainer_class):
     """The strategy's Trainer class with the family's loss mixed in.
 
@@ -159,6 +256,19 @@ def wrap_trainer(args, trainer_class):
         from pytorch_distributed_rnn_tpu.training.lm import wrap_lm_trainer
 
         return wrap_lm_trainer(trainer_class)
+    if family_of(args) == "mla_moe":
+        from pytorch_distributed_rnn_tpu.training.lm import (
+            wrap_model_loss_trainer,
+        )
+
+        if not isinstance(trainer_class, type):  # the mesh factory
+            raise SystemExit(
+                "--model mla_moe is not wired into the mesh strategy: its "
+                "expert layer computes one chip's share and has no "
+                "exchange between chips"
+            )
+
+        return wrap_model_loss_trainer(trainer_class)
     if family_of(args) == "moe" and not getattr(
         trainer_class, "OWNS_MOE_LOSS", False
     ):

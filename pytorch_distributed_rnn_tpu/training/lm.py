@@ -61,15 +61,39 @@ class LMLossMixin:
         return loss, {"correct": jnp.sum(acc * (w > 0))}
 
 
+class ModelLossMixin:
+    """For a model whose loss is more than cross entropy of one logit
+    array (``models/mla_moe_lm.py``: the main model's loss plus the
+    prediction module's): the model computes it over the token window,
+    ``model.loss_and_stats(params, tokens) -> (loss, stats)``, and what
+    ``stats`` holds beside ``correct`` (the expert layers' routing
+    counters) rides the step's metrics to the fetch the loop already
+    makes (``Trainer._fetch_correct``).  There is no per-sequence weighted
+    form, so the family refuses ``--fuse-run`` (``families.build_model``)."""
+
+    def _loss_and_metrics(self, params, batch, key=None):
+        tokens, _ = batch
+        return self.model.loss_and_stats(params, tokens)
+
+
 _WRAPPED: dict = {}
+
+
+def _mixed(mixin, prefix: str, trainer_class):
+    cls = _WRAPPED.get((mixin, trainer_class))
+    if cls is None:
+        cls = type(
+            f"{prefix}{trainer_class.__name__}", (mixin, trainer_class), {}
+        )
+        _WRAPPED[mixin, trainer_class] = cls
+    return cls
 
 
 def wrap_lm_trainer(trainer_class):
     """The trainer class with LM losses mixed in (cached per base class)."""
-    cls = _WRAPPED.get(trainer_class)
-    if cls is None:
-        cls = type(
-            f"LM{trainer_class.__name__}", (LMLossMixin, trainer_class), {}
-        )
-        _WRAPPED[trainer_class] = cls
-    return cls
+    return _mixed(LMLossMixin, "LM", trainer_class)
+
+
+def wrap_model_loss_trainer(trainer_class):
+    """The trainer class taking its loss from the model (cached)."""
+    return _mixed(ModelLossMixin, "ModelLoss", trainer_class)

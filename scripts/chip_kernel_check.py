@@ -11,7 +11,11 @@ and GRU at H=512 in f32 and bf16 (the largest hidden size ``auto``
 routes to the kernel; shape of the launcher's char-LM chip row: batch
 256, T=128), and flash attention forward / dQ / dK,dV at the attention
 family's CLI defaults (hidden 32 over 4 heads -> head_dim 8, batch 1440,
-T=128).  Everything runs under ``jax.default_matmul_precision("highest")``
+T=128), and the same kernels with a value width of their own at the latent
+attention cell's shape (32 heads, T=4096, q / k 192 wide, v 128 wide, causal,
+f32; once more at JAX's default precision, where the kernel's f32 products
+are bf16 passes and the bf16 tolerance applies).  Everything else runs under
+``jax.default_matmul_precision("highest")``
 and the reference always computes in float32 - on the kernel's own
 inputs, upcast - so it is the exact side of the comparison also for the
 bf16 cases (a bf16 ``lax.scan`` reference accumulates its bias gradient
@@ -116,7 +120,13 @@ def _rnn_case(cell, hidden, batch, seq, in_dim, dtype_name):
     return row
 
 
-def _flash_case(batch, heads, seq, head_dim, dtype_name):
+def _flash_case(batch, heads, seq, head_dim, dtype_name, *, v_dim=None,
+                causal=False, precision="highest"):
+    """``precision`` is the ambient matmul precision of the KERNEL's side
+    (the reference always runs at "highest"); below "highest" an f32
+    kernel multiplies in bf16 passes and is held to the bf16 tolerance."""
+    import functools
+
     import jax
     import jax.numpy as jnp
 
@@ -128,15 +138,23 @@ def _flash_case(batch, heads, seq, head_dim, dtype_name):
     dtype = jnp.dtype(dtype_name)
     q, k, v = (
         jax.random.normal(jax.random.PRNGKey(i),
-                          (batch, heads, seq, head_dim),
+                          (batch, heads, seq, width),
                           jnp.float32).astype(dtype)
-        for i in range(3)
+        for i, width in enumerate((head_dim, head_dim, v_dim or head_dim))
     )
-    name = f"flash_attention b{batch} h{heads} t{seq} d{head_dim}"
+    name = (f"flash_attention b{batch} h{heads} t{seq} d{head_dim}"
+            + (f" v{v_dim} causal {precision}" if v_dim else ""))
+
+    def fused(*args):
+        with jax.default_matmul_precision(precision):
+            return flash_attention(*args, causal=causal)
+
     # grad0/grad1/grad2 = the dQ kernel and the two outputs of the dK,dV
     # kernel
-    return _compare(name, flash_attention, mha_attention, (q, k, v),
-                    dtype_name)
+    return _compare(name, fused,
+                    functools.partial(mha_attention, causal=causal),
+                    (q, k, v),
+                    dtype_name if precision == "highest" else "bfloat16")
 
 
 def _provoke_refusals():
@@ -239,6 +257,12 @@ def main(argv=None) -> int:
             lambda: _flash_case(1440, 4, 128, 8, "float32"),
         "flash attention bf16":
             lambda: _flash_case(1440, 4, 128, 8, "bfloat16"),
+        "flash attention latent f32 highest (mla_moe cell)":
+            lambda: _flash_case(1, 32, 4096, 192, "float32", v_dim=128,
+                                causal=True),
+        "flash attention latent f32 default (mla_moe cell)":
+            lambda: _flash_case(1, 32, 4096, 192, "float32", v_dim=128,
+                                causal=True, precision="default"),
     }
     cases = {k: v for k, v in cases.items() if args.only in k}
     if not cases:
