@@ -29,16 +29,40 @@ Kernel layout (all three kernels share it):
 - ``m``/``l``/``lse``/``delta`` row stats live lane-replicated as
   ``(block, 128)`` tiles (the (8, 128) f32 register tile has no cheap
   1-lane form on TPU).
-- The global positions of the first query/key ride in as a (2,) int32
-  SMEM scalar, so causal masking works on *traced* offsets - a ring
-  shard's offset is ``lax.axis_index``, unknown at trace time.  Blocks
-  entirely above the causal diagonal skip their compute via ``pl.when``.
 - Each ``pallas_call`` carries a ``name`` (``<name>_fwd`` / ``_dq`` /
   ``_dkv``, the caller's ``name`` or ``flash``): what a device trace and
   the benchmark's kernel metrics call it.
 - Under ``jax.default_matmul_precision("highest")`` the exponentials are
   the kernels' own (:func:`_exp`): the chip's ``exp`` is a fast
   approximation, fifty times less exact than the float32 products.
+
+The block schedule (which blocks a kernel visits, fetches and masks, and
+how large they are) follows the causal triangle:
+
+- The global positions of the first query/key ride in as a (2,) int32
+  scalar-prefetch operand, so the causal mask, the kernels' branches AND
+  the index maps work on *traced* offsets - a ring shard's offset is
+  ``lax.axis_index``, unknown at trace time.
+- A grid step above the diagonal computes nothing (``pl.when`` on
+  :func:`_causal_skip`) and fetches nothing: the inner block index is clamped
+  to the last (forward, dQ: :func:`_last_k_block`) or first (dK/dV:
+  :func:`_first_q_block`) block the outer one needs, so the step names
+  the block already in VMEM and Pallas issues no copy.  What is left of
+  a skipped step is the grid step's own fixed cost.  Every computed block
+  builds the full mask: a second branch without it, for the blocks wholly
+  below the diagonal, gave 0 - 5 % of a kernel on the v5e, under 1 % of
+  the step it ran in, and was left out (PERF.md, PR 29).
+- The tiles are picked per kernel (:func:`pick_blocks`), at the moment
+  the kernel is traced and so at the precision it is traced under: of
+  the multiples of 128 that divide the padded length, up to 1,024 a
+  side, the pair of the largest area whose VMEM need by
+  :func:`vmem_bytes` stays under ``_VMEM_MOST``; where the need passes
+  Mosaic's default 16 MiB the kernel asks for its own
+  ``vmem_limit_bytes``.  Larger tiles divide the re-reads of the swept
+  operand and the grid steps; past 1,024 the products wasted in the
+  blocks the diagonal crosses outweigh both.  :func:`schedule` counts
+  what a tile costs in steps and blocks, :func:`fetched_bytes` in HBM
+  traffic.
 
 :func:`ring_flash_attention` composes the same kernels into the
 sequence-parallel ring (K/V blocks rotating via ``lax.ppermute``): the
@@ -136,6 +160,24 @@ def _block_mask(qi, ki, q_off, k_off, *, block_q, block_k, t_q, t_k,
     return mask
 
 
+def _last_k_block(qi, q_off, k_off, *, block_q, block_k, n_k):
+    """The last key block that row ``qi`` of a (query, key) grid needs under
+    the causal mask (block 0 for a row that sees no key at all).  The
+    index maps ask it on traced scalars, :func:`schedule` on whole index
+    arrays."""
+    reach = (qi + 1) * block_q - 1 + q_off - k_off  # last visible key
+    return jnp.clip(lax.div(jnp.maximum(reach, 0), jnp.int32(block_k)),
+                    0, n_k - 1)
+
+
+def _first_q_block(ki, q_off, k_off, *, block_q, block_k, n_q):
+    """The first query block that column ``ki`` of a (key, query) grid needs
+    under the causal mask (the last block for a column no query sees)."""
+    first = ki * block_k + k_off - q_off  # first query that sees the block
+    return jnp.clip(lax.div(jnp.maximum(first, 0), jnp.int32(block_q)),
+                    0, n_q - 1)
+
+
 def _causal_skip(qi, ki, q_off, k_off, *, block_q, block_k):
     """True when the whole block lies above the causal diagonal (no valid
     score) - its compute can be skipped entirely."""
@@ -200,57 +242,6 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
         lse = jnp.where(l > 0, m + jnp.log(l_safe), _NEG_INF)
         lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
-
-
-def _scalar_spec():
-    return pl.BlockSpec(memory_space=pltpu.SMEM)
-
-
-def _fwd_impl(q, k, v, offsets, causal, block_q, block_k, t_q, t_k,
-              name=DEFAULT_NAME):
-    """q: (BH, Tq, D) padded to block multiples, k: (BH, Tk, D), v:
-    (BH, Tk, Dv) - the value width is its own (latent attention: q/k
-    192 wide, v 128); ``t_q``/``t_k`` are the actual (pre-padding)
-    lengths the masks validate against; ``offsets`` is a (2,) int32
-    [q_offset, k_offset] (may be traced).  Returns (o (BH, Tq, Dv), lse)
-    with lse lane-replicated (BH, Tq, 128) f32.  The kernel shows in a
-    device trace as ``<name>_fwd``."""
-    bh, t_q_pad, d = q.shape
-    t_k_pad = k.shape[1]
-    d_v = v.shape[2]
-    grid = (bh, t_q_pad // block_q, t_k_pad // block_k)
-    kernel = functools.partial(
-        _fwd_kernel, scale=d ** -0.5, causal=causal,
-        t_q=t_q, t_k=t_k, block_q=block_q, block_k=block_k,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            _scalar_spec(),
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d_v), lambda b, qi, ki: (b, ki, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d_v), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, qi, ki: (b, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t_q_pad, d_v), q.dtype),
-            jax.ShapeDtypeStruct((bh, t_q_pad, _LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d_v), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=_interpret(),
-        name=f"{name}_fwd",
-    )(offsets, q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -333,60 +324,125 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+# ---------------------------------------------------------------------------
+# Launching the kernels
+# ---------------------------------------------------------------------------
+
+
+def _grid_specs(causal, block_q, block_k, n_q, n_k, d, d_v, *,
+                k_outer=False):
+    """Block specs of a kernel's operands by role.  The grid is (rows,
+    query blocks, key blocks), or with ``k_outer`` (rows, key blocks, query
+    blocks); the offsets are the one scalar-prefetch operand, so an index
+    map may read them.  Under the causal mask the INNER index is clamped
+    to the blocks the outer one needs: a step above the diagonal names the
+    block that is already in VMEM, and Pallas issues no copy for a block
+    index that did not change."""
+    tiles = dict(block_q=block_q, block_k=block_k)
+
+    def index(b, outer, inner, offs):
+        qi, ki = (inner, outer) if k_outer else (outer, inner)
+        if causal and k_outer:
+            qi = jnp.maximum(qi, _first_q_block(
+                ki, offs[0], offs[1], n_q=n_q, **tiles))
+        elif causal:
+            ki = jnp.minimum(ki, _last_k_block(
+                qi, offs[0], offs[1], n_k=n_k, **tiles))
+        return b, qi, ki
+
+    def q_map(*g):
+        b, qi, _ = index(*g)
+        return b, qi, 0
+
+    def k_map(*g):
+        b, _, ki = index(*g)
+        return b, ki, 0
+
+    def q_side(width):
+        return pl.BlockSpec((1, block_q, width), q_map)
+
+    def k_side(width):
+        return pl.BlockSpec((1, block_k, width), k_map)
+
+    return {"q": q_side(d), "do": q_side(d_v), "row": q_side(_LANES),
+            "k": k_side(d), "v": k_side(d_v)}
+
+
+def _call(kind, operands, offsets, causal, block_q, block_k, t_q, t_k,
+          name):
+    """Launch kernel ``kind`` (``"fwd"``, ``"dq"`` or ``"dkv"``) on
+    ``operands`` (q, k, v, then for a backward kernel do, lse, delta; all
+    padded to block multiples) at tiles of its own: a block that is
+    ``None`` is picked here, at the precision the kernel is traced under.
+    ``"dkv"`` swaps the grid: key blocks outside, query blocks swept."""
+    q, k, v = operands[:3]
+    bh, t_q_pad, d = q.shape
+    t_k_pad = k.shape[1]
+    d_v = v.shape[2]
+    block_q, block_k, vmem_limit = pick_blocks(
+        kind, t_q_pad, t_k_pad, d, d_v, q.dtype.itemsize,
+        block_q=block_q, block_k=block_k)
+    n_q, n_k = t_q_pad // block_q, t_k_pad // block_k
+    k_outer = kind == "dkv"
+    spec = _grid_specs(causal, block_q, block_k, n_q, n_k, d, d_v,
+                       k_outer=k_outer)
+    kernel, outs, scratch = {
+        "fwd": (_fwd_kernel, ("do", "row"),
+                [(block_q, _LANES), (block_q, _LANES), (block_q, d_v)]),
+        "dq": (_dq_kernel, ("q",), [(block_q, d)]),
+        "dkv": (_dkv_kernel, ("k", "v"), [(block_k, d), (block_k, d_v)]),
+    }[kind]
+    ins = ("q", "k", "v", "do", "row", "row")[:len(operands)]
+
+    def out_shape(role):
+        length = t_k_pad if role in ("k", "v") else t_q_pad
+        return jax.ShapeDtypeStruct(
+            (bh, length, spec[role].block_shape[-1]),
+            jnp.float32 if role == "row" else q.dtype)
+
+    return pl.pallas_call(
+        functools.partial(
+            kernel, scale=d ** -0.5, causal=causal, t_q=t_q, t_k=t_k,
+            block_q=block_q, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, n_k, n_q) if k_outer else (bh, n_q, n_k),
+            in_specs=[spec[role] for role in ins],
+            out_specs=[spec[role] for role in outs],
+            scratch_shapes=[pltpu.VMEM(shape, jnp.float32)
+                            for shape in scratch],
+        ),
+        out_shape=[out_shape(role) for role in outs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit,
+        ),
+        interpret=_interpret(),
+        name=f"{name}_{kind}",
+    )(offsets, *operands)
+
+
+def _fwd_impl(q, k, v, offsets, causal, block_q, block_k, t_q, t_k,
+              name=DEFAULT_NAME):
+    """q: (BH, Tq, D) padded to block multiples, k: (BH, Tk, D), v:
+    (BH, Tk, Dv) - the value width is its own (latent attention: q/k
+    192 wide, v 128); ``t_q``/``t_k`` are the actual (pre-padding)
+    lengths the masks validate against; ``offsets`` is a (2,) int32
+    [q_offset, k_offset] (may be traced).  Returns (o (BH, Tq, Dv), lse)
+    with lse lane-replicated (BH, Tq, 128) f32.  The kernel shows in a
+    device trace as ``<name>_fwd``."""
+    return _call("fwd", (q, k, v), offsets, causal, block_q, block_k, t_q,
+                 t_k, name)
+
+
 def _bwd_impl(q, k, v, do, lse, delta, offsets, causal, block_q, block_k,
               t_q, t_k, name=DEFAULT_NAME):
     """dq, dk (D wide) and dv (Dv wide, as ``v`` and ``do`` are); the two
     kernels show in a device trace as ``<name>_dq`` and ``<name>_dkv``."""
-    bh, t_q_pad, d = q.shape
-    t_k_pad = k.shape[1]
-    d_v = v.shape[2]
-    common = dict(scale=d ** -0.5, causal=causal, t_q=t_q, t_k=t_k,
-                  block_q=block_q, block_k=block_k)
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0))
-    do_spec = pl.BlockSpec((1, block_q, d_v), lambda b, qi, ki: (b, qi, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0))
-    v_spec = pl.BlockSpec((1, block_k, d_v), lambda b, qi, ki: (b, ki, 0))
-    row_spec = pl.BlockSpec((1, block_q, _LANES),
-                            lambda b, qi, ki: (b, qi, 0))
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **common),
-        grid=(bh, t_q_pad // block_q, t_k_pad // block_k),
-        in_specs=[_scalar_spec(), q_spec, k_spec, v_spec, do_spec, row_spec,
-                  row_spec],
-        out_specs=[q_spec],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=_interpret(),
-        name=f"{name}_dq",
-    )(offsets, q, k, v, do, lse, delta)[0]
-
-    # swapped grid: outer = K blocks, inner sweep = Q blocks
-    q_spec_t = pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0))
-    do_spec_t = pl.BlockSpec((1, block_q, d_v), lambda b, ki, qi: (b, qi, 0))
-    k_spec_t = pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0))
-    v_spec_t = pl.BlockSpec((1, block_k, d_v), lambda b, ki, qi: (b, ki, 0))
-    row_spec_t = pl.BlockSpec((1, block_q, _LANES),
-                              lambda b, ki, qi: (b, qi, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **common),
-        grid=(bh, t_k_pad // block_k, t_q_pad // block_q),
-        in_specs=[_scalar_spec(), q_spec_t, k_spec_t, v_spec_t, do_spec_t,
-                  row_spec_t, row_spec_t],
-        out_specs=[k_spec_t, v_spec_t],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d_v), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=_interpret(),
-        name=f"{name}_dkv",
-    )(offsets, q, k, v, do, lse, delta)
+    operands = (q, k, v, do, lse, delta)
+    where = (offsets, causal, block_q, block_k, t_q, t_k, name)
+    (dq,) = _call("dq", operands, *where)
+    dk, dv = _call("dkv", operands, *where)
     return dq, dk, dv
 
 
@@ -433,14 +489,141 @@ def _flash_bwd(causal, q_offset, k_offset, block_q, block_k, t_q, t_k,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _resolve_blocks(t_q, t_k, block_q, block_k):
-    for name, blk in (("block_q", block_q), ("block_k", block_k)):
-        if blk is not None and blk % _LANES:
-            raise ValueError(f"{name} ({blk}) must be a multiple of "
-                             f"{_LANES} (the TPU lane width)")
-    block_q = min(block_q or 256, _round_up(t_q, _LANES))
-    block_k = min(block_k or 256, _round_up(t_k, _LANES))
-    return block_q, block_k
+# Mosaic's scoped VMEM when a kernel asks for nothing, and the most the
+# picker's model may come to (a v5e core has 128 MiB)
+_VMEM_DEFAULT = 16 * 2 ** 20
+_VMEM_MOST = 32 * 2 ** 20
+# past this the diagonal blocks waste more products than the tile saves in
+# fetches and steps (25 % of the causal triangle at 1,024 of T 4,096)
+_LARGEST_BLOCK = 1024
+# (block_q, block_k) f32 arrays a kernel's body keeps alive at once, by
+# what the compiler needed at 33 tiles a kernel and precision of the latent
+# cell's shape (PERF.md, PR 29)
+_SCORE_TEMPS = {"fwd": 2.5, "dq": 3.5, "dkv": 3.0}
+
+
+def _window_bytes(kind, block_q, block_k, d, d_v, itemsize):
+    """(swept, resident, results): bytes of the operand blocks a kernel's
+    inner sweep fetches anew at each step, of those that stay for the whole
+    sweep, and of the results it writes once a sweep."""
+    rows = block_q * _LANES * 4  # one lane-replicated row statistic
+    q_blk, do_blk = block_q * d * itemsize, block_q * d_v * itemsize
+    kv_blk = block_k * (d + d_v) * itemsize
+    if kind == "fwd":
+        return kv_blk, q_blk, do_blk + rows
+    if kind == "dq":
+        return kv_blk, q_blk + do_blk + 2 * rows, q_blk
+    return q_blk + do_blk + 2 * rows, kv_blk, kv_blk
+
+
+def vmem_bytes(kind, block_q, block_k, d, d_v, itemsize, precise=False):
+    """Scoped VMEM one of the three kernels needs at a tile, from above:
+    every operand and result window twice (Pallas double-buffers them) and
+    under ``PRECISE_PRECISIONS`` once more (the six-pass products keep
+    their operands split), the f32 accumulators three times (each is read,
+    updated and written back), and the body's (block_q, block_k)
+    temporaries.  Held against the compiler in PERF.md (PR 29) and, at the
+    picked tiles, by ``tests/test_flash_compile_v5e.py``."""
+    outer = block_k if kind == "dkv" else block_q
+    scratch = outer * 4 * {"fwd": 2 * _LANES + d_v, "dq": d,
+                           "dkv": d + d_v}[kind]
+    windows = sum(_window_bytes(kind, block_q, block_k, d, d_v, itemsize))
+    return int((3 if precise else 2) * windows + 3 * scratch
+               + _SCORE_TEMPS[kind] * block_q * block_k * 4)
+
+
+def _caller_block(name, block, length):
+    """A caller's own block (``None``: left to the picker), checked, and
+    no larger than the length padded to the lane width."""
+    if block is None:
+        return None
+    if block % _LANES:
+        raise ValueError(f"{name} ({block}) must be a multiple of "
+                         f"{_LANES} (the TPU lane width)")
+    return min(block, _round_up(length, _LANES))
+
+
+def _tiles_of(length, block):
+    """The tiles a padded length admits: the caller's, or every multiple
+    of 128 that divides it, up to ``_LARGEST_BLOCK``."""
+    if block is not None:
+        return [block]
+    lanes = length // _LANES
+    return [n * _LANES for n in range(1, lanes + 1)
+            if lanes % n == 0 and n * _LANES <= _LARGEST_BLOCK]
+
+
+def pick_blocks(kind, t_q, t_k, d, d_v, itemsize, *, precise=None,
+                block_q=None, block_k=None):
+    """(block_q, block_k, vmem_limit_bytes or None) for kernel ``kind``
+    (``"fwd"``, ``"dq"`` or ``"dkv"``) over padded lengths ``t_q`` / ``t_k``,
+    from what the call can observe: the lengths, the widths, the operands'
+    itemsize and whether the ambient precision is one of
+    ``PRECISE_PRECISIONS`` (``precise=None`` reads it).
+
+    A block the caller gives stands.  An open one takes the tile of the
+    largest area among the multiples of 128 that divide its length, up to
+    ``_LARGEST_BLOCK``, whose need by :func:`vmem_bytes` is at most
+    ``_VMEM_MOST``.  Of two tiles of one area the wider key block wins,
+    in dQ the taller query block: the forward updates its running softmax
+    state (block_q rows) once a step, dQ and dK/dV re-read their swept
+    operands once an outer block (v5e sweep, PERF.md PR 29)."""
+    if precise is None:
+        precise = (jax.config.jax_default_matmul_precision
+                   in PRECISE_PRECISIONS)
+
+    def need(tile):
+        return vmem_bytes(kind, *tile, d, d_v, itemsize, precise)
+
+    tiles = [(bq, bk) for bq in _tiles_of(t_q, block_q)
+             for bk in _tiles_of(t_k, block_k)]
+    # the smallest is there whatever the model says: the compiler has the
+    # last word on a caller's own tile and on widths the model never saw
+    fits = [t for t in tiles if need(t) <= _VMEM_MOST] or [min(tiles)]
+    wider = 0 if kind == "dq" else 1
+    best = max(fits, key=lambda t: (t[0] * t[1], t[wider]))
+    # an eighth of headroom where the kernel asks for its own limit: the
+    # model stood 0.6 % above the compiler's need at its tightest point
+    bytes_ = need(best)
+    return (*best, bytes_ * 9 // 8 if bytes_ > _VMEM_DEFAULT else None)
+
+
+def schedule(kind, t_q, t_k, block_q, block_k, *, causal, q_offset=0,
+             k_offset=0):
+    """What kernel ``kind`` does on one row (one batch x head) of its grid,
+    counted in blocks from the functions the kernel and its index maps ask:
+    ``steps`` of the grid, ``computed`` (not above the diagonal), ``sweeps``
+    (outer blocks) and ``fetched``, the inner blocks the sweeps name anew
+    (a sweep's first block may be the one the sweep before left in VMEM;
+    that saving is not counted)."""
+    n_q, n_k = -(-t_q // block_q), -(-t_k // block_k)
+    computed = fetched = n_q * n_k
+    if causal:
+        tiles = dict(block_q=block_q, block_k=block_k)
+        qi, ki = jnp.meshgrid(jnp.arange(n_q), jnp.arange(n_k),
+                              indexing="ij")
+        computed = int((~_causal_skip(qi, ki, q_offset, k_offset,
+                                      **tiles)).sum())
+        if kind == "dkv":
+            fetched = int((n_q - _first_q_block(
+                ki[0], q_offset, k_offset, n_q=n_q, **tiles)).sum())
+        else:
+            fetched = int((1 + _last_k_block(
+                qi[:, 0], q_offset, k_offset, n_k=n_k, **tiles)).sum())
+    return {"steps": n_q * n_k, "computed": computed,
+            "sweeps": n_k if kind == "dkv" else n_q, "fetched": fetched}
+
+
+def fetched_bytes(kind, t_q, t_k, block_q, block_k, d, d_v, itemsize,
+                  **where):
+    """HBM bytes one row of kernel ``kind``'s grid moves by
+    :func:`schedule`: the swept blocks it fetches, and once a sweep the
+    resident operands and the results."""
+    counts = schedule(kind, t_q, t_k, block_q, block_k, **where)
+    swept, resident, results = _window_bytes(
+        kind, block_q, block_k, d, d_v, itemsize)
+    return (counts["fetched"] * swept
+            + counts["sweeps"] * (resident + results))
 
 
 def _flatten_pad(x, t_pad):
@@ -474,9 +657,12 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset: int = 0,
                          f"long as k, got {q.shape}/{k.shape}/{v.shape}")
     b, h, t_q, _ = q.shape
     t_k = k.shape[2]
-    block_q, block_k = _resolve_blocks(t_q, t_k, block_q, block_k)
-    t_q_pad = _round_up(t_q, block_q)
-    t_k_pad = _round_up(t_k, block_k)
+    block_q = _caller_block("block_q", block_q, t_q)
+    block_k = _caller_block("block_k", block_k, t_k)
+    # a block the caller leaves open is picked per kernel, among the tiles
+    # that divide the length padded to the lane width
+    t_q_pad = _round_up(t_q, block_q or _LANES)
+    t_k_pad = _round_up(t_k, block_k or _LANES)
     o = _flash(_flatten_pad(q, t_q_pad), _flatten_pad(k, t_k_pad),
                _flatten_pad(v, t_k_pad),
                causal, q_offset, k_offset, block_q, block_k, t_q, t_k, name)
@@ -608,11 +794,13 @@ def ring_flash_attention(q, k, v, axis: str, *, causal: bool = False,
     the visiting block and folds the result in through its logsumexp.
     """
     b, h, t_local, d = q.shape
-    block_q, block_k = _resolve_blocks(t_local, t_local, block_q, block_k)
+    block_q = _caller_block("block_q", block_q, t_local)
+    block_k = _caller_block("block_k", block_k, t_local)
     # Q and K share t_local in the ring, so one padded length must tile
     # by BOTH block sizes - max() would silently drop tail K blocks for
     # mismatched explicit blocks (e.g. 384/256 at t=300)
-    t_pad = _round_up(t_local, math.lcm(block_q, block_k))
+    t_pad = _round_up(t_local, math.lcm(block_q or _LANES,
+                                        block_k or _LANES))
     o = _ring_flash(_flatten_pad(q, t_pad), _flatten_pad(k, t_pad),
                     _flatten_pad(v, t_pad),
                     axis, causal, block_q, block_k, t_local)

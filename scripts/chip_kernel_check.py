@@ -14,7 +14,10 @@ family's CLI defaults (hidden 32 over 4 heads -> head_dim 8, batch 1440,
 T=128), and the same kernels with a value width of their own at the latent
 attention cell's shape (32 heads, T=4096, q / k 192 wide, v 128 wide, causal,
 f32; once more at JAX's default precision, where the kernel's f32 products
-are bf16 passes and the bf16 tolerance applies).  Everything else runs under
+are bf16 passes and the bf16 tolerance applies; and a sweep that times the
+three kernels alone there over a list of tiles, beside what the block
+schedule says each tile costs in grid steps and fetched bytes).  Everything
+else runs under
 ``jax.default_matmul_precision("highest")``
 and the reference always computes in float32 - on the kernel's own
 inputs, upcast - so it is the exact side of the comparison also for the
@@ -157,6 +160,76 @@ def _flash_case(batch, heads, seq, head_dim, dtype_name, *, v_dim=None,
                     dtype_name if precision == "highest" else "bfloat16")
 
 
+# (block_q, block_k) the sweep times each kernel at, besides the picker's own
+SWEEP_TILES = ((256, 256), (512, 512), (1024, 256), (256, 1024), (1024, 512),
+               (512, 1024), (1024, 1024), (2048, 512), (512, 2048))
+
+
+def _flash_sweep(batch, heads, seq, head_dim, v_dim, *, tiles=SWEEP_TILES,
+                 calls=5):
+    """Time forward, dq and dk / dv each alone (causal, f32, JAX's default
+    precision: the trainer's) at the picker's tile and at every tile of
+    ``tiles`` the compiler takes; one row a (kernel, tile) with ms a call
+    beside the schedule's grid steps and fetched bytes for the whole call.
+    Measures, compares nothing: ``ok`` says only that the picker's tiles
+    compiled and ran."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_rnn_tpu.ops import pallas_attention as pa
+
+    rows_n = batch * heads
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k = (jax.random.normal(key, (rows_n, seq, head_dim), jnp.float32)
+            for key in keys[:2])
+    v, do = (jax.random.normal(key, (rows_n, seq, v_dim), jnp.float32)
+             for key in keys[2:])
+    offsets = jnp.zeros((2,), jnp.int32)
+
+    def run(kind, block_q, block_k, *residuals):
+        operands = (q, k, v) if kind == "fwd" else (q, k, v, do, *residuals)
+        fn = jax.jit(lambda *a: pa._call(
+            kind, a, offsets, True, block_q, block_k, seq, seq, "sweep"))
+        out = jax.block_until_ready(fn(*operands))  # compiles
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*operands)
+        jax.block_until_ready(out)
+        return out, 1e3 * (time.perf_counter() - t0) / calls
+
+    table, ok = [], True
+    with jax.default_matmul_precision("default"):
+        (o, lse), _ = run("fwd", None, None)
+        delta = pa._delta_of(do, o)
+        for kind in ("fwd", "dq", "dkv"):
+            picked = pa.pick_blocks(kind, seq, seq, head_dim, v_dim, 4)[:2]
+            for tile in dict.fromkeys((picked, *tiles)):
+                counts = pa.schedule(kind, seq, seq, *tile, causal=True)
+                row = {
+                    "kernel": kind, "tile": list(tile),
+                    "picked": tile == picked,
+                    "grid_steps": rows_n * counts["steps"],
+                    "computed_blocks": rows_n * counts["computed"],
+                    "fetched_gb": round(rows_n * pa.fetched_bytes(
+                        kind, seq, seq, *tile, head_dim, v_dim, 4,
+                        causal=True) / 1e9, 3),
+                    "vmem_model_mib": round(pa.vmem_bytes(
+                        kind, *tile, head_dim, v_dim, 4) / 2 ** 20, 2),
+                }
+                try:
+                    row["ms_a_call"] = round(
+                        run(kind, *tile, lse, delta)[1], 3)
+                except Exception as exc:  # noqa: BLE001 - a refused tile
+                    row["refused"] = str(exc)[-300:]
+                    ok = ok and tile != picked
+                table.append(row)
+                print(json.dumps(row), flush=True)
+    return {"case": f"flash_attention sweep b{batch} h{heads} t{seq} "
+                    f"d{head_dim} v{v_dim} causal default",
+            "dtype": "float32", "calls_timed": calls, "sweep": table,
+            "ok": ok}
+
+
 def _provoke_refusals():
     """Make the installed compiler refuse programs and return what it
     says, verbatim (first 1500 characters)."""
@@ -263,6 +336,8 @@ def main(argv=None) -> int:
         "flash attention latent f32 default (mla_moe cell)":
             lambda: _flash_case(1, 32, 4096, 192, "float32", v_dim=128,
                                 causal=True, precision="default"),
+        "flash attention latent f32 default tile sweep (mla_moe cell)":
+            lambda: _flash_sweep(2, 32, 4096, 192, 128),
     }
     cases = {k: v for k, v in cases.items() if args.only in k}
     if not cases:

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from pytorch_distributed_rnn_tpu.models import AttentionClassifier
+from pytorch_distributed_rnn_tpu.ops import pallas_attention
 from pytorch_distributed_rnn_tpu.ops.attention import mha_attention
 from pytorch_distributed_rnn_tpu.ops.pallas_attention import (
     flash_attention,
@@ -363,3 +364,202 @@ class TestModelIntegration:
                 np.asarray(gf_l), np.asarray(gd_l), rtol=1e-4, atol=1e-5,
                 err_msg=jax.tree_util.keystr(pd),
             )
+
+
+# -- the block schedule (PR 29): the tiles, and what is fetched ---------------
+
+KINDS = ("fwd", "dq", "dkv")
+# the latent-attention cell's call: T 4,096, q / k 192 wide, v 128, f32
+CELL = dict(t_q=4096, t_k=4096, d=192, d_v=128, itemsize=4)
+
+
+class TestTilePicker:
+    @pytest.mark.parametrize("precise", [False, True],
+                             ids=["default", "highest"])
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_cell_shape_tiles_divide_the_length_and_fit_vmem(
+            self, kind, precise):
+        bq, bk, limit = pallas_attention.pick_blocks(
+            kind, *CELL.values(), precise=precise)
+        assert 4096 % bq == 0 and 4096 % bk == 0
+        assert bq % 128 == 0 and bk % 128 == 0
+        # larger than the 256 x 256 every call got before the picker
+        assert bq * bk > 256 * 256
+        need = pallas_attention.vmem_bytes(
+            kind, bq, bk, CELL["d"], CELL["d_v"], 4, precise)
+        assert need <= (limit or pallas_attention._VMEM_DEFAULT)
+        assert need <= pallas_attention._VMEM_MOST
+        # the scoped limit is raised only where the default does not do
+        assert (limit is None) == (need <= pallas_attention._VMEM_DEFAULT)
+
+    @pytest.mark.parametrize("t", [8, 64, 128, 200, 256])
+    def test_short_sequences_keep_one_block(self, t):
+        """T <= 256 got min(256, round_up(T, 128)) before the picker, and
+        gets it still, from every kernel at both precisions."""
+        padded = -(-t // 128) * 128
+        for kind in KINDS:
+            for precise in (False, True):
+                assert pallas_attention.pick_blocks(
+                    kind, padded, padded, 16, 16, 4,
+                    precise=precise) == (padded, padded, None)
+
+    def test_explicit_blocks_win(self):
+        assert pallas_attention.pick_blocks(
+            "fwd", 4096, 4096, 192, 128, 4, block_q=128,
+            block_k=256)[:2] == (128, 256)
+        # one side given: the other is still picked, and divides its length
+        bq, bk, _ = pallas_attention.pick_blocks(
+            "dkv", 4096, 1536, 192, 128, 4, block_q=128)
+        assert bq == 128 and 1536 % bk == 0 and bk > 128
+
+    def test_a_block_off_the_lane_width_is_refused(self):
+        q, k, v = _qkv(t_q=128)
+        with pytest.raises(ValueError, match="multiple of 128"):
+            flash_attention(q, k, v, block_q=96)
+
+    @pytest.mark.parametrize("ambient,precise",
+                             [("highest", True), ("float32", True),
+                              ("default", False), ("bfloat16", False)])
+    def test_the_picker_reads_the_precision_it_is_traced_under(
+            self, ambient, precise):
+        with jax.default_matmul_precision(ambient):
+            picked = pallas_attention.pick_blocks("dq", *CELL.values())
+        assert picked == pallas_attention.pick_blocks(
+            "dq", *CELL.values(), precise=precise)
+        # the limit follows the need, which the precision moves
+        assert picked[2] != pallas_attention.pick_blocks(
+            "dq", *CELL.values(), precise=not precise)[2]
+
+    def test_vmem_model_grows_with_the_tile_and_the_precision(self):
+        model = pallas_attention.vmem_bytes
+        for kind in KINDS:
+            small = model(kind, 256, 256, 192, 128, 4)
+            assert small < model(kind, 512, 256, 192, 128, 4)
+            assert small < model(kind, 256, 512, 192, 128, 4)
+            assert small < model(kind, 256, 256, 192, 128, 4, precise=True)
+
+
+class TestSchedule:
+    @pytest.mark.parametrize("kind", KINDS)
+    @pytest.mark.parametrize("block_q,block_k",
+                             [(256, 256), (512, 512), (1024, 256),
+                              (256, 1024)])
+    def test_a_causal_call_fetches_the_blocks_it_computes(
+            self, kind, block_q, block_k):
+        counts = pallas_attention.schedule(
+            kind, 4096, 4096, block_q, block_k, causal=True)
+        assert counts["fetched"] == counts["computed"] < counts["steps"]
+        # no fewer than the triangle needs, and no block above its edge
+        n_q, n_k = 4096 // block_q, 4096 // block_k
+        needed = sum(
+            1 for qi in range(n_q) for ki in range(n_k)
+            if (qi + 1) * block_q - 1 >= ki * block_k)
+        assert counts["computed"] == needed
+
+    def test_the_cell_s_old_tiles_by_hand(self):
+        counts = pallas_attention.schedule(
+            "fwd", 4096, 4096, 256, 256, causal=True)
+        assert counts == {"steps": 256, "computed": 136, "sweeps": 16,
+                          "fetched": 136}
+
+    def test_chunks_ragged_tails_and_full_attention(self):
+        sched = pallas_attention.schedule
+        # a later chunk of queries over an earlier chunk of keys: all of it
+        assert sched("dq", 256, 256, 128, 128, causal=True,
+                     q_offset=256) == {
+            "steps": 4, "computed": 4, "sweeps": 2, "fetched": 4}
+        # queries before every key: nothing computed, one block named
+        assert sched("dkv", 256, 256, 128, 128, causal=True,
+                     k_offset=512)["computed"] == 0
+        # a ragged tail is padded to whole blocks: the triangle of 3 x 3
+        assert sched("fwd", 300, 300, 128, 128, causal=True) == {
+            "steps": 9, "computed": 6, "sweeps": 3, "fetched": 6}
+        # no causal mask: every step computes and fetches
+        assert sched("fwd", 512, 256, 128, 128, causal=False) == {
+            "steps": 8, "computed": 8, "sweeps": 4, "fetched": 8}
+
+    def test_fetched_bytes_of_the_cell_s_forward_by_hand(self):
+        # 36 K / V blocks of 512 x (192 + 128) f32, and 8 sweeps' Q block,
+        # output block and lane-replicated logsumexp
+        assert pallas_attention.fetched_bytes(
+            "fwd", 4096, 4096, 512, 512, 192, 128, 4, causal=True) == (
+            36 * 512 * 320 * 4 + 8 * 512 * (192 + 128 + 128) * 4)
+
+
+def _grads(attn, q, k, v, **kw):
+    def loss(q, k, v):
+        return jnp.sum(jnp.sin(attn(q, k, v, **kw)))
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """The picker's own path (no explicit block) at tiles of 128, so a short
+    sequence has blocks above, on and below the causal diagonal."""
+    monkeypatch.setattr(pallas_attention, "_LARGEST_BLOCK", 128)
+
+
+class TestPickedTilesParity:
+    """Forward and the three gradients against ``mha_attention`` where the
+    grid has blocks above, on and below the causal diagonal (4 a side,
+    widths 16 / 8)."""
+
+    def _qkv(self, t_q, t_k):
+        keys = jax.random.split(jax.random.PRNGKey(3), 3)
+        return (jax.random.normal(keys[0], (1, 2, t_q, 16)),
+                jax.random.normal(keys[1], (1, 2, t_k, 16)),
+                jax.random.normal(keys[2], (1, 2, t_k, 8)))
+
+    @pytest.mark.parametrize(
+        "t_q,t_k,where",
+        [(512, 512, {}),
+         (384, 512, dict(q_offset=256, k_offset=128)),
+         (450, 450, {}),
+         (512, 512, dict(block_q=256, block_k=128))],
+        ids=["whole", "offset_chunks", "ragged", "tall_explicit"])
+    def test_forward_and_gradients(self, small_tiles, t_q, t_k, where):
+        q, k, v = self._qkv(t_q, t_k)
+        where = dict(causal=True, **where)
+        dense = {key: where[key] for key in where
+                 if not key.startswith("block")}
+        np.testing.assert_allclose(
+            np.asarray(flash_attention(q, k, v, **where)),
+            np.asarray(mha_attention(q, k, v, **dense)),
+            rtol=1e-5, atol=1e-5)
+        for name, want, got in zip(
+                "qkv", _grads(mha_attention, q, k, v, **dense),
+                _grads(flash_attention, q, k, v, **where)):
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5,
+                err_msg=f"d{name}")
+
+    def test_ring_with_traced_offsets(self, small_tiles):
+        """Two shards of 4 blocks each: the index maps clamp on offsets that
+        are ``lax.axis_index`` products, unknown at trace time."""
+        from functools import partial
+
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        from pytorch_distributed_rnn_tpu.ops.pallas_attention import (
+            ring_flash_attention,
+        )
+        from pytorch_distributed_rnn_tpu.parallel import make_mesh
+
+        q, k, v = _qkv(t_q=1024, b=1, h=2, d=16)
+        ring = shard_map(
+            partial(ring_flash_attention, axis="sp", causal=True),
+            mesh=make_mesh({"sp": 2}),
+            in_specs=(P(None, None, "sp"),) * 3,
+            out_specs=P(None, None, "sp"), check_vma=False)
+        np.testing.assert_allclose(
+            np.asarray(jax.jit(ring)(q, k, v)),
+            np.asarray(mha_attention(q, k, v, causal=True)),
+            rtol=1e-5, atol=1e-5)
+        for name, want, got in zip(
+                "qkv", _grads(mha_attention, q, k, v, causal=True),
+                _grads(ring, q, k, v)):
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5,
+                err_msg=f"d{name}")
