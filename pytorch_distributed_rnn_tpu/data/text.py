@@ -141,3 +141,15 @@ class TextDataset:
         valid = cls(windows[n_test : n_test + n_valid], vocab_size)
         train = cls(windows[n_test + n_valid :], vocab_size)
         return train, valid, test
+
+
+def flag_vocab_size(args, training_set) -> int:
+    """--vocab-size, else what the data declares; never fewer rows than
+    the data has ids."""
+    vocab = getattr(args, "vocab_size", None) or training_set.vocab_size
+    if vocab < training_set.vocab_size:
+        raise SystemExit(
+            f"--vocab-size {vocab} is smaller than the data's vocabulary "
+            f"({training_set.vocab_size})"
+        )
+    return vocab

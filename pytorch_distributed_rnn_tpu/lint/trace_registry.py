@@ -5,7 +5,7 @@ The AST rules (PD1xx) see source text; the deep rules (PD2xx,
 concrete step function is bound to concrete input specs and a mesh.
 This module is where each trainer family declares that binding: every
 provider module (``training/native_ddp.py``, ``training/zero.py``,
-``training/moe.py``, ``parallel/{dp,tp,sp,pp,ep}.py``) exposes a
+``parallel/{dp,tp,sp,pp,ep,strategy}.py``) exposes a
 ``declare_trace_entries(register)`` hook that registers its step/forward
 entry points with ABSTRACT input specs - shapes and dtypes only, via
 ``jax.ShapeDtypeStruct`` / ``jax.eval_shape``, no real data and no
@@ -45,7 +45,7 @@ PROVIDER_MODULES = (
     "pytorch_distributed_rnn_tpu.parallel.ep",
     "pytorch_distributed_rnn_tpu.training.native_ddp",
     "pytorch_distributed_rnn_tpu.training.zero",
-    "pytorch_distributed_rnn_tpu.training.moe",
+    "pytorch_distributed_rnn_tpu.parallel.strategy",
     "pytorch_distributed_rnn_tpu.serving.engine",
     "pytorch_distributed_rnn_tpu.parallel.mpmd",
     "pytorch_distributed_rnn_tpu.streaming.runner",

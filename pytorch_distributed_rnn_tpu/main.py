@@ -32,6 +32,11 @@ DEFAULT_DATASET_PATH = Path("data")
 
 
 def build_parser() -> argparse.ArgumentParser:
+    # imported here, not at the top, so that importing this module stays
+    # cheap (the registries import JAX)
+    from pytorch_distributed_rnn_tpu import param_server, training
+    from pytorch_distributed_rnn_tpu.training import families
+
     parser = argparse.ArgumentParser(
         description="TPU-native distributed RNN trainer"
     )
@@ -52,25 +57,8 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--seed", default=None, type=int)
     parser.add_argument("--no-validation", action="store_true")
     parser.add_argument("--cell", default="lstm", choices=["lstm", "gru"])
-    parser.add_argument(
-        "--model", default="rnn",
-        choices=["rnn", "attention", "char", "moe", "mla_moe"],
-        help="model family: stacked RNN (reference parity), the "
-        "attention classifier (long-context family; composes the full "
-        "dp x sp x tp mesh under the mesh strategy), the byte-level "
-        "char LM (next-token loss on --dataset-path corpus.txt windows, "
-        "synthetic motif stream when absent), or the MoE classifier "
-        "(RNN backbone + Switch-routed expert FFN; experts shard over "
-        "the ep mesh axis under the mesh strategy), or mla_moe: a "
-        "DeepSeek-V3-style decoder LM (latent attention, sigmoid top-k "
-        "routed experts, a shared expert, a multi-token-prediction "
-        "module) as one chip of an expert-parallel deployment trains "
-        "it - --hidden-units / --stacked-layer / --num-heads / "
-        "--num-experts / --moe-top-k give its hidden size, layers, "
-        "heads, routed experts and experts per token, the --mla-* / "
-        "--ffn-dims / --experts-held / --vocab-size flags the rest "
-        "(defaults: the published JoyAI-LLM-Flash widths)",
-    )
+    # --model, and each family's own flags, come from the model classes
+    families.add_model_flags(parser)
     parser.add_argument(
         "--seq-length", default=None, type=int, metavar="T",
         help="token-window length for --model char / mla_moe (default "
@@ -83,45 +71,6 @@ def build_parser() -> argparse.ArgumentParser:
         "data declares (256 for a byte corpus).  A slice of a larger "
         "vocabulary is a smaller vocabulary: the data's ids must lie "
         "under it",
-    )
-    parser.add_argument(
-        "--mla-ranks", default="1536,512", metavar="Q,KV",
-        help="--model mla_moe: ranks of the low-rank query and key-value "
-        "projections (q_lora_rank, kv_lora_rank)",
-    )
-    parser.add_argument(
-        "--mla-head-dims", default="128,64,128", metavar="NOPE,ROPE,V",
-        help="--model mla_moe: per-head widths of the un-rotated and "
-        "the rotary part of q / k and of v (qk_nope_head_dim, "
-        "qk_rope_head_dim, v_head_dim)",
-    )
-    parser.add_argument(
-        "--rope-theta", default=32e6, type=float,
-        help="--model mla_moe: base of the rotary embedding",
-    )
-    parser.add_argument(
-        "--ffn-dims", default="7168,768", metavar="DENSE,EXPERT",
-        help="--model mla_moe: width of the leading dense layer's MLP and "
-        "of one expert (intermediate_size, moe_intermediate_size)",
-    )
-    parser.add_argument(
-        "--experts-held", default=None, metavar="FIRST:COUNT",
-        help="--model mla_moe: the share of each layer's --num-experts "
-        "routed experts this chip holds, as an expert-parallel rank "
-        "does.  The router scores all experts; the layer computes its "
-        "own experts' part for the tokens routed to them and drops "
-        "none.  Default: all of them",
-    )
-    parser.add_argument(
-        "--moe-route-scale", default=2.5, type=float,
-        help="--model mla_moe: routed_scaling_factor on the normalised "
-        "weights of the picked experts",
-    )
-    parser.add_argument(
-        "--mtp-weight", default=0.3, type=float,
-        help="--model mla_moe: weight of the multi-token-prediction "
-        "module's loss (one module, predicting the token after next); "
-        "0 builds no module",
     )
     parser.add_argument(
         "--num-heads", default=4, type=int,
@@ -325,9 +274,6 @@ def build_parser() -> argparse.ArgumentParser:
         title="Available commands", metavar="command [options ...]"
     )
     sub_parser.required = True
-
-    # imported lazily so --help works fast and the registries stay decoupled
-    from pytorch_distributed_rnn_tpu import param_server, training
 
     param_server.add_sub_command(sub_parser)
     training.add_sub_commands(sub_parser)

@@ -18,6 +18,9 @@ import jax.numpy as jnp
 
 from pytorch_distributed_rnn_tpu.ops.attention import mha_attention
 from pytorch_distributed_rnn_tpu.ops.initializers import linear_init
+from pytorch_distributed_rnn_tpu.ops.losses import (
+    classification_loss_and_metrics,
+)
 
 
 def _layer_norm(x, scale, bias, eps=1e-5):
@@ -119,6 +122,13 @@ class AttentionClassifier:
     """Pre-norm Transformer encoder over (B, T, input_dim) windows, mean
     pooled into class logits."""
 
+    family = "attention"
+    data_kind = "har"
+    family_help = (
+        "the attention classifier (long-context family; composes the full "
+        "dp x sp x tp mesh under the mesh strategy)"
+    )
+
     input_dim: int = 9
     dim: int = 64
     depth: int = 2
@@ -144,6 +154,33 @@ class AttentionClassifier:
                 f"{self.num_heads} (head splitting would silently "
                 f"truncate projections)"
             )
+
+    @classmethod
+    def from_args(cls, args, training_set):
+        from pytorch_distributed_rnn_tpu.data import MotionDataset
+
+        if getattr(args, "cell", "lstm") != "lstm":
+            raise SystemExit(
+                "--model attention does not support: --cell gru "
+                "(the encoder has no recurrent cell)"
+            )
+        return cls(
+            input_dim=training_set.num_features,
+            dim=args.hidden_units,
+            depth=args.stacked_layer,
+            num_heads=getattr(args, "num_heads", 4),
+            output_dim=len(MotionDataset.LABELS),
+            dropout=getattr(args, "dropout", 0.0) or 0.0,
+            precision=getattr(args, "precision", "f32"),
+            remat=getattr(args, "remat", False),
+        )
+
+    def resolved_impl(self) -> str:
+        from pytorch_distributed_rnn_tpu.ops.pallas_attention import (
+            resolve_attention_impl,
+        )
+
+        return resolve_attention_impl(self.impl)
 
     def init(self, key: jax.Array):
         ks = jax.random.split(key, self.depth + 3)
@@ -200,3 +237,8 @@ class AttentionClassifier:
         # pooled head in f32 regardless of compute dtype (model contract)
         pooled = jnp.mean(h.astype(jnp.float32), axis=1)
         return _linear(params["head"], pooled)
+
+    def loss_and_metrics(self, params, batch, dropout_key=None, weights=None):
+        x, y = batch
+        logits = self.apply(params, x, dropout_key=dropout_key)
+        return classification_loss_and_metrics(logits, y, weights)

@@ -22,10 +22,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from pytorch_distributed_rnn_tpu.ops.initializers import linear_init
-from pytorch_distributed_rnn_tpu.ops.losses import cross_entropy_loss
+from pytorch_distributed_rnn_tpu.ops.losses import (
+    cross_entropy_loss,
+    next_token_loss_and_metrics,
+)
 from pytorch_distributed_rnn_tpu.ops.rnn import (
     head_logits,
     init_stacked_rnn,
+    resolve_rnn_impl,
     stacked_rnn,
 )
 
@@ -34,6 +38,13 @@ from pytorch_distributed_rnn_tpu.ops.rnn import (
 class CharRNN:
     """``params = model.init(key)``; ``logits = model.apply(params, tokens)``
     maps (B, T) int tokens -> (B, T, vocab) next-token logits."""
+
+    family = "char"
+    data_kind = "tokens"
+    family_help = (
+        "the byte-level char LM (next-token loss on --dataset-path "
+        "corpus.txt windows, synthetic motif stream when absent)"
+    )
 
     vocab_size: int = 256
     embed_dim: int = 128
@@ -45,6 +56,24 @@ class CharRNN:
     precision: str = "f32"  # "bf16": bf16 compute, f32 params (MXU rate)
     remat: bool = False  # recompute activations in backward (HBM lever)
     dropout: float = 0.0  # inter-layer dropout (train mode only)
+
+    @classmethod
+    def from_args(cls, args, training_set):
+        from pytorch_distributed_rnn_tpu.data.text import flag_vocab_size
+
+        return cls(
+            vocab_size=flag_vocab_size(args, training_set),
+            embed_dim=args.hidden_units,
+            hidden_dim=args.hidden_units,
+            layer_dim=args.stacked_layer,
+            cell=getattr(args, "cell", "lstm"),
+            precision=getattr(args, "precision", "f32"),
+            remat=getattr(args, "remat", False),
+            dropout=getattr(args, "dropout", 0.0) or 0.0,
+        )
+
+    def resolved_impl(self) -> str:
+        return resolve_rnn_impl(self.impl, self.cell, hidden=self.hidden_dim)
 
     def init(self, key: jax.Array):
         k_embed, k_rnn, k_head = jax.random.split(key, 3)
@@ -84,6 +113,15 @@ class CharRNN:
         return cross_entropy_loss(
             logits.reshape(-1, self.vocab_size), targets.reshape(-1)
         )
+
+    def loss_and_metrics(self, params, batch, dropout_key=None, weights=None):
+        """A batch is ``(tokens (B, T + 1) int32, dummy labels)``: inputs
+        are ``tokens[:, :-1]``, targets ``tokens[:, 1:]``, as in
+        :meth:`loss`."""
+        tokens, _ = batch
+        logits = self.apply(params, tokens[:, :-1], dropout_key=dropout_key)
+        return next_token_loss_and_metrics(
+            logits.astype(jnp.float32), tokens[:, 1:], weights)
 
     def generate(self, params, prompt: jax.Array, length: int,
                  key: jax.Array | None = None,

@@ -94,6 +94,19 @@ class MlaMoeLM:
     token windows; ``model.apply(params, tokens)`` gives the main model's
     (B, T, vocab) next-token logits."""
 
+    family = "mla_moe"
+    data_kind = "tokens"
+    family_help = (
+        "a DeepSeek-V3-style decoder LM (latent attention, sigmoid top-k "
+        "routed experts, a shared expert, a multi-token-prediction "
+        "module) as one chip of an expert-parallel deployment trains "
+        "it - --hidden-units / --stacked-layer / --num-heads / "
+        "--num-experts / --moe-top-k give its hidden size, layers, "
+        "heads, routed experts and experts per token, the --mla-* / "
+        "--ffn-dims / --experts-held / --vocab-size flags the rest "
+        "(defaults: the published JoyAI-LLM-Flash widths)"
+    )
+
     vocab_size: int                 # rows held of `vocab_size`
     hidden_dim: int = 2048          # hidden_size
     layer_dim: int = 5              # num_hidden_layers kept
@@ -142,6 +155,109 @@ class MlaMoeLM:
     def held(self) -> int:
         return (self.num_experts if self.experts_held is None
                 else self.experts_held)
+
+    # -- the command line ---------------------------------------------------
+
+    @staticmethod
+    def add_flags(parser):
+        parser.add_argument(
+            "--mla-ranks", default="1536,512", metavar="Q,KV",
+            help="--model mla_moe: ranks of the low-rank query and "
+            "key-value projections (q_lora_rank, kv_lora_rank)",
+        )
+        parser.add_argument(
+            "--mla-head-dims", default="128,64,128", metavar="NOPE,ROPE,V",
+            help="--model mla_moe: per-head widths of the un-rotated and "
+            "the rotary part of q / k and of v (qk_nope_head_dim, "
+            "qk_rope_head_dim, v_head_dim)",
+        )
+        parser.add_argument(
+            "--rope-theta", default=32e6, type=float,
+            help="--model mla_moe: base of the rotary embedding",
+        )
+        parser.add_argument(
+            "--ffn-dims", default="7168,768", metavar="DENSE,EXPERT",
+            help="--model mla_moe: width of the leading dense layer's MLP "
+            "and of one expert (intermediate_size, moe_intermediate_size)",
+        )
+        parser.add_argument(
+            "--experts-held", default=None, metavar="FIRST:COUNT",
+            help="--model mla_moe: the share of each layer's --num-experts "
+            "routed experts this chip holds, as an expert-parallel rank "
+            "does.  The router scores all experts; the layer computes its "
+            "own experts' part for the tokens routed to them and drops "
+            "none.  Default: all of them",
+        )
+        parser.add_argument(
+            "--moe-route-scale", default=2.5, type=float,
+            help="--model mla_moe: routed_scaling_factor on the normalised "
+            "weights of the picked experts",
+        )
+        parser.add_argument(
+            "--mtp-weight", default=0.3, type=float,
+            help="--model mla_moe: weight of the multi-token-prediction "
+            "module's loss (one module, predicting the token after next); "
+            "0 builds no module",
+        )
+
+    @classmethod
+    def from_args(cls, args, training_set):
+        """Every flag the family cannot honour is refused, and a share
+        that is no share of the layer too."""
+        from pytorch_distributed_rnn_tpu.data.text import flag_vocab_size
+
+        refused = [
+            flag for flag, bad in (
+                ("--dropout (pass --dropout 0: the family has none; the CLI "
+                 "default 0.1 mirrors the reference surface)",
+                 bool(getattr(args, "dropout", 0.0))),
+                ("--cell gru (no recurrent cell)",
+                 getattr(args, "cell", "lstm") != "lstm"),
+                ("--precision bf16 (its bf16 path has not been brought up)",
+                 getattr(args, "precision", "f32") != "f32"),
+                ("--moe-router expert (tokens pick experts here)",
+                 getattr(args, "moe_router", "token") != "token"),
+                ("--moe-group-size (no capacity slots: no pick is dropped)",
+                 getattr(args, "moe_group_size", None) is not None),
+                ("--fuse-run (its loss has no per-sequence weighted form)",
+                 bool(getattr(args, "fuse_run", False))),
+            ) if bad
+        ]
+        if refused:
+            raise SystemExit(
+                "--model mla_moe does not support: " + "; ".join(refused))
+        q_rank, kv_rank = _ints(args, "--mla-ranks", 2)
+        nope_dim, rope_dim, v_dim = _ints(args, "--mla-head-dims", 3)
+        dense_ffn, expert_ffn = _ints(args, "--ffn-dims", 2)
+        first, held = 0, None
+        if getattr(args, "experts_held", None) is not None:
+            first, held = _ints(args, "--experts-held", 2, sep=":")
+        try:
+            return cls(
+                vocab_size=flag_vocab_size(args, training_set),
+                hidden_dim=args.hidden_units,
+                layer_dim=args.stacked_layer,
+                num_heads=getattr(args, "num_heads", 4),
+                q_rank=q_rank, kv_rank=kv_rank,
+                nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+                rope_theta=args.rope_theta,
+                dense_ffn_dim=dense_ffn, expert_ffn_dim=expert_ffn,
+                num_experts=getattr(args, "num_experts", 4),
+                num_selected=getattr(args, "moe_top_k", 1),
+                experts_first=first, experts_held=held,
+                route_scale=args.moe_route_scale,
+                mtp_weight=args.mtp_weight,
+                remat=getattr(args, "remat", False),
+            )
+        except ValueError as exc:
+            raise SystemExit(f"--model mla_moe: {exc}") from None
+
+    def resolved_impl(self) -> str:
+        from pytorch_distributed_rnn_tpu.ops.pallas_attention import (
+            resolve_attention_impl,
+        )
+
+        return resolve_attention_impl(self.impl)
 
     # -- parameters ---------------------------------------------------------
 
@@ -336,6 +452,36 @@ class MlaMoeLM:
                 moe_picks_dropped=sum(c["picks_dropped"] for c in counters),
             )
         return loss, stats
+
+    def loss_and_metrics(self, params, batch, dropout_key=None, weights=None):
+        """:meth:`loss_and_stats` over a ``(tokens, dummy labels)`` batch.
+        What ``stats`` holds beside ``correct`` rides the step's metrics
+        to the fetch the loop already makes (``Trainer._fetch_correct``).
+        The loss is more than cross entropy of one logit array and has no
+        per-sequence weighted form, so the family refuses ``--fuse-run``
+        and this refuses ``weights``; it has no dropout."""
+        if weights is not None:
+            raise NotImplementedError(
+                "--model mla_moe: its loss has no per-sequence weighted form")
+        tokens, _ = batch
+        return self.loss_and_stats(params, tokens)
+
+
+def _ints(args, flag: str, count: int, sep: str = ","):
+    """A flag that holds ``count`` whole numbers, e.g. ``--mla-ranks
+    1536,512``."""
+    text = getattr(args, flag.lstrip("-").replace("-", "_"))
+    try:
+        values = tuple(int(v) for v in text.split(sep))
+    except ValueError:
+        values = ()
+    if len(values) != count or min(values) < 0:
+        raise SystemExit(
+            f"{flag} wants {count} whole numbers separated by {sep!r}, "
+            f"got {text!r}"
+        )
+    return values
+
 
 @functools.partial(jax.checkpoint, static_argnums=(4,))
 def _head_nll(h, norm, head, targets, eps):

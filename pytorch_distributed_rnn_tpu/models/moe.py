@@ -44,6 +44,10 @@ import jax
 import jax.numpy as jnp
 
 from pytorch_distributed_rnn_tpu.ops.initializers import linear_init
+from pytorch_distributed_rnn_tpu.ops.losses import (
+    classification_loss_and_metrics,
+    cross_entropy_loss,
+)
 from pytorch_distributed_rnn_tpu.ops.moe import init_moe_ffn, moe_ffn_dense
 from pytorch_distributed_rnn_tpu.ops.rnn import init_stacked_rnn, stacked_rnn
 
@@ -52,6 +56,13 @@ from pytorch_distributed_rnn_tpu.ops.rnn import init_stacked_rnn, stacked_rnn
 class MoEClassifier:
     """Functional model: ``params = model.init(key)``,
     ``logits = model.apply(params, x)`` (dense-exact path)."""
+
+    family = "moe"
+    data_kind = "har"
+    family_help = (
+        "the MoE classifier (RNN backbone + Switch-routed expert FFN; "
+        "experts shard over the ep mesh axis under the mesh strategy)"
+    )
 
     input_dim: int = 9
     hidden_dim: int = 32
@@ -127,6 +138,40 @@ class MoEClassifier:
                 f"number, got {self.capacity_factor}"
             )
 
+    @classmethod
+    def from_args(cls, args, training_set):
+        from pytorch_distributed_rnn_tpu.data import MotionDataset
+
+        if getattr(args, "moe_top_k", 1) not in (1, 2):
+            raise SystemExit(
+                "--model moe does not support: --moe-top-k "
+                f"{args.moe_top_k} (1 = Switch, 2 = GShard)"
+            )
+        if getattr(args, "dropout", 0.0):
+            raise SystemExit(
+                "--model moe does not support: --dropout "
+                "(pass --dropout 0; the CLI default 0.1 mirrors the "
+                "reference surface)"
+            )
+        return cls(
+            input_dim=training_set.num_features,
+            hidden_dim=args.hidden_units,
+            layer_dim=args.stacked_layer,
+            output_dim=len(MotionDataset.LABELS),
+            num_experts=getattr(args, "num_experts", 4),
+            num_selected=getattr(args, "moe_top_k", 1),
+            router_type=getattr(args, "moe_router", "token"),
+            capacity_factor=getattr(args, "moe_capacity_factor", 2.0),
+            group_size=getattr(args, "moe_group_size", None),
+            cell=getattr(args, "cell", "lstm"),
+            precision=getattr(args, "precision", "f32"),
+            remat=getattr(args, "remat", False),
+        )
+
+    def resolved_impl(self) -> None:
+        """The backbone always takes the scan path: no switch."""
+        return None
+
     @property
     def _expert_hidden(self) -> int:
         return self.expert_hidden or 2 * self.hidden_dim
@@ -177,8 +222,8 @@ class MoEClassifier:
 
     def apply_with_aux(self, params, x: jax.Array, dropout_key=None):
         """(logits (B, out), aux scalar).  ``dropout_key`` accepted for the
-        shared ``_apply_model`` signature; the family has no dropout (the
-        CLI rejects the flag loudly)."""
+        signature the families share; the family has no dropout (the CLI
+        rejects the flag loudly)."""
         h, aux = self.features(params, x)
         last = h[:, -1, :].astype(jnp.float32)
         logits = last @ params["fc"]["weight"].T + params["fc"]["bias"]
@@ -186,3 +231,23 @@ class MoEClassifier:
 
     def apply(self, params, x: jax.Array, dropout_key=None) -> jax.Array:
         return self.apply_with_aux(params, x, dropout_key)[0]
+
+    def loss_and_metrics(self, params, batch, dropout_key=None, weights=None):
+        """Classification plus the Switch load-balancing loss (dense-exact
+        forward), for training AND evaluation: one objective, comparable
+        across epochs.  Under 0/1 ``weights`` (the whole-run program's
+        padding mask) the aux loss still runs over ALL rows: padding rows
+        are real (repeated) examples, so the router statistics stay
+        well-defined, and all-ones weights give the plain loss exactly."""
+        x, y = batch
+        logits, aux = self.apply_with_aux(params, x, dropout_key)
+        if weights is None:
+            loss, metrics = classification_loss_and_metrics(logits, y)
+            return loss + self.aux_weight * aux, metrics
+        nll = cross_entropy_loss(logits, y, reduction="none")
+        loss = (
+            jnp.sum(nll * weights) / jnp.maximum(jnp.sum(weights), 1.0)
+            + self.aux_weight * aux
+        )
+        correct = jnp.sum((jnp.argmax(logits, axis=1) == y) * (weights > 0))
+        return loss, {"correct": correct}

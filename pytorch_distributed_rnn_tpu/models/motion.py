@@ -17,13 +17,24 @@ import jax
 import jax.numpy as jnp
 
 from pytorch_distributed_rnn_tpu.ops.initializers import linear_init
-from pytorch_distributed_rnn_tpu.ops.rnn import init_stacked_rnn, stacked_rnn
+from pytorch_distributed_rnn_tpu.ops.losses import (
+    classification_loss_and_metrics,
+)
+from pytorch_distributed_rnn_tpu.ops.rnn import (
+    init_stacked_rnn,
+    resolve_rnn_impl,
+    stacked_rnn,
+)
 
 
 @dataclass(frozen=True)
 class MotionModel:
     """Functional model: ``params = model.init(key)``,
     ``logits = model.apply(params, x)``."""
+
+    family = "rnn"
+    data_kind = "har"
+    family_help = "the stacked RNN (reference parity)"
 
     input_dim: int = 9
     hidden_dim: int = 32
@@ -38,6 +49,24 @@ class MotionModel:
     # never uses --dropout (/root/reference/src/motion/main.py:26) - here
     # the flag is real (conscious fix, PARITY.md): train mode passes a
     # dropout_key, eval passes none and stays deterministic
+
+    @classmethod
+    def from_args(cls, args, training_set):
+        from pytorch_distributed_rnn_tpu.data import MotionDataset
+
+        return cls(
+            input_dim=training_set.num_features,
+            hidden_dim=args.hidden_units,
+            layer_dim=args.stacked_layer,
+            output_dim=len(MotionDataset.LABELS),
+            cell=getattr(args, "cell", "lstm"),
+            precision=getattr(args, "precision", "f32"),
+            remat=getattr(args, "remat", False),
+            dropout=getattr(args, "dropout", 0.0) or 0.0,
+        )
+
+    def resolved_impl(self) -> str:
+        return resolve_rnn_impl(self.impl, self.cell, hidden=self.hidden_dim)
 
     def init(self, key: jax.Array):
         rnn_key, fc_key = jax.random.split(key)
@@ -65,3 +94,8 @@ class MotionModel:
         with jax.named_scope("head"):
             last = outputs[:, -1, :].astype(jnp.float32)
             return last @ params["fc"]["weight"].T + params["fc"]["bias"]
+
+    def loss_and_metrics(self, params, batch, dropout_key=None, weights=None):
+        x, y = batch
+        logits = self.apply(params, x, dropout_key=dropout_key)
+        return classification_loss_and_metrics(logits, y, weights)

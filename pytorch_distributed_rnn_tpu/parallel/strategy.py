@@ -507,8 +507,9 @@ def make_char_mesh_loss_fn(mesh, axes: dict[str, int], *,
     shared loaders/epoch programs drive the LM unchanged).
 
     ``metrics['correct']`` sums per-sequence mean token accuracy over the
-    GLOBAL batch (``training/lm.py`` semantics), so the shared loop's
-    ``correct / len(dataset)`` prints mean token accuracy.
+    GLOBAL batch (as ``ops/losses.py:next_token_loss_and_metrics`` does),
+    so the shared loop's ``correct / len(dataset)`` prints mean token
+    accuracy.
     """
     kw, model_axis = _axis_kwargs(axes, cell, allow_sp_tp=True)
     _reject_unsupported_mesh_levers(model_axis, precision, remat, dropout,
@@ -1002,3 +1003,42 @@ def make_mesh_grad_step(loss_fn, optimizer):
         return params, opt_state, loss, metrics
 
     return step
+
+
+# ---------------------------------------------------------------------------
+# pdrnn-lint --deep trace registry (lint/trace_registry.py)
+
+
+def declare_trace_entries(register):
+    """Register the MoE mesh step (dp x ep: batch over both axes, experts
+    over ep, router f32 by contract even under bf16 compute)."""
+
+    def build():
+        from pytorch_distributed_rnn_tpu.lint.trace_registry import (
+            abstract_init,
+            lint_mesh,
+            prng_spec,
+            sds,
+        )
+        from pytorch_distributed_rnn_tpu.models import MoEClassifier
+
+        mesh = lint_mesh({"dp": 2, "ep": 2})
+        model = MoEClassifier(input_dim=9, hidden_dim=8, layer_dim=1,
+                              output_dim=6, num_experts=4,
+                              expert_hidden=16)
+        params = abstract_init(model.init, prng_spec())
+        optimizer = optax.adam(1e-3)
+        opt_state = abstract_init(optimizer.init, params)
+        step = make_mesh_grad_step(
+            make_moe_mesh_loss_fn(model, mesh), optimizer
+        )
+        batch = (sds((8, 12, 9), jnp.float32), sds((8,), jnp.int32))
+        jitted = jax.jit(step, donate_argnums=(0, 1))
+        return jitted, (params, opt_state, batch)
+
+    register(
+        name="moe.mesh_train_step", family="moe",
+        path="pytorch_distributed_rnn_tpu/parallel/strategy.py",
+        build=build, mesh_axes={"dp": 2, "ep": 2}, data_axis="dp",
+        donate=(0, 1),
+    )

@@ -287,9 +287,8 @@ def run_worker(args, rank: int, worker_id: int | None = None,
         args, training_set, args.seed
     )
     from pytorch_distributed_rnn_tpu.obs import MetricsRecorder
-    from pytorch_distributed_rnn_tpu.training import families
+    from pytorch_distributed_rnn_tpu.training import loop_kwargs
 
-    trainer_class = families.wrap_trainer(args, ParameterServerWorkerTrainer)
     # per-worker telemetry sidecar (rank-suffixed path): ps_exchange
     # latency/retry events plus the base trainer's step/epoch stream.
     # A respawn REWRITES the rank's sidecar (its meta carries the
@@ -313,30 +312,21 @@ def run_worker(args, rank: int, worker_id: int | None = None,
                                   role="worker", faults=faults)
     train_history = None
     try:
-        trainer = trainer_class(
+        trainer = ParameterServerWorkerTrainer(
             comm,
             model,
             training_set,
-            batch_size=args.batch_size,
-            learning_rate=args.learning_rate,
+            # --grad-accum, --fuse-run and the checkpoint flags among
+            # them, so that the guards refuse what a worker cannot honour
+            **loop_kwargs(args, faults=faults, recorder=recorder),
             worker_rank=rank,
             num_workers=max(1, args.world_size - 1),
-            seed=args.seed,
-            # forwarded so the unsupported-flag guard raises instead of
-            # the flag being silently dropped
-            grad_accum=getattr(args, "grad_accum", 1),
-            fuse_run=getattr(args, "fuse_run", False),
-            checkpoint_format=getattr(args, "checkpoint_format",
-                                      "gathered"),
-            checkpoint_async=getattr(args, "checkpoint_async", False),
             transport_retries=getattr(args, "ps_transport_retries", 3),
             # retry storms must die inside the round they retry into
             transport_deadline_s=getattr(args, "ps_sync_timeout", 300.0),
             worker_id=worker_id if worker_id is not None else rank,
             register=rejoin,
             drain_signal=drain,
-            faults=faults,
-            recorder=recorder,
         )
         try:
             _, train_history, _ = trainer.train(epochs=args.epochs)

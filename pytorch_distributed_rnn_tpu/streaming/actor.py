@@ -55,33 +55,17 @@ log = logging.getLogger(__name__)
 DRAIN_EXIT_CODE = 0
 
 
-def make_rollout_loss(args, model):
+def make_rollout_loss(model):
     """The family's scalar loss over one ``(x, y)`` batch - the
     standalone surface the actor jits ``value_and_grad`` over (the
-    Trainer mixin stack is a training-loop contract; the actor has no
-    optimizer, no epochs, no eval, so it carries only the loss)."""
-    from pytorch_distributed_rnn_tpu.ops.losses import cross_entropy_loss
-
-    if families.family_of(args) == "char":
-
-        def loss_fn(params, batch):
-            tokens, _ = batch
-            logits = model.apply(params, tokens[:, :-1]).astype(
-                jnp.float32
-            )
-            vocab = logits.shape[-1]
-            return cross_entropy_loss(
-                logits.reshape(-1, vocab), tokens[:, 1:].reshape(-1)
-            )
-
-        return loss_fn
+    actor has no optimizer, no epochs, no eval, so it carries only the
+    model's loss, without its metrics)."""
 
     def loss_fn(params, batch):
         x, y = batch
-        # labels arrive (B, 1) off the motion loader; the loss wants (B,)
-        return cross_entropy_loss(
-            model.apply(params, x), jnp.asarray(y).reshape(-1)
-        )
+        # labels arrive (B, 1) off the loader; the loss wants (B,)
+        return model.loss_and_metrics(
+            params, (x, jnp.asarray(y).reshape(-1)))[0]
 
     return loss_fn
 
@@ -128,7 +112,7 @@ class StreamingActor:
         self._epoch = 0
         self._batches = iter(())
         self._grad_fn = jax.jit(
-            jax.value_and_grad(make_rollout_loss(args, model))
+            jax.value_and_grad(make_rollout_loss(model))
         )
         params = model.init(
             jax.random.PRNGKey(args.seed if args.seed is not None else 0)

@@ -303,8 +303,6 @@ def declare_trace_entries(register):
     from pytorch_distributed_rnn_tpu.lint.trace_registry import sds
 
     def build_actor_grad():
-        import argparse
-
         import jax
         import jax.numpy as jnp
 
@@ -317,9 +315,7 @@ def declare_trace_entries(register):
             lambda a: sds(a.shape, a.dtype),
             model.init(jax.random.PRNGKey(0)),
         )
-        loss_fn = make_rollout_loss(
-            argparse.Namespace(model="rnn"), model
-        )
+        loss_fn = make_rollout_loss(model)
         batch = (sds((4, 12, 9), jnp.float32), sds((4,), jnp.int32))
         return jax.value_and_grad(loss_fn), (params, batch)
 

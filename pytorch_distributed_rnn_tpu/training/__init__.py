@@ -25,7 +25,9 @@ __all__ = [
     "HorovodTrainer",
     "MeshTrainer",
     "add_sub_commands",
+    "loop_kwargs",
     "train",
+    "trainer_kwargs",
 ]
 
 
@@ -110,13 +112,10 @@ def train(args, trainer_class):
     logging.basicConfig(level=args.log)
     logging.getLogger().setLevel(args.log)
 
-    # ONE family-generic path for all four CLI families (rnn, char,
-    # attention, moe): families.load_datasets rejects --seq-length
-    # off-char; build_model carries every family's loud flag rejects (the
-    # ONE construction path, shared with distributed-native and the
-    # parameter server); wrap_trainer mixes in the char-LM / moe loss
-    # surface where the strategy does not own it (the mesh factory's
-    # OWNS_*_LOSS markers pass through).
+    # ONE path for every family (training/families.py:FAMILIES), shared
+    # with distributed-native and the parameter server: the data of the
+    # family's kind, the model with the family's loud flag rejects, and
+    # the one strategy-by-family gate.  The loss is the model's.
     from pytorch_distributed_rnn_tpu.training import families
 
     training_set, validation_set, test_set = _log_and_trim_datasets(
@@ -138,6 +137,39 @@ def _log_and_trim_datasets(args, training_set, validation_set, test_set):
     logging.info(f"Validation set of size {len(validation_set)}")
     logging.info(f"Test set of size {len(test_set)}")
     return training_set, validation_set, test_set
+
+
+def loop_kwargs(args, *, faults, recorder) -> dict:
+    """The constructor keywords every trainer takes from the CLI flags,
+    the parameter server's workers included (they keep no checkpoints
+    and no optimizer, so they stop here)."""
+    return dict(
+        batch_size=args.batch_size,
+        learning_rate=args.learning_rate,
+        seed=args.seed,
+        grad_accum=getattr(args, "grad_accum", 1),
+        fuse_run=getattr(args, "fuse_run", False),
+        checkpoint_format=getattr(args, "checkpoint_format", "gathered"),
+        checkpoint_async=getattr(args, "checkpoint_async", False),
+        faults=faults,
+        recorder=recorder,
+    )
+
+
+def trainer_kwargs(args, *, faults, recorder, profile_steps) -> dict:
+    """:func:`loop_kwargs` and what a trainer that owns its optimizer and
+    its checkpoints takes beside them: the ONE mapping from flags to
+    constructor keywords of the in-process strategies and
+    ``distributed-native``."""
+    return dict(
+        loop_kwargs(args, faults=faults, recorder=recorder),
+        checkpoint_dir=args.checkpoint_directory,
+        checkpoint_every=getattr(args, "checkpoint_every", 0),
+        max_bad_steps=getattr(args, "max_bad_steps", 0),
+        keep_checkpoints=getattr(args, "keep_checkpoints", 0),
+        profile_steps=profile_steps,
+        sharded_update=getattr(args, "sharded_update", True),
+    )
 
 
 def _run_trainer(args, trainer_class, model, datasets):
@@ -186,21 +218,8 @@ def _run_trainer(args, trainer_class, model, datasets):
         training_set=training_set,
         validation_set=validation_set,
         test_set=test_set,
-        batch_size=args.batch_size,
-        learning_rate=args.learning_rate,
-        checkpoint_dir=args.checkpoint_directory,
-        seed=args.seed,
-        checkpoint_every=getattr(args, "checkpoint_every", 0),
-        grad_accum=getattr(args, "grad_accum", 1),
-        fuse_run=getattr(args, "fuse_run", False),
-        checkpoint_format=getattr(args, "checkpoint_format", "gathered"),
-        checkpoint_async=getattr(args, "checkpoint_async", False),
-        faults=faults,
-        max_bad_steps=getattr(args, "max_bad_steps", 0),
-        keep_checkpoints=getattr(args, "keep_checkpoints", 0),
-        recorder=recorder,
-        profile_steps=profile_steps,
-        sharded_update=getattr(args, "sharded_update", True),
+        **trainer_kwargs(args, faults=faults, recorder=recorder,
+                         profile_steps=profile_steps),
     )
 
     resume = getattr(args, "resume", None)

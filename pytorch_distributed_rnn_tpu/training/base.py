@@ -36,7 +36,6 @@ from pytorch_distributed_rnn_tpu.obs.recorder import NULL_RECORDER
 from pytorch_distributed_rnn_tpu.obs.spans import span
 from pytorch_distributed_rnn_tpu.data.prefetch import prefetch
 from pytorch_distributed_rnn_tpu.data.sampler import DistributedSampler
-from pytorch_distributed_rnn_tpu.ops.losses import cross_entropy_loss
 from pytorch_distributed_rnn_tpu.resilience.guard import NonFiniteGuard
 from pytorch_distributed_rnn_tpu.training.checkpoint import (
     load_checkpoint,
@@ -57,8 +56,8 @@ def _fence(value):
 
 def _correct_count(value) -> int:
     """Host-side display form of the ``correct`` metric: classification
-    counts are exact integers; the LM's fractional per-sequence accuracy
-    sums (``training/lm.py``) ROUND for display instead of flooring (int()
+    counts are exact integers; the LMs' fractional per-sequence accuracy
+    sums (``ops/losses.py``) ROUND for display instead of flooring (int()
     would bias every printed accuracy downward)."""
     return int(round(float(value)))
 
@@ -66,8 +65,11 @@ def _correct_count(value) -> int:
 class Trainer:
     """Single-replica ("local") trainer; distribution strategies subclass.
 
-    ``model`` is a functional model object with ``init(key)`` / ``apply``
-    (e.g. ``MotionModel``); ``training_set`` etc. are array datasets.
+    ``model`` is a functional model object as ``models/__init__.py``
+    describes it: ``init(key)``, ``loss_and_metrics(params, batch,
+    dropout_key, weights)`` (the family's loss - the loop and every
+    strategy know no family), ``resolved_impl()``, and ``dropout`` where
+    it has one.  ``training_set`` etc. are array datasets.
 
     Data path (``DEVICE_DATA = True``): the training arrays are placed in
     device memory ONCE and every batch is gathered on device from a small
@@ -291,23 +293,24 @@ class Trainer:
         torch DDP, where every rank has its own RNG stream)."""
         return key
 
-    def _apply_model(self, params, x, key=None):
-        """Model forward; threads the dropout key in train mode only."""
-        if key is None or self._dropout <= 0.0:
-            return self.model.apply(params, x)
-        return self.model.apply(params, x, dropout_key=self._fold_rank(key))
-
-    def _loss_and_metrics(self, params, batch, key=None):
-        x, y = batch
-        logits = self._apply_model(params, x, key)
-        loss = cross_entropy_loss(logits, y)
-        correct = jnp.sum(jnp.argmax(logits, axis=1) == y)
-        return loss, {"correct": correct}
+    def _loss_and_metrics(self, params, batch, key=None, weights=None):
+        """The one loss hook: the model's ``loss_and_metrics``, with the
+        dropout key threaded in train mode only (evaluation passes none)
+        and folded per rank.  ``weights``: the whole-run program's 0/1
+        mask over its zero-padded batches; all-ones weights give the
+        unweighted loss."""
+        if key is not None and self._dropout > 0.0:
+            key = self._fold_rank(key)
+        else:
+            key = None
+        return self.model.loss_and_metrics(
+            params, batch, dropout_key=key, weights=weights)
 
     def _make_grad_step(self, loss_and_metrics):
         """The shared grad+update body: ``step(params, opt_state, batch,
         *extra) -> (params, opt_state, loss, metrics)``; ``*extra`` is
-        forwarded to the loss fn (the weighted-run path's mask).
+        forwarded to the loss fn (a dropout key; the whole-run program's
+        mask before it).
 
         With ``grad_accum > 1`` (plain, unweighted loss only) the batch is
         reshaped into equal microbatches and scanned: grads and batch-mean
@@ -462,7 +465,7 @@ class Trainer:
 
     def _make_run_fn(self):
         """The un-jitted whole-run program (see _build_run_fn)."""
-        grad_step = self._make_grad_step(self._weighted_loss_and_metrics)
+        grad_step = self._make_grad_step(self._masked_loss())
         with_key = self._dropout > 0.0
 
         def train_run(params, opt_state, features, labels, idx_mat, w_mat,
@@ -483,6 +486,15 @@ class Trainer:
 
         return train_run
 
+    def _masked_loss(self):
+        """The loss hook as the whole-run programs call it: the mask
+        comes before the optional dropout key."""
+
+        def loss_and_metrics(params, batch, w, key=None):
+            return self._loss_and_metrics(params, batch, key, weights=w)
+
+        return loss_and_metrics
+
     def _build_run_fn(self):
         """The whole multi-epoch training run as ONE program: scan over
         every batch of every epoch (weight-masked so the final partial
@@ -492,9 +504,6 @@ class Trainer:
 
     # -- dropout keys --------------------------------------------------------
 
-    # (benchmarks/tests/data's recorded trace names this def by its line,
-    # 498: test_trace_reduce.py fails if it moves, until the `benchmark`
-    # PR of ROADMAP S0 matches frames by name.  PERF.md section 7.)
     def _epoch_dropout_keys(self, epoch: int, num_batches: int):
         """Per-step dropout keys for one epoch, derived deterministically
         from (seed, epoch, batch index) so the batched scan path and the
@@ -642,26 +651,15 @@ class Trainer:
 
     def _resolved_impl(self) -> dict | None:
         """What the model's ``impl`` setting resolves to on this backend
-        (``auto`` gives way to the portable path off-TPU and above
-        hidden 512 - ``ops/rnn.py:resolve_rnn_impl``), and whether its
-        Pallas kernels compile or run interpreted.  None for models
-        without the switch; strategies whose programs pick their own
-        inner step (the mesh layouts) override to None."""
-        model = self.model
-        requested = getattr(model, "impl", None)
-        if requested is None:
+        (the model says: ``auto`` gives way to the portable path off-TPU
+        and above hidden 512 - ``ops/rnn.py:resolve_rnn_impl``), and
+        whether its Pallas kernels compile or run interpreted.  None for
+        models without the switch; strategies whose programs pick their
+        own inner step (the mesh layouts) override to None."""
+        resolved = self.model.resolved_impl()
+        if resolved is None:
             return None
-        if hasattr(model, "cell"):
-            from pytorch_distributed_rnn_tpu.ops.rnn import resolve_rnn_impl
-
-            resolved = resolve_rnn_impl(
-                requested, model.cell, hidden=model.hidden_dim)
-        else:
-            from pytorch_distributed_rnn_tpu.ops.pallas_attention import (
-                resolve_attention_impl,
-            )
-
-            resolved = resolve_attention_impl(requested)
+        requested = self.model.impl
         interpret = None
         if resolved in ("fused", "flash"):
             from pytorch_distributed_rnn_tpu.ops.pallas_rnn import _interpret
@@ -905,19 +903,6 @@ class Trainer:
         self._last_device_peaks = device_peaks
         return memory, duration
 
-    def _weighted_loss_and_metrics(self, params, batch, w, key=None):
-        """Masked variant used by the fused whole-run program: ``w`` is a
-        0/1 weight per example.  With all-ones weights this equals
-        ``_loss_and_metrics`` exactly; with a zero-padded tail it equals
-        the reference's smaller final batch's mean (``base.py:46-51``).
-        Override together with ``_loss_and_metrics``."""
-        x, y = batch
-        logits = self._apply_model(params, x, key)
-        nll = cross_entropy_loss(logits, y, reduction="none")
-        loss = jnp.sum(nll * w) / jnp.sum(w)
-        correct = jnp.sum((jnp.argmax(logits, axis=1) == y) * (w > 0))
-        return loss, {"correct": correct}
-
     def _train_run_fused(self, epochs: int):
         """Run ``epochs`` epochs as one device program; returns the
         per-epoch train-loss history (reference normalization: sum of
@@ -1097,7 +1082,7 @@ class Trainer:
     def _fetch_correct(self, metrics, name: str, **attrs) -> float:
         """``metrics["correct"]`` as :meth:`_fetch` brings it.  Whatever
         else the step counted (an expert layer's routing counters,
-        ``training/lm.py:ModelLossMixin``) was computed by the same
+        ``models/mla_moe_lm.py``) was computed by the same
         program, so it is on the host after the same wait: it is noted
         on the span, and no fetch is added."""
         with span(name, self.recorder, **attrs) as fetch:

@@ -259,7 +259,7 @@ class SpmdTrainer(Trainer):
 
     def _build_run_fn(self):
         return make_spmd_run_fn(
-            self._weighted_loss_and_metrics,
+            self._masked_loss(),
             self.optimizer,
             self.mesh,
             axis=self.axis,

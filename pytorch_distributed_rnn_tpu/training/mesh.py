@@ -14,7 +14,8 @@ replicated-scalar loss (grad OUTSIDE the shard_map, the
 requested axis: time-sharded wavefront relay (sp), Megatron gate/head
 sharding (tp), or a GPipe stage schedule (pp).  Batch rows shard over
 ``dp`` exactly like the DDP strategies; evaluation uses the plain
-single-device forward (identical numerics).
+single-device forward through the model's own loss (identical numerics;
+for ``--model moe`` the dense-exact path with its aux loss).
 """
 
 from __future__ import annotations
@@ -64,10 +65,17 @@ class MeshTrainer(SpmdTrainer):
         # the attention family composes the FULL dp x sp x tp mesh (ring
         # attention over sp, Megatron sharding over tp); RNN cells (motion
         # classifier and char-LM alike) take dp plus at most one model
-        # axis; the MoE family takes dp x ep (experts sharded over ep)
-        self.is_attention = hasattr(model, "num_heads")
-        self.is_char = hasattr(model, "vocab_size")
-        self.is_moe = hasattr(model, "num_experts")
+        # axis; the MoE family takes dp x ep (experts sharded over ep).
+        # The programs are per family (parallel/strategy.py), so this
+        # strategy asks the class which family it is
+        if model.family not in ("rnn", "char", "attention", "moe"):
+            raise ValueError(
+                f"the mesh strategy has no program for --model "
+                f"{model.family}"
+            )
+        self.is_attention = model.family == "attention"
+        self.is_char = model.family == "char"
+        self.is_moe = model.family == "moe"
         # `!= 1`, not `> 1`: a -1 ("all remaining devices") size must hit
         # these rejects too, not silently resolve into ghost replication
         if not self.is_moe and axes.get("ep", 1) != 1:
@@ -398,25 +406,8 @@ def mesh_trainer_factory(args):
     """Bind the CLI's mesh flags into a Trainer-compatible constructor."""
     spec = parse_mesh_spec(args.mesh)
 
-    cls = MeshTrainer
-    if getattr(args, "model", "rnn") == "char":
-        # the mesh TRAIN steps come from make_char_mesh_loss_fn; the LM
-        # mixin supplies the matching EVAL loss surface (the base class's
-        # _loss_and_metrics is classification-shaped)
-        from pytorch_distributed_rnn_tpu.training.lm import wrap_lm_trainer
-
-        cls = wrap_lm_trainer(MeshTrainer)
-    elif getattr(args, "model", "rnn") == "moe":
-        # train steps come from make_moe_mesh_loss_fn (expert-parallel);
-        # the MoE mixin supplies the dense-exact EVAL surface + aux loss
-        from pytorch_distributed_rnn_tpu.training.moe import (
-            wrap_moe_trainer,
-        )
-
-        cls = wrap_moe_trainer(MeshTrainer)
-
     def build(**kwargs):
-        return cls(
+        return MeshTrainer(
             mesh_axes=spec,
             schedule=args.sp_schedule,
             num_microbatches=args.num_microbatches,
@@ -425,8 +416,4 @@ def mesh_trainer_factory(args):
             **kwargs,
         )
 
-    # tells families.wrap_trainer the LM loss is already wired in (wrapping the
-    # factory's PRODUCT is not possible from outside - it is not a class)
-    build.OWNS_LM_LOSS = True
-    build.OWNS_MOE_LOSS = True
     return build

@@ -661,13 +661,13 @@ def declare_trace_entries(register):
     )
 
 
-def run_rank(comm, args, model, datasets, trainer_class=None):
+def run_rank(comm, args, model, datasets):
     """Train this rank's replica; returns the trainer (rank 0 writes
-    ``history.json``, every rank logs its perf line).  ``trainer_class``
-    lets a family mix its loss surface over :class:`NativeDDPTrainer`."""
+    ``history.json``, every rank logs its perf line)."""
     training_set, validation_set, test_set = datasets
     from pytorch_distributed_rnn_tpu.obs import MetricsRecorder
     from pytorch_distributed_rnn_tpu.resilience import FaultSchedule
+    from pytorch_distributed_rnn_tpu.training import trainer_kwargs
 
     # rank-bound chaos schedule (one entry point per strategy, all via
     # FaultSchedule.resolve so no strategy can silently drop --faults).
@@ -696,31 +696,17 @@ def run_rank(comm, args, model, datasets, trainer_class=None):
         install_stack_dump_handler(recorder.path)
         plane = LivePlane.resolve(args, recorder, rank=comm.rank,
                                   role="trainer", faults=faults)
-    trainer = (trainer_class or NativeDDPTrainer)(
+    trainer = NativeDDPTrainer(
         comm=comm,
         model=model,
         training_set=training_set,
         validation_set=validation_set,
         test_set=test_set,
-        batch_size=args.batch_size,
-        learning_rate=args.learning_rate,
-        checkpoint_dir=args.checkpoint_directory,
-        # previously dropped here: --faults epoch kills + --resume auto
-        # on the ring need periodic epoch checkpoints to restart from
-        checkpoint_every=getattr(args, "checkpoint_every", 0),
-        seed=args.seed,
-        # forwarded so the unsupported-flag guard raises instead of the
-        # flag being silently dropped
-        grad_accum=getattr(args, "grad_accum", 1),
-        fuse_run=getattr(args, "fuse_run", False),
-        checkpoint_format=getattr(args, "checkpoint_format", "gathered"),
-        checkpoint_async=getattr(args, "checkpoint_async", False),
-        faults=faults,
-        max_bad_steps=getattr(args, "max_bad_steps", 0),
-        keep_checkpoints=getattr(args, "keep_checkpoints", 0),
-        recorder=recorder,
-        profile_steps=profile_steps,
-        sharded_update=getattr(args, "sharded_update", True),
+        # the whole mapping, so that a flag this strategy cannot honour
+        # (--grad-accum, --fuse-run, the sharded checkpoint format)
+        # reaches the guard that refuses it and is not silently dropped
+        **trainer_kwargs(args, faults=faults, recorder=recorder,
+                         profile_steps=profile_steps),
         bucketed_comm=getattr(args, "bucketed_comm", True),
         bucket_mb=getattr(args, "bucket_mb", DEFAULT_BUCKET_MB),
     )
@@ -834,6 +820,4 @@ def execute(args):
         datasets = (datasets[0], None, None)
     model = families.build_model(args, datasets[0])
     with init_from_env() as comm:
-        return run_rank(comm, args, model, datasets,
-                        trainer_class=families.wrap_trainer(
-                            args, NativeDDPTrainer))
+        return run_rank(comm, args, model, datasets)

@@ -13,7 +13,6 @@ from pytorch_distributed_rnn_tpu.data.synthetic import generate_har_arrays
 from pytorch_distributed_rnn_tpu.models import CharRNN, MotionModel
 from pytorch_distributed_rnn_tpu.parallel import make_mesh
 from pytorch_distributed_rnn_tpu.training import Trainer
-from pytorch_distributed_rnn_tpu.training.lm import wrap_lm_trainer
 from pytorch_distributed_rnn_tpu.training.zero import ZeroTrainer
 
 SEED = 123456789
@@ -88,12 +87,12 @@ class TestFsdpStrategy:
         train = TextDataset(rng.randint(0, 256, size=(96, 17)))
         model = CharRNN(vocab_size=256, embed_dim=64, hidden_dim=128,
                         layer_dim=1, impl="scan")
-        local = wrap_lm_trainer(Trainer)(
+        local = Trainer(
             model, train, batch_size=32, learning_rate=1e-3, seed=SEED,
         )
         _, local_hist, _ = local.train(epochs=2)
 
-        fsdp = wrap_lm_trainer(ZeroTrainer)(
+        fsdp = ZeroTrainer(
             model=model, training_set=train, batch_size=32,
             learning_rate=1e-3, seed=SEED, mesh=make_mesh({"dp": 4}),
         )

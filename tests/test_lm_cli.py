@@ -1,5 +1,6 @@
 """--model char: the byte-level LM as a first-class CLI citizen
-(TextDataset windows, LM loss mixin over every shared-loop strategy)."""
+(TextDataset windows, the model's next-token loss under every shared-loop
+strategy)."""
 
 import json
 
@@ -12,7 +13,6 @@ from pytorch_distributed_rnn_tpu.data.text import TextDataset
 from pytorch_distributed_rnn_tpu.models import CharRNN
 from pytorch_distributed_rnn_tpu.parallel import make_mesh
 from pytorch_distributed_rnn_tpu.training import DDPTrainer, Trainer
-from pytorch_distributed_rnn_tpu.training.lm import wrap_lm_trainer
 
 SEED = 123456789
 
@@ -71,7 +71,7 @@ class TestTextDataset:
             TextDataset.load(tmp_path, seq_length=128)
 
 
-class TestLMLossMixin:
+class TestCharLoss:
     def _dataset(self, n=96, t=16):
         rng = np.random.RandomState(0)
         return TextDataset(rng.randint(0, 256, size=(n, t + 1)))
@@ -80,14 +80,14 @@ class TestLMLossMixin:
         train = self._dataset()
         model = CharRNN(vocab_size=256, embed_dim=16, hidden_dim=16,
                         layer_dim=1, impl="scan")
-        trainer = wrap_lm_trainer(Trainer)(
+        trainer = Trainer(
             model, train, batch_size=32, learning_rate=1e-3, seed=SEED
         )
         batch = (jnp.asarray(train.features[:32]),
                  jnp.asarray(train.labels[:32]))
         loss_p, m_p = trainer._loss_and_metrics(trainer.params, batch)
-        loss_w, m_w = trainer._weighted_loss_and_metrics(
-            trainer.params, batch, jnp.ones(32)
+        loss_w, m_w = trainer._loss_and_metrics(
+            trainer.params, batch, weights=jnp.ones(32)
         )
         np.testing.assert_allclose(float(loss_p), float(loss_w), rtol=1e-6)
         np.testing.assert_allclose(
@@ -100,12 +100,12 @@ class TestLMLossMixin:
         train = self._dataset()
         model = CharRNN(vocab_size=256, embed_dim=16, hidden_dim=16,
                         layer_dim=1, impl="scan")
-        local = wrap_lm_trainer(Trainer)(
+        local = Trainer(
             model, train, batch_size=32, learning_rate=1e-3, seed=SEED
         )
         _, local_hist, _ = local.train(epochs=2)
 
-        ddp = wrap_lm_trainer(DDPTrainer)(
+        ddp = DDPTrainer(
             model, train, batch_size=32, learning_rate=1e-3, seed=SEED,
             mesh=make_mesh({"dp": 4}),
         )
@@ -206,7 +206,7 @@ class TestCharMesh:
         )
         model = CharRNN(vocab_size=256, embed_dim=32, hidden_dim=32,
                         layer_dim=2, impl="scan")
-        local = wrap_lm_trainer(Trainer)(
+        local = Trainer(
             model, train, batch_size=64, learning_rate=0.0025, seed=1
         )
         _, local_hist, _ = local.train(epochs=2)
@@ -333,7 +333,7 @@ class TestCharCombos:
                         layer_dim=1, impl="scan")
         hist = {}
         for accum in (1, 4):
-            trainer = wrap_lm_trainer(Trainer)(
+            trainer = Trainer(
                 model, train, batch_size=32, learning_rate=1e-3, seed=SEED,
                 grad_accum=accum,
             )

@@ -163,16 +163,13 @@ class TestMoETraining:
         history when capacity is ample (same global batches)."""
         from pytorch_distributed_rnn_tpu.training import DDPTrainer
         from pytorch_distributed_rnn_tpu.training.mesh import MeshTrainer
-        from pytorch_distributed_rnn_tpu.training.moe import (
-            wrap_moe_trainer,
-        )
 
         model = _model(num_experts=4, capacity_factor=4.0)
         hist = {}
         for name, build in (
-            ("mesh", lambda **kw: wrap_moe_trainer(MeshTrainer)(
+            ("mesh", lambda **kw: MeshTrainer(
                 mesh_axes={"dp": 2, "ep": 2}, **kw)),
-            ("ddp", lambda **kw: wrap_moe_trainer(DDPTrainer)(
+            ("ddp", lambda **kw: DDPTrainer(
                 mesh=make_mesh({"dp": 4}), **kw)),
         ):
             trainer = build(
