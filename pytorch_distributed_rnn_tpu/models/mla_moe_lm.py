@@ -456,7 +456,7 @@ class MlaMoeLM:
     def loss_and_metrics(self, params, batch, dropout_key=None, weights=None):
         """:meth:`loss_and_stats` over a ``(tokens, dummy labels)`` batch.
         What ``stats`` holds beside ``correct`` rides the step's metrics
-        to the fetch the loop already makes (``Trainer._fetch_correct``).
+        to the fetch the loop already makes (``Trainer._fetch_epoch``).
         The loss is more than cross entropy of one logit array and has no
         per-sequence weighted form, so the family refuses ``--fuse-run``
         and this refuses ``weights``; it has no dropout."""

@@ -25,6 +25,7 @@ from __future__ import annotations
 import logging
 import time
 from pathlib import Path
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +61,19 @@ def _correct_count(value) -> int:
     sums (``ops/losses.py``) ROUND for display instead of flooring (int()
     would bias every printed accuracy downward)."""
     return int(round(float(value)))
+
+
+class _EpochInputs(NamedTuple):
+    """What one epoch's launches take from the host
+    (``Trainer._prepare_epoch``)."""
+
+    epoch: int
+    batches: list  # the epoch's index batches, in order, on the host
+    idx_mat: Any  # scan path: the equal-size batches' matrix, on the device
+    remainder: Any  # scan path: the smaller final batch on the device, or None
+    # dropout keys, None with dropout off.  Scan path: (matrix rows,
+    # final batch's key) on the device; step path: one host matrix
+    keys: Any
 
 
 class Trainer:
@@ -245,8 +259,16 @@ class Trainer:
         self._idx_step_fn = None
         self._epoch_fn = None
         self._run_fn = None
+        self._key_fn = None
         self._device_data = None
         self._eval_data_cache = {}
+        # the epoch loop's two hand-overs (see _train_epoch): the next
+        # epoch's inputs, made while the device was busy with this one,
+        # and the validation pass launched behind the training programs
+        # as (dataset, the params it reads, its unfetched values)
+        self._prepared = None
+        self._launched_eval = None
+        self._epochs = 0  # where the running train() call ends
         self._resume_best_loss = None
         self._epoch = 0
         # auto-resume: epochs [0, _start_epoch) are already banked in the
@@ -504,16 +526,48 @@ class Trainer:
 
     # -- dropout keys --------------------------------------------------------
 
-    def _epoch_dropout_keys(self, epoch: int, num_batches: int):
-        """Per-step dropout keys for one epoch, derived deterministically
-        from (seed, epoch, batch index) so the batched scan path and the
-        per-batch logging path produce identical numerics."""
-        ekey = jax.random.fold_in(self._dropout_key, epoch)
-        return np.asarray(
-            jax.vmap(lambda i: jax.random.fold_in(ekey, i))(
-                jnp.arange(num_batches)
-            )
+    def _build_key_fn(self):
+        """One epoch's per-step dropout keys as ONE program, launched and
+        not fetched: ``dropout_keys(base, epoch, full, remainder)`` gives
+        the rows ``fold_in(fold_in(base, epoch), i)`` of the ``full``
+        equal-size steps and, as a second output, the key of the smaller
+        final step where the epoch has one - what the epoch program and
+        the remainder's step take, still on the device (replicated over
+        the mesh where there is one).  ``epoch`` is traced, so every
+        epoch of a run is one compilation; the two counts are static.
+        Both folds run at the rows' shape (the epoch's fold once a row,
+        the same numbers): the unrolled cipher is then lowered once and
+        called twice, which halves what the program adds to a warm
+        start."""
+
+        def dropout_keys(base, epoch, full, remainder):
+            steps = full + remainder
+            fold = jax.vmap(jax.random.fold_in)
+            ekeys = fold(jnp.broadcast_to(base, (steps, 2)),
+                         jnp.broadcast_to(epoch, (steps,)))
+            keys = fold(ekeys, jnp.arange(steps, dtype=jnp.uint32))
+            return keys[:full], (keys[full] if remainder else None)
+
+        return jax.jit(  # noqa: PD103 - two words of key: nothing to donate
+            dropout_keys, static_argnums=(2, 3),
+            out_shardings=self._data_sharding(),
         )
+
+    def _device_dropout_keys(self, epoch: int, full: int, remainder: bool):
+        """``(key_mat, remainder_key)`` of :meth:`_build_key_fn` for one
+        epoch, derived deterministically from (seed, epoch, batch index)
+        so the batched scan path and the per-batch paths produce
+        identical numerics."""
+        if self._key_fn is None:
+            self._key_fn = self._build_key_fn()
+        return self._key_fn(
+            self._dropout_key, np.uint32(epoch), full, remainder)
+
+    def _epoch_dropout_keys(self, epoch: int, num_batches: int):
+        """All of an epoch's keys as one host matrix, for the loops that
+        hand a key to every step themselves."""
+        return np.asarray(
+            self._device_dropout_keys(epoch, num_batches, False)[0])
 
     # -- data ----------------------------------------------------------------
 
@@ -550,6 +604,12 @@ class Trainer:
                         jax.device_put(labels, sharding),
                     )
         return self._device_data
+
+    def _put_indices(self, idx):
+        """An epoch's index matrix (or its final batch's vector) on the
+        device, placed as the programs take it in, so that the launch
+        copies nothing.  SPMD subclasses shard the batch dimension."""
+        return jax.device_put(idx)
 
     def _epoch_index_batches(self):
         """The epoch's batches as a list of index arrays, in order.  All
@@ -805,6 +865,8 @@ class Trainer:
             self._train_step_fn = self._build_train_step()
         if self._eval_step_fn is None:
             self._eval_step_fn = self._build_eval_step()
+        self._epochs = epochs
+        self._prepared = self._launched_eval = None
 
         # the whole run fuses into one device program when nothing needs
         # the host between batches or epochs: no per-epoch validation /
@@ -1072,27 +1134,70 @@ class Trainer:
             return "step"
         return "scan"
 
-    def _fetch(self, value, name: str, **attrs) -> float:
-        """A device scalar as a host float, under a ``*.fetch`` span:
-        ``float`` waits for the program that computes the value, so the
-        span holds what is left of that program's run time."""
-        with span(name, self.recorder, **attrs):
-            return float(value)
+    def _prepare_epoch(self, epoch: int, ahead: bool) -> _EpochInputs:
+        """Everything epoch ``epoch``'s launches need from the host.  On
+        the scan path the index matrix goes to the device here, placed as
+        the program takes it in, and the dropout keys are one program's
+        unfetched output, so the epoch itself starts with launches only.
+        ``ahead``: made during the epoch before, behind a busy device
+        (the permutation is a function of (seed, epoch) alone, so drawing
+        it early changes no batch)."""
+        scan = self._epoch_path() == "scan"
+        self.sampler.set_epoch(epoch)
+        with span("epoch.indices", self.recorder, ahead=int(ahead)):
+            batches = self._epoch_index_batches()
+            # scan path: all equal-size batches as ONE index matrix, the
+            # final partial batch (if any) as one extra step
+            full, remainder = batches, None
+            if scan and len(batches) > 1 and (
+                    len(batches[-1]) != len(batches[0])):
+                full, remainder = batches[:-1], batches[-1]
+            idx_mat = None
+            if scan:
+                idx_mat = self._put_indices(np.stack(full))
+                if remainder is not None:
+                    remainder = self._put_indices(remainder)
+        keys = None
+        if self._dropout > 0.0:
+            with span("epoch.dropout_keys", self.recorder):
+                if scan:
+                    keys = self._device_dropout_keys(
+                        epoch, len(full), remainder is not None)
+                else:
+                    keys = self._epoch_dropout_keys(epoch, len(batches))
+        return _EpochInputs(epoch, batches, idx_mat, remainder, keys)
 
-    def _fetch_correct(self, metrics, name: str, **attrs) -> float:
-        """``metrics["correct"]`` as :meth:`_fetch` brings it.  Whatever
-        else the step counted (an expert layer's routing counters,
-        ``models/mla_moe_lm.py``) was computed by the same
-        program, so it is on the host after the same wait: it is noted
-        on the span, and no fetch is added."""
-        with span(name, self.recorder, **attrs) as fetch:
-            correct = float(metrics["correct"])
-            for key, value in metrics.items():
-                if key != "correct":
-                    fetch.attrs[key] = float(value)
-        return correct
+    def _fetch_epoch(self, launched):
+        """The epoch's ONE wait for its training programs: the loss and
+        the metrics dict of every ``(program, loss, metrics)`` launched,
+        brought to the host together, as ``(loss total, correct
+        total)``.  Whatever else a step counted (an expert layer's routing
+        counters, ``models/mla_moe_lm.py``) came in the same dict, so it
+        is noted on the span, and no fetch is added."""
+        programs = "+".join(program for program, _, _ in launched)
+        total_loss = 0.0
+        total_correct = 0.0
+        with span("epoch.fetch", self.recorder, program=programs) as fetch:
+            values = jax.device_get(
+                [(loss, metrics) for _, loss, metrics in launched])
+            for loss, metrics in values:
+                total_loss += float(loss)
+                total_correct += float(metrics["correct"])
+                for key, value in metrics.items():
+                    if key != "correct":
+                        fetch.attrs[key] = (
+                            fetch.attrs.get(key, 0.0) + float(value))
+        return total_loss, total_correct
 
     def _train_epoch(self, formatter):
+        """One epoch on the device paths, in the order that keeps the
+        device fed: everything the epoch runs is enqueued before anything
+        is read back.  Launch the training programs (the remainder's step
+        and the validation pass depend on the scanned epoch through
+        ``params`` on the DEVICE, which the runtime orders by itself),
+        make the next epoch's inputs while the device is busy, then wait
+        ONCE for the training values; :meth:`_evaluate` waits for the
+        validation's."""
         epoch_path = self._epoch_path()
         if epoch_path == "host":
             return self._train_epoch_host(formatter)
@@ -1108,32 +1213,21 @@ class Trainer:
             # the scanned epoch dispatches its steps as one program: a
             # --profile-steps capture opens before the first epoch that
             # holds one of its steps and closes after the last one's
-            # fetches, which are the fence
+            # fetch, which is the fence
             self._profile.on_step_start(
                 step_base, count=self._steps_per_epoch())
         features, labels = self._device_train_data()
-        with span("epoch.indices", self.recorder):
-            batches = self._epoch_index_batches()
-            if epoch_path == "scan":
-                # all equal-size batches as ONE index matrix, the final
-                # partial batch (if any) as one extra step
-                full, remainder = batches, None
-                if len(batches) > 1 and len(batches[-1]) != len(batches[0]):
-                    full, remainder = batches[:-1], batches[-1]
-                idx_mat = np.stack(full) if full else None
-        keys = None
-        if self._dropout > 0.0:
-            with span("epoch.dropout_keys", self.recorder):
-                keys = self._epoch_dropout_keys(self._epoch, len(batches))
-        # host-side accumulators: each program's loss/metrics outputs are
-        # replicated over the (possibly multi-process) mesh, so fetching
-        # them immediately is legal on every rank - while accumulating
-        # into a process-LOCAL device zero can land the sum on a device
-        # other controllers cannot address.  Cost: two fetches per program
-        # on the fast path (whole-epoch program + optional remainder
-        # step), values the host needs for history/logging anyway.
-        total_loss = 0.0
-        total_correct = 0.0
+        inputs, self._prepared = self._prepared, None
+        if inputs is None or inputs.epoch != self._epoch:
+            inputs = self._prepare_epoch(self._epoch, ahead=False)
+        batches, keys = inputs.batches, inputs.keys
+        # the programs' loss/metrics outputs are replicated over the
+        # (possibly multi-process) mesh, so fetching them is legal on
+        # every rank, however late - while accumulating into a
+        # process-LOCAL device zero can land the sum on a device other
+        # controllers cannot address.  So the values stay device scalars
+        # until the epoch's one wait and are added up on the host, which
+        # needs them for history/logging anyway.
         t_epoch = time.perf_counter()
 
         if epoch_path == "step":
@@ -1184,12 +1278,16 @@ class Trainer:
                     corrects.append(metrics["correct"])
                 if recording:
                     raw.append((step, t0, dispatch_s, fenced_s))
+            self._launch_validation()
             with span("epoch.fetch", self.recorder, program="train_step"):
+                # one wait for every step's values (host floats already
+                # under DEBUG pass through)
+                losses, corrects = jax.device_get((losses, corrects))
                 total_loss = sum(float(l) for l in losses)
                 total_correct = sum(float(c) for c in corrects)
             if recording:
                 # step events are emitted AFTER the loop: the deferred
-                # float() fetches here are the same epoch-end fetch the
+                # fetch here is the same epoch-end fetch the
                 # uninstrumented path already pays, not per-step syncs.
                 # tm is overridden to the step's dispatch START so the
                 # timeline exporter can synthesize the dispatch/device
@@ -1206,35 +1304,34 @@ class Trainer:
             # fast path: one scanned program and at most one extra step
             # (never with a recorder on: these spans reach the log and
             # the profiler only)
-            if full:
-                extra = (keys[: len(full)],) if keys is not None else ()
-                with span("epoch.launch", program="train_epoch"):
-                    (
-                        self.params,
-                        self.opt_state,
-                        loss_sum,
-                        metrics_sum,
-                    ) = self._epoch_fn(
-                        self.params, self.opt_state, features, labels,
-                        idx_mat, *extra,
-                    )
-                total_loss += self._fetch(
-                    loss_sum, "epoch.fetch", program="train_epoch")
-                total_correct += self._fetch_correct(
-                    metrics_sum, "epoch.fetch", program="train_epoch")
-            if remainder is not None:
-                extra = (keys[-1],) if keys is not None else ()
+            epoch_extra, step_extra = (
+                [(key,) for key in keys] if keys is not None else [(), ()])
+            launched = []
+            with span("epoch.launch", program="train_epoch"):
+                (
+                    self.params,
+                    self.opt_state,
+                    loss_sum,
+                    metrics_sum,
+                ) = self._epoch_fn(
+                    self.params, self.opt_state, features, labels,
+                    inputs.idx_mat, *epoch_extra,
+                )
+            launched.append(("train_epoch", loss_sum, metrics_sum))
+            if inputs.remainder is not None:
                 with span("epoch.launch", program="train_step"):
                     (
                         self.params, self.opt_state, loss, metrics,
                     ) = self._idx_step_fn(
                         self.params, self.opt_state, features, labels,
-                        remainder, *extra,
+                        inputs.remainder, *step_extra,
                     )
-                total_loss += self._fetch(
-                    loss, "epoch.fetch", program="train_step")
-                total_correct += self._fetch_correct(
-                    metrics, "epoch.fetch", program="train_step")
+                launched.append(("train_step", loss, metrics))
+            self._launch_validation()
+            if self._epoch + 1 < self._epochs:
+                self._prepared = self._prepare_epoch(
+                    self._epoch + 1, ahead=True)
+            total_loss, total_correct = self._fetch_epoch(launched)
             self._steps_done = step_base + len(batches)
             if self._profile is not None:
                 self._profile.on_step_end(self._steps_done - 1)
@@ -1440,34 +1537,61 @@ class Trainer:
         )
         return train_loss, train_acc
 
-    def _evaluate(self, dataset, formatter, epoch=None):
-        """Full-dataset evaluation in one batch (reference loads val/test
-        with batch_size=len(dataset), base.py:53-54)."""
+    def _launch_eval(self, dataset):
+        """Enqueue the one-batch evaluation of ``self.params`` on
+        ``dataset`` (reference loads val/test with
+        batch_size=len(dataset), base.py:53-54) and return its values
+        unfetched.  Evaluation donates nothing, so ``self.params`` stays
+        valid for a checkpoint."""
         # cache holds (dataset, batch): the strong reference keeps id()
         # stable (a collected dataset's id could be reused by a new one)
         key = id(dataset)
-        split = "validation" if dataset is self.validation_set else "test"
         cached = self._eval_data_cache.get(key)
         if cached is None or cached[0] is not dataset:
             features, labels = dataset[np.arange(len(dataset))]
-            with span("input.upload", self.recorder, split=split):
+            with span("input.upload", self.recorder,
+                      split=self._split_name(dataset)):
                 batch = self._prepare_batch(features, labels)
             cached = (dataset, batch)
             self._eval_data_cache[key] = cached
-        batch = cached[1]
-        # the fetches below fence the eval program, so the span's
-        # extent is the honest wall time of the whole evaluation
+        with span("eval.launch", self.recorder, cat="eval",
+                  program="eval_step"):
+            loss, metrics = self._eval_step_fn(self.params, cached[1])
+        return loss, metrics["correct"]
+
+    def _launch_validation(self):
+        """Where the epoch has a validation pass, enqueue it behind the
+        training programs just launched; :meth:`_evaluate` finds it."""
+        if self.validation_set is not None:
+            self._launched_eval = (
+                self.validation_set, self.params,
+                self._launch_eval(self.validation_set))
+
+    def _split_name(self, dataset) -> str:
+        return "validation" if dataset is self.validation_set else "test"
+
+    def _evaluate(self, dataset, formatter, epoch=None):
+        """Full-dataset evaluation in one batch.  Where the epoch loop
+        has already launched this evaluation of these very ``params``
+        (:meth:`_launch_validation`), only its values are waited for."""
+        launched, self._launched_eval = self._launched_eval, None
+        values = None
+        if (launched is not None and launched[0] is dataset
+                and launched[1] is self.params):
+            values = launched[2]
+        # the fetch below fences the eval program, so the span's extent
+        # is the wall time the evaluation ADDS: all of it where it is
+        # launched inside, what is left of it where it ran behind the
+        # training programs
         with span("eval", self.recorder, cat="eval", epoch=epoch,
-                  split=split):
-            with span("eval.launch", self.recorder, cat="eval",
+                  split=self._split_name(dataset)):
+            if values is None:
+                values = self._launch_eval(dataset)
+            with span("eval.fetch", self.recorder, cat="eval",
                       program="eval_step"):
-                loss, metrics = self._eval_step_fn(self.params, batch)
-            # one batch -> already the mean
-            eval_loss = self._fetch(
-                loss, "eval.fetch", cat="eval", program="eval_step")
-            total_correct = self._fetch(
-                metrics["correct"], "eval.fetch", cat="eval",
-                program="eval_step")
+                # one batch -> already the mean
+                eval_loss, total_correct = map(
+                    float, jax.device_get(values))
         num_examples = len(dataset)
         accuracy = total_correct / num_examples
         self.recorder.record(

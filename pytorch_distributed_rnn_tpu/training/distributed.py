@@ -273,6 +273,17 @@ class SpmdTrainer(Trainer):
         # along dp so each device gathers its rank's micro-batch locally
         return NamedSharding(self.mesh, P())
 
+    def _put_indices(self, idx):
+        """Sharded along ``dp`` in the batch (last) dimension, as the
+        shard_mapped programs take their indices in (``P(None, axis)``
+        for the matrix, ``parallel/dp.py``): every device gets its rank's
+        columns and the launch copies nothing.  Shard by shard, so a
+        multi-process world needs no agreement collective."""
+        sharding = NamedSharding(
+            self.mesh, P(*[None] * (idx.ndim - 1), self.axis))
+        return jax.make_array_from_callback(
+            idx.shape, sharding, idx.__getitem__)
+
     def _epoch_index_batches(self):
         """Rank-major global-batch index vectors: device r's shard of each
         batch is exactly what MPI rank r would have loaded (per-rank batch

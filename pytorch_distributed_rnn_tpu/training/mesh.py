@@ -333,6 +333,11 @@ class MeshTrainer(SpmdTrainer):
         rep = NamedSharding(self.mesh, PartitionSpec())
         return jax.jit(fn, donate_argnums=(0, 1), out_shardings=rep)
 
+    def _put_indices(self, idx):
+        # the programs below leave their inputs' layout to the compiler:
+        # the host's array goes in at the launch
+        return idx
+
     def _build_train_step(self):
         return self._jit_replicated(make_mesh_grad_step(
             self._mesh_loss_fn(weighted=False), self.optimizer

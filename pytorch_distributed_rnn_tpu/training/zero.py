@@ -105,6 +105,11 @@ class ZeroTrainer(SpmdTrainer):
     _build_epoch_fn = Trainer._build_epoch_fn
     _build_run_fn = Trainer._build_run_fn
 
+    def _put_indices(self, idx):
+        # the base class's programs leave their inputs' layout to the
+        # compiler: the host's array goes in at the launch
+        return idx
+
     def _build_eval_step(self):
         # eval shards the full-dataset batch too (parallel evaluation)
         def eval_step(params, batch, *extra):

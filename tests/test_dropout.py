@@ -201,6 +201,91 @@ class TestTrainerDropout:
             leaves_sum(p_fused), rel=1e-6
         )
 
+    @pytest.mark.parametrize("epoch", [0, 1, 7])
+    @pytest.mark.parametrize("num_batches", [1, 5, 6])
+    def test_key_program_gives_the_folded_keys_bit_for_bit(
+            self, train_set, epoch, num_batches):
+        """The one jitted key program (ISSUE 31) against the eager
+        definition: row i is fold_in(fold_in(key, epoch), i), whether the
+        rows come as one matrix or as the full steps' rows and the
+        remainder's key."""
+        drop = MotionModel(input_dim=9, hidden_dim=8, layer_dim=2,
+                           output_dim=6, impl="scan", dropout=0.3)
+        trainer = Trainer(drop, train_set, batch_size=24,
+                          learning_rate=2.5e-3, seed=SEED)
+        ekey = jax.random.fold_in(trainer._dropout_key, epoch)
+        want = np.stack([np.asarray(jax.random.fold_in(ekey, i))
+                         for i in range(num_batches)])
+        rows, last = trainer._device_dropout_keys(epoch, num_batches, False)
+        assert last is None
+        assert isinstance(rows, jax.Array)  # launched, not fetched
+        np.testing.assert_array_equal(np.asarray(rows), want)
+        np.testing.assert_array_equal(
+            trainer._epoch_dropout_keys(epoch, num_batches), want)
+        if num_batches > 1:
+            rows, last = trainer._device_dropout_keys(
+                epoch, num_batches - 1, True)
+            np.testing.assert_array_equal(np.asarray(rows), want[:-1])
+            np.testing.assert_array_equal(np.asarray(last), want[-1])
+
+    @pytest.mark.parametrize("cls", [Trainer, DDPTrainer])
+    def test_five_epochs_compile_the_key_program_once(self, train_set, cls):
+        """The epoch is a traced argument: a key program specialised on
+        it would compile again in every epoch (inside the benchmark's
+        window).  Under the SPMD trainer its outputs are replicated over
+        the mesh, as the epoch program takes its key matrix in."""
+        import logging
+
+        drop = MotionModel(input_dim=9, hidden_dim=8, layer_dim=2,
+                           output_dim=6, impl="scan", dropout=0.3)
+        trainer = cls(drop, train_set, batch_size=40, learning_rate=2.5e-3,
+                      seed=SEED)
+        logging.getLogger().setLevel(logging.INFO)  # the scan path
+        try:
+            trainer.train(epochs=5)
+        finally:
+            logging.getLogger().setLevel(logging.WARNING)
+        assert trainer._key_fn._cache_size() == 1
+        rows, last = trainer._device_dropout_keys(4, 2, True)
+        assert trainer._key_fn._cache_size() == 1
+        assert rows.shape == (2, 2) and last.shape == (2,)
+        if cls is DDPTrainer:
+            assert rows.sharding.is_fully_replicated
+            assert rows.sharding.device_set == set(trainer.mesh.devices.flat)
+
+    def test_scan_and_step_paths_agree_with_a_remainder_batch(
+            self, train_set):
+        """Dropout 0.1 and a smaller final batch (96 = 2 x 40 + 16): the
+        scan path, whose keys and indices never leave the device, gives
+        the losses and the params of the step path, which hands every
+        step its key from the host."""
+        import logging
+
+        drop = MotionModel(input_dim=9, hidden_dim=8, layer_dim=2,
+                           output_dim=6, impl="scan", dropout=0.1)
+
+        def run(level):
+            trainer = Trainer(drop, train_set, batch_size=40,
+                              learning_rate=2.5e-3, seed=SEED,
+                              validation_set=train_set)
+            assert trainer._has_partial_batch()
+            logging.getLogger().setLevel(level)
+            try:
+                path = trainer._epoch_path()
+                params, history, validation = trainer.train(epochs=3)
+            finally:
+                logging.getLogger().setLevel(logging.WARNING)
+            return path, params, history, validation
+
+        scan_path, p_scan, h_scan, v_scan = run(logging.INFO)
+        step_path, p_step, h_step, v_step = run(logging.DEBUG)
+        assert (scan_path, step_path) == ("scan", "step")
+        assert h_scan == pytest.approx(h_step, rel=1e-6)
+        assert v_scan == pytest.approx(v_step, rel=1e-6)
+        for a, b in zip(jax.tree.leaves(p_scan), jax.tree.leaves(p_step)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-7)
+
     def test_eval_deterministic_under_dropout(self, train_set):
         drop = MotionModel(input_dim=9, hidden_dim=8, layer_dim=2,
                            output_dim=6, impl="scan", dropout=0.5)

@@ -3,6 +3,7 @@ the names on programs, scopes and kernels, and the profile capture that no
 longer changes the program (ISSUE 23)."""
 
 import logging
+import re
 import threading
 import time
 
@@ -239,15 +240,24 @@ class TestTrainerSpans:
             inside = [e for e in program_spans(log) if under(e, epoch)]
             names = [e[NAME] for e in inside]
             # the steady epoch, read off _train_epoch and _evaluate: the
-            # scanned program and the remainder step (a launch and two
-            # fetches each), one validation pass
-            steady = sorted(
-                ["epoch.indices", "epoch.dropout_keys"]
-                + ["epoch.launch", "epoch.fetch", "epoch.fetch"] * 2
-                + ["eval", "eval.launch", "eval.fetch", "eval.fetch"])
-            uploads = ["input.upload"] * 2 if number == 0 else []
-            assert sorted(names) == sorted(steady + uploads)
+            # scanned program, the remainder step and the validation pass
+            # launched back to back, ONE wait for the training programs'
+            # values, then the evaluation's
+            steady = (["epoch.launch"] * 2
+                      + ["eval.launch", "epoch.fetch", "eval", "eval.fetch"])
+            # an epoch's inputs are made during the epoch before; the
+            # call's first epoch makes its own as well, and uploads
+            own = (["input.upload"] * 2
+                   + ["epoch.indices", "epoch.dropout_keys"]
+                   if number == 0 else [])
+            ahead = (["epoch.indices", "epoch.dropout_keys"]
+                     if number + 1 < len(epochs) else [])
+            assert sorted(names) == sorted(steady + own + ahead)
             assert len(inside) + 1 <= 16  # with the epoch span itself
+        assert [e[ATTRS] for e in by_name(log, "epoch.indices")] == [
+            {"ahead": 0}, {"ahead": 1}]
+        assert [e[ATTRS]["program"] for e in by_name(log, "epoch.fetch")] == [
+            "train_epoch+train_step"] * 2
         launches = by_name(log, "epoch.launch")
         assert [e[ATTRS]["program"] for e in launches] == [
             "train_epoch", "train_step"] * 2
@@ -291,12 +301,14 @@ class TestTrainerSpans:
         log = spans.log()
         assert {e[ATTRS]["path"] for e in by_name(log, "epoch")} == {"step"}
         recorded = [e[NAME] for e in program_spans(log)]
-        # per step nothing, per epoch one fetch where the scan path has
-        # two launches and four fetches
+        # per step nothing, per epoch the one fetch the scan path makes
+        # after its two launches
         assert "epoch.launch" not in recorded
         assert recorded.count("epoch.fetch") == 2
-        unchanged = ("train", "epoch", "epoch.indices", "epoch.dropout_keys",
-                     "eval", "eval.launch", "eval.fetch", "input.upload")
+        assert plain.count("epoch.launch") == 4
+        unchanged = ("train", "epoch", "epoch.fetch", "epoch.indices",
+                     "epoch.dropout_keys", "eval", "eval.launch",
+                     "eval.fetch", "input.upload")
         for name in unchanged:
             assert recorded.count(name) == plain.count(name), name
 
@@ -308,16 +320,113 @@ class TestTrainerSpans:
         assert names.count("eval") == 3 and names.count("eval.launch") == 3
         assert names.count("epoch.indices") == 2
         evals = {e["span"]: e for e in emitted if e["name"] == "eval"}
-        for child in emitted:
-            if child["name"] in ("eval.launch", "eval.fetch"):
-                outer = evals[child["parent"]]
-                assert outer["tm"] <= child["tm"]
-                assert (child["tm"] + child["dur_s"]
-                        <= outer["tm"] + outer["dur_s"])
+        assert names.count("eval.fetch") == 3
+        # every wait for an evaluation's values lies inside its `eval`;
+        # so does the test evaluation's launch, while a validation pass
+        # is launched behind the epoch's steps, before its `eval` opens
+        inside = [e for e in emitted if e["name"] == "eval.fetch"
+                  or (e["name"] == "eval.launch" and e["parent"] in evals)]
+        assert [e["name"] for e in inside].count("eval.launch") == 1
+        for child in inside:
+            outer = evals[child["parent"]]
+            assert outer["tm"] <= child["tm"]
+            assert (child["tm"] + child["dur_s"]
+                    <= outer["tm"] + outer["dur_s"])
+        behind = [e for e in emitted if e["name"] == "eval.launch"
+                  and e["parent"] not in evals]
+        validations = sorted(
+            (e for e in evals.values() if e["epoch"] is not None),
+            key=lambda e: e["tm"])
+        assert len(behind) == len(validations) == 2
+        for launch, outer in zip(behind, validations):
+            assert launch["tm"] + launch["dur_s"] <= outer["tm"]
         write_chrome_trace(path, tmp_path / "m.trace.json")  # validates
         ledger = ledger_events(events)
         assert ledger["phase_s"]["eval"] == pytest.approx(
             sum(e["dur_s"] for e in evals.values()))
+
+    @pytest.mark.parametrize("cls", [Trainer, DDPTrainer])
+    @pytest.mark.parametrize("batch_size", [48, 40],
+                             ids=["remainder", "no_remainder"])
+    def test_an_epoch_is_enqueued_whole_before_anything_is_read(
+            self, datasets, info_logging, cls, batch_size):
+        """ISSUE 31: every launch of an epoch comes before its first
+        fetch, the epochs after a call's first find their inputs made
+        ahead (the same batches as drawn in their own epoch), and an
+        evaluation's `eval` span holds its wait."""
+        train, validation = datasets
+        model = MotionModel(input_dim=9, hidden_dim=8, layer_dim=2,
+                            output_dim=6, dropout=0.1)
+        kwargs = {"mesh": make_mesh()} if cls is DDPTrainer else {}
+        trainer = cls(model, train, batch_size=batch_size,
+                      learning_rate=2.5e-3, seed=SEED,
+                      validation_set=validation, test_set=validation,
+                      **kwargs)
+        taken = []
+
+        def spying(program):
+            def launch(params, opt_state, features, labels, idx, *extra):
+                taken.append(np.asarray(idx))
+                return program(params, opt_state, features, labels, idx,
+                               *extra)
+            return launch
+
+        trainer._epoch_fn = spying(trainer._build_epoch_fn())
+        trainer._idx_step_fn = spying(trainer._build_idx_train_step())
+        trainer.train(epochs=4)
+        log = program_spans(spans.log())
+        train_span, = by_name(log, "train")
+        epochs = by_name(log, "epoch")
+        assert len(epochs) == 4
+
+        # (i) launches first, then the waits
+        remainder = batch_size == 48
+        for epoch in epochs:
+            inside = [e for e in log if e[PARENT] == epoch[ID]]
+            launches = [e for e in inside if e[NAME].endswith(".launch")]
+            fetches = [e for e in log if e[NAME].endswith(".fetch")
+                       and epoch[START] <= e[START] <= epoch[END]]
+            assert [e[NAME] for e in launches] == (
+                ["epoch.launch"] * (2 if remainder else 1) + ["eval.launch"])
+            assert [e[NAME] for e in fetches] == ["epoch.fetch", "eval.fetch"]
+            assert max(e[END] for e in launches) <= min(
+                e[START] for e in fetches)
+
+        # (ii) made ahead everywhere but in the call's first epoch, inside
+        # the epoch before and between its last launch and its wait
+        indices = by_name(log, "epoch.indices")
+        assert [e[ATTRS]["ahead"] for e in indices] == [0, 1, 1, 1]
+        assert [e[PARENT] for e in indices] == [
+            epochs[0][ID], epochs[0][ID], epochs[1][ID], epochs[2][ID]]
+        for made, epoch in zip(indices[1:], epochs):
+            launch = [e for e in log if e[PARENT] == epoch[ID]
+                      and e[NAME] == "eval.launch"][0]
+            fetch = [e for e in log if e[PARENT] == epoch[ID]
+                     and e[NAME] == "epoch.fetch"][0]
+            assert launch[END] <= made[START] and made[END] <= fetch[START]
+        # ... and the batches are the ones each epoch draws by itself
+        expected = []
+        for epoch in range(4):
+            trainer.sampler.set_epoch(epoch)
+            batches = trainer._epoch_index_batches()
+            full = batches[:-1] if remainder else batches
+            expected.append(np.stack(full))
+            expected += batches[len(full):]
+        assert len(taken) == len(expected)
+        for got, want in zip(taken, expected):
+            np.testing.assert_array_equal(got, want)
+
+        # (iii) an `eval` span per evaluation, its wait inside it
+        evals = by_name(log, "eval")
+        assert [(e[ATTRS]["split"], e[ATTRS]["epoch"]) for e in evals] == [
+            ("validation", 0), ("validation", 1), ("validation", 2),
+            ("validation", 3), ("validation", None)]  # the test set IS it
+        assert [e[PARENT] for e in evals] == [
+            e[ID] for e in epochs] + [train_span[ID]]
+        waits = by_name(log, "eval.fetch")
+        assert [e[PARENT] for e in waits] == [e[ID] for e in evals]
+        for wait, outer in zip(waits, evals):
+            assert outer[START] <= wait[START] and wait[END] <= outer[END]
 
     def test_compiles_are_noted_under_the_launch_that_caused_them(
             self, datasets, info_logging):
@@ -354,6 +463,40 @@ class TestTrainerSpans:
             trainer._prepare_batch(*trainer.validation_set[np.arange(8)]),
         ).as_text()
         assert "module @jit_eval_step" in text
+
+
+    @pytest.mark.parametrize("cls", [Trainer, DDPTrainer])
+    def test_programs_lower_alike_from_device_and_host_inputs(
+            self, datasets, cls, info_logging):
+        """The epoch's indices and keys now reach the programs as device
+        arrays, placed as the programs take them in; the programs
+        themselves are what the host's arrays gave (ISSUE 31: the lowered
+        text is the parent's)."""
+        kwargs = {"mesh": make_mesh()} if cls is DDPTrainer else {}
+        trainer = small_trainer(datasets, cls=cls, **kwargs)
+        features, labels = trainer._device_train_data()
+        inputs = trainer._prepare_epoch(0, ahead=False)
+        key_mat, key = inputs.keys
+        for program, idx, keys in (
+                (trainer._build_epoch_fn(), inputs.idx_mat, key_mat),
+                (trainer._build_idx_train_step(), inputs.remainder, key)):
+            assert isinstance(idx, jax.Array) and isinstance(keys, jax.Array)
+            state = (trainer.params, trainer.opt_state, features, labels)
+            from_device = program.lower(*state, idx, keys).as_text()
+            from_host = program.lower(
+                *state, np.asarray(idx), np.asarray(keys)).as_text()
+            if cls is DDPTrainer:
+                # a placed argument says so in the entry point's
+                # signature, and there alone: what shard_map's in_specs
+                # asked of the host's array is now the array's own
+                placed = re.compile(
+                    r' \{sdy\.sharding = #sdy\.sharding<@mesh, '
+                    r'\[(\{[^{}]*\}(, )?)*\]>\}')
+                assert len(placed.findall(from_device)) == len(
+                    placed.findall(from_host)) + 2
+                from_device, from_host = (
+                    placed.sub("", text) for text in (from_device, from_host))
+            assert from_device == from_host
 
 
 # -- names on the device ----------------------------------------------------------
@@ -516,11 +659,17 @@ class TestProfilerTrace:
         assert capture.close()["captured"] is True
         events = host_events(tmp_path)
         names = {name for line in events.values() for name, _, _ in line}
-        # the first epoch and nothing of the second
+        # the first epoch and nothing the second runs: its two programs
+        # and its one wait, and between them the second epoch's inputs,
+        # which are made behind the first's programs
         assert {"epoch.launch", "epoch.fetch"} <= names
         driving, = [line for line in events.values()
                     if any(name == "epoch.launch" for name, _, _ in line)]
-        assert sum(name == "epoch.indices" for name, _, _ in driving) == 1
+        counts = {name: sum(n == name for n, _, _ in driving)
+                  for name in ("epoch.launch", "epoch.fetch",
+                               "epoch.indices")}
+        assert counts == {"epoch.launch": 2, "epoch.fetch": 1,
+                          "epoch.indices": 2}
 
     def test_a_capture_starts_at_the_first_epoch_holding_its_steps(
             self, tmp_path, monkeypatch):
