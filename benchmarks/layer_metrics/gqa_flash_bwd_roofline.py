@@ -1,0 +1,21 @@
+"""The two grouped-query-attention flash backward kernels' (dq; dk and dv)
+share of their roofline: the least time the chip could take for every call
+of either in the traced calls (each recomputes the scores, which is counted:
+the kernel has to; ``benchmarks/flops_hybrid_ssm_moe.py``) over their device
+time."""
+
+from benchmarks import flops_hybrid_ssm_moe
+
+NAME = "gqa_flash_bwd_roofline"
+LAYER = "model_ops"
+UNIT = "%"
+MOVES = "train_seq_per_s"
+SOURCE = "device_trace"
+WORKLOADS = ["nemotron3_nano_train_t8192_1chip"]
+
+
+def read(context):
+    least, seconds = flops_hybrid_ssm_moe.kernels_least_seconds(
+        context["trace"], context["cell"]["config"]["model"],
+        ["gqa_flash_dq", "gqa_flash_dkv"], context["peaks"])
+    return 100.0 * least / seconds if seconds else None

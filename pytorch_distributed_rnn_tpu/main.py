@@ -61,13 +61,14 @@ def build_parser() -> argparse.ArgumentParser:
     families.add_model_flags(parser)
     parser.add_argument(
         "--seq-length", default=None, type=int, metavar="T",
-        help="token-window length for --model char / mla_moe (default "
-        "128); motion/attention take their length from the HAR data",
+        help="token-window length for --model char / mla_moe / "
+        "hybrid_ssm_moe (default 128); motion/attention take their length "
+        "from the HAR data",
     )
     parser.add_argument(
         "--vocab-size", default=None, type=int, metavar="V",
-        help="vocabulary of the token families (--model char / mla_moe): "
-        "rows of the embedding and the output head.  Default: what the "
+        help="vocabulary of the token families (--model char / mla_moe / "
+        "hybrid_ssm_moe): rows of the embedding and the output head.  Default: what the "
         "data declares (256 for a byte corpus).  A slice of a larger "
         "vocabulary is a smaller vocabulary: the data's ids must lie "
         "under it",
@@ -84,8 +85,9 @@ def build_parser() -> argparse.ArgumentParser:
     )
     parser.add_argument(
         "--moe-top-k", default=1, type=int,
-        help="experts per token.  --model mla_moe: any number up to "
-        "--num-experts (the published 8: pass it).  --model moe, 1 or 2 "
+        help="experts per token.  --model mla_moe / hybrid_ssm_moe: any "
+        "number up to --num-experts (the published 8 and 6: pass it).  "
+        "--model moe, 1 or 2 "
         "only: 1 = Switch routing "
         "(raw max-gate combine weight), 2 = GShard (renormalized top-2 "
         "gates; capacity slots assigned choice-major so second choices "
@@ -116,6 +118,29 @@ def build_parser() -> argparse.ArgumentParser:
         "routing) - capacity becomes per-group, keeping the one-hot "
         "dispatch einsums linear in token count.  Default: one global "
         "group per shard (exact-union drop semantics)",
+    )
+    # the flags that both decoder LMs (--model mla_moe / hybrid_ssm_moe) read
+    parser.add_argument(
+        "--ffn-dims", default=None, metavar="A,EXPERT",
+        help="widths of the decoder LMs' feed-forward parts: --model "
+        "mla_moe DENSE,EXPERT, the leading dense layer's MLP and one "
+        "expert (intermediate_size, moe_intermediate_size; default "
+        "7168,768); --model hybrid_ssm_moe SHARED,EXPERT, the shared "
+        "expert and one routed expert (moe_shared_expert_intermediate_size, "
+        "moe_intermediate_size; default 3712,1856)",
+    )
+    parser.add_argument(
+        "--experts-held", default=None, metavar="FIRST:COUNT",
+        help="--model mla_moe / hybrid_ssm_moe: the share of each layer's "
+        "--num-experts routed experts this chip holds, as an "
+        "expert-parallel rank does.  The router scores all experts; the "
+        "layer computes its own experts' part for the tokens routed to "
+        "them and drops none.  Default: all of them",
+    )
+    parser.add_argument(
+        "--moe-route-scale", default=2.5, type=float,
+        help="--model mla_moe / hybrid_ssm_moe: routed_scaling_factor on "
+        "the normalised weights of the picked experts",
     )
     parser.add_argument(
         "--resume", default=None, type=Path, metavar="PATH|auto",

@@ -1,7 +1,8 @@
 """The models.  A model family of the CLI is its model class.
 
-The five classes ``training/families.py:FAMILIES`` lists (``MotionModel``,
-``CharRNN``, ``AttentionClassifier``, ``MoEClassifier``, ``MlaMoeLM``)
+The six classes ``training/families.py:FAMILIES`` lists (``MotionModel``,
+``CharRNN``, ``AttentionClassifier``, ``MoEClassifier``, ``MlaMoeLM``,
+``HybridSsmMoeLM``; the two decoder LMs share ``decoder_common.py``)
 carry, by convention and with no base class, all that the program knows
 of a family:
 
@@ -29,6 +30,9 @@ from pytorch_distributed_rnn_tpu.models.char_rnn import (
     char_rnn_50m,
     num_params,
 )
+from pytorch_distributed_rnn_tpu.models.hybrid_ssm_moe_lm import (
+    HybridSsmMoeLM,
+)
 from pytorch_distributed_rnn_tpu.models.mla_moe_lm import MlaMoeLM
 from pytorch_distributed_rnn_tpu.models.moe import MoEClassifier
 from pytorch_distributed_rnn_tpu.models.moe_lm import MoELM
@@ -41,6 +45,7 @@ __all__ = [
     "CharRNN",
     "char_rnn_50m",
     "num_params",
+    "HybridSsmMoeLM",
     "MlaMoeLM",
     "MoEClassifier",
     "MoELM",

@@ -38,28 +38,30 @@ ids from it.
 
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from pytorch_distributed_rnn_tpu.ops.moe import (
-    held_experts_ffn,
-    route_sigmoid_topk,
+from pytorch_distributed_rnn_tpu.models.decoder_common import (
+    check_share,
+    expert_layer,
+    experts_held_flag,
+    head_nll,
+    init_on_device,
+    ints_flag,
+    moe_stats,
+    refuse_flags,
+    rms_norm,
 )
+from pytorch_distributed_rnn_tpu.ops.moe import expert_mlp
 
 # what a device trace calls the attention kernels: mla_flash_fwd / _dq / _dkv
 KERNEL_NAME = "mla_flash"
-
-
-def rms_norm(x, weight, eps: float):
-    """A division by a square root, not ``lax.rsqrt``: the TPU's rsqrt is
-    an approximation (PERF.md, PR 28), and every gradient passes through
-    a norm."""
-    mean_square = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x / jnp.sqrt(mean_square + eps) * weight
+# --ffn-dims DENSE,EXPERT where the flag is not given (intermediate_size,
+# moe_intermediate_size)
+FFN_DIMS = "7168,768"
 
 
 def rotary(x, theta: float):
@@ -81,10 +83,6 @@ def rotary(x, theta: float):
     turned = jnp.stack(
         [even * cos - odd * sin, even * sin + odd * cos], axis=-1)
     return turned.reshape(x.shape)
-
-
-def gated_mlp(p, x):
-    return (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
 
 
 @dataclass(frozen=True)
@@ -139,15 +137,7 @@ class MlaMoeLM:
     remat: bool = False             # recompute each block in the backward
 
     def __post_init__(self):
-        held = self.held
-        if not (0 <= self.experts_first
-                and self.experts_first + held <= self.num_experts
-                and held >= 1):
-            raise ValueError(
-                f"experts {self.experts_first}:{self.experts_first + held} "
-                f"are not a share of {self.num_experts}")
-        if self.num_selected > self.num_experts:
-            raise ValueError("more experts a token than experts")
+        check_share(self)
         if self.rope_dim % 2:
             raise ValueError("rope_dim must be even")
 
@@ -176,24 +166,6 @@ class MlaMoeLM:
             help="--model mla_moe: base of the rotary embedding",
         )
         parser.add_argument(
-            "--ffn-dims", default="7168,768", metavar="DENSE,EXPERT",
-            help="--model mla_moe: width of the leading dense layer's MLP "
-            "and of one expert (intermediate_size, moe_intermediate_size)",
-        )
-        parser.add_argument(
-            "--experts-held", default=None, metavar="FIRST:COUNT",
-            help="--model mla_moe: the share of each layer's --num-experts "
-            "routed experts this chip holds, as an expert-parallel rank "
-            "does.  The router scores all experts; the layer computes its "
-            "own experts' part for the tokens routed to them and drops "
-            "none.  Default: all of them",
-        )
-        parser.add_argument(
-            "--moe-route-scale", default=2.5, type=float,
-            help="--model mla_moe: routed_scaling_factor on the normalised "
-            "weights of the picked experts",
-        )
-        parser.add_argument(
             "--mtp-weight", default=0.3, type=float,
             help="--model mla_moe: weight of the multi-token-prediction "
             "module's loss (one module, predicting the token after next); "
@@ -206,32 +178,12 @@ class MlaMoeLM:
         that is no share of the layer too."""
         from pytorch_distributed_rnn_tpu.data.text import flag_vocab_size
 
-        refused = [
-            flag for flag, bad in (
-                ("--dropout (pass --dropout 0: the family has none; the CLI "
-                 "default 0.1 mirrors the reference surface)",
-                 bool(getattr(args, "dropout", 0.0))),
-                ("--cell gru (no recurrent cell)",
-                 getattr(args, "cell", "lstm") != "lstm"),
-                ("--precision bf16 (its bf16 path has not been brought up)",
-                 getattr(args, "precision", "f32") != "f32"),
-                ("--moe-router expert (tokens pick experts here)",
-                 getattr(args, "moe_router", "token") != "token"),
-                ("--moe-group-size (no capacity slots: no pick is dropped)",
-                 getattr(args, "moe_group_size", None) is not None),
-                ("--fuse-run (its loss has no per-sequence weighted form)",
-                 bool(getattr(args, "fuse_run", False))),
-            ) if bad
-        ]
-        if refused:
-            raise SystemExit(
-                "--model mla_moe does not support: " + "; ".join(refused))
-        q_rank, kv_rank = _ints(args, "--mla-ranks", 2)
-        nope_dim, rope_dim, v_dim = _ints(args, "--mla-head-dims", 3)
-        dense_ffn, expert_ffn = _ints(args, "--ffn-dims", 2)
-        first, held = 0, None
-        if getattr(args, "experts_held", None) is not None:
-            first, held = _ints(args, "--experts-held", 2, sep=":")
+        refuse_flags(cls.family, args)
+        q_rank, kv_rank = ints_flag(args, "--mla-ranks", 2)
+        nope_dim, rope_dim, v_dim = ints_flag(args, "--mla-head-dims", 3)
+        dense_ffn, expert_ffn = ints_flag(
+            args, "--ffn-dims", 2, default=FFN_DIMS)
+        first, held = experts_held_flag(args)
         try:
             return cls(
                 vocab_size=flag_vocab_size(args, training_set),
@@ -311,7 +263,7 @@ class MlaMoeLM:
         """Normal(0, ``init_std``) matrices, norm weights 1, the router's
         bias buffer 0: one program on the device, nothing made on the
         host (680 M parameters took a minute there)."""
-        return _init_on_device(self, key)
+        return init_on_device(self, key)
 
     # -- forward ------------------------------------------------------------
 
@@ -354,31 +306,15 @@ class MlaMoeLM:
             o = o.transpose(0, 2, 1, 3).reshape(b, t, h * self.v_dim)
             return o @ p["w_o"]
 
-    def _expert_ffn(self, p, x):
-        shape = x.shape
-        xt = x.reshape(-1, shape[-1])
-        picked, weights = route_sigmoid_topk(
-            p["router"], p["router_bias"], xt, self.num_selected,
-            self.route_scale)
-        num_picks = xt.shape[0] * self.num_selected
-        uniform = num_picks * self.held / self.num_experts
-        capacity = max(int(self.capacity_factor * uniform), 8 * self.held)
-        routed, counters = held_experts_ffn(
-            p["experts"], xt, picked, weights, first=self.experts_first,
-            capacity=-(-capacity // 128) * 128)
-        with jax.named_scope("shared_expert"):
-            shared = gated_mlp(p["shared"], xt)
-        return (shared + routed).reshape(shape), counters
-
     def _block(self, p, x):
         """One decoder block -> (x, the expert layer's counters or None)."""
         x = x + self._attention(
             p["attn"], rms_norm(x, p["attn_norm"], self.norm_eps))
         y = rms_norm(x, p["ffn_norm"], self.norm_eps)
         if "router" in p["ffn"]:
-            y, counters = self._expert_ffn(p["ffn"], y)
+            y, counters = expert_layer(self, p["ffn"], y)
         else:
-            y, counters = gated_mlp(p["ffn"], y), None
+            y, counters = expert_mlp(p["ffn"], y), None
         return x + y, counters
 
     def _run_block(self, p, x):
@@ -431,27 +367,19 @@ class MlaMoeLM:
         expert of any layer)."""
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
         h, counters = self.hidden(params, inputs)
-        nll, hit = _head_nll(
+        nll, hit = head_nll(
             h, params["final_norm"], params["head"], targets, self.norm_eps)
         loss = jnp.mean(nll)
         if self.mtp_weight:
             h_mtp, c = self._mtp_hidden(params, h, targets)
             counters.append(c)
             # position i < T - 1 predicts t_{i+2} = targets[i + 1]
-            nll_mtp, _ = _head_nll(
+            nll_mtp, _ = head_nll(
                 h_mtp[:, :-1], params["mtp"]["final_norm"], params["head"],
                 targets[:, 1:], self.norm_eps)
             loss = loss + self.mtp_weight * jnp.mean(nll_mtp)
-        stats = {"correct": jnp.sum(jnp.mean(hit, axis=1))}
-        if counters:
-            stats.update(
-                moe_rows_max=functools.reduce(
-                    jnp.maximum, [c["rows_max"] for c in counters]),
-                moe_rows_sum=sum(c["rows_sum"] for c in counters),
-                moe_picks_absent=sum(c["picks_absent"] for c in counters),
-                moe_picks_dropped=sum(c["picks_dropped"] for c in counters),
-            )
-        return loss, stats
+        return loss, {"correct": jnp.sum(jnp.mean(hit, axis=1)),
+                      **moe_stats(counters)}
 
     def loss_and_metrics(self, params, batch, dropout_key=None, weights=None):
         """:meth:`loss_and_stats` over a ``(tokens, dummy labels)`` batch.
@@ -465,54 +393,3 @@ class MlaMoeLM:
                 "--model mla_moe: its loss has no per-sequence weighted form")
         tokens, _ = batch
         return self.loss_and_stats(params, tokens)
-
-
-def _ints(args, flag: str, count: int, sep: str = ","):
-    """A flag that holds ``count`` whole numbers, e.g. ``--mla-ranks
-    1536,512``."""
-    text = getattr(args, flag.lstrip("-").replace("-", "_"))
-    try:
-        values = tuple(int(v) for v in text.split(sep))
-    except ValueError:
-        values = ()
-    if len(values) != count or min(values) < 0:
-        raise SystemExit(
-            f"{flag} wants {count} whole numbers separated by {sep!r}, "
-            f"got {text!r}"
-        )
-    return values
-
-
-@functools.partial(jax.checkpoint, static_argnums=(4,))
-def _head_nll(h, norm, head, targets, eps):
-    """Final norm, output head and per-position cross entropy (B, T),
-    with the hit of the arg max beside it.  Checkpointed: the backward
-    pass recomputes the (B, T, vocab) logits instead of keeping them, so
-    the main model's and the prediction module's never lie in memory
-    together."""
-    with jax.named_scope("head"):
-        logits = (rms_norm(h, norm, eps) @ head).astype(jnp.float32)
-    with jax.named_scope("loss"):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(
-            logp, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
-    hit = (jnp.argmax(logits, axis=-1) == targets).astype(jnp.float32)
-    return nll, hit
-
-
-@functools.partial(jax.jit, static_argnums=0)
-def _init_on_device(model: MlaMoeLM, key):
-    leaves, tree = jax.tree_util.tree_flatten_with_path(
-        model.param_shapes(), is_leaf=lambda s: isinstance(s, tuple))
-    keys = jax.random.split(key, len(leaves))
-
-    def make(path, shape, k):
-        if jax.tree_util.keystr(path).endswith("router_bias']"):
-            return jnp.zeros(shape, jnp.float32)
-        if len(shape) == 1:  # a norm's weight
-            return jnp.ones(shape, jnp.float32)
-        return model.init_std * jax.random.normal(k, shape, jnp.float32)
-
-    return jax.tree.unflatten(
-        tree, [make(path, shape, k)
-               for (path, shape), k in zip(leaves, keys)])

@@ -349,19 +349,32 @@ def route_sigmoid_topk(router, bias, x, k: int, scale: float):
     return picked, weights
 
 
-def _gated_rows(experts, rows, sizes, valid):
-    """``W_down(silu(x W_gate) * x W_up)`` of rows sorted by expert:
-    three grouped products (``jax.lax.ragged_dot``; XLA's own kernel on
-    the TPU, which keeps the ambient matmul precision).  What a product
-    writes in rows past the last group is not defined, so those rows are
-    zeroed (``valid``) wherever they could reach a result or a gradient."""
+def expert_mlp(p, x):
+    """One expert on every row of ``x``, in the form its parameters hold:
+    with a ``w_gate`` the gated SiLU ``W_down(silu(x W_gate) * x W_up)``
+    (DeepSeek-V3's experts), without one ``W_down relu(x W_up)^2``
+    (Nemotron-H's: not gated, two products)."""
+    if "w_gate" in p:
+        return (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
+    return jnp.square(jax.nn.relu(x @ p["w_up"])) @ p["w_down"]
+
+
+def _expert_rows(experts, rows, sizes, valid):
+    """:func:`expert_mlp` of rows sorted by expert: grouped products
+    (``jax.lax.ragged_dot``; XLA's own kernel on the TPU, which keeps the
+    ambient matmul precision), three for the gated form and two for the
+    other.  What a product writes in rows past the last group is not
+    defined, so those rows are zeroed (``valid``) wherever they could
+    reach a result or a gradient."""
     keep = valid[:, None]
     rows = jnp.where(keep, rows, 0)
-    gate = jnp.where(keep, jax.lax.ragged_dot(
-        rows, experts["w_gate"], sizes), 0)
+    gated = "w_gate" in experts
+    if gated:
+        gate = jnp.where(keep, jax.lax.ragged_dot(
+            rows, experts["w_gate"], sizes), 0)
     up = jnp.where(keep, jax.lax.ragged_dot(rows, experts["w_up"], sizes), 0)
-    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, experts["w_down"],
-                             sizes)
+    hidden = jax.nn.silu(gate) * up if gated else jnp.square(jax.nn.relu(up))
+    out = jax.lax.ragged_dot(hidden, experts["w_down"], sizes)
     return jnp.where(keep, out, 0)
 
 
@@ -370,9 +383,10 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
     """The routed part of an expert layer that THIS chip computes:
     ``sum over picked experts held here of w_e * Expert_e(x)``.
 
-    ``experts`` holds ``count`` gated MLPs stacked on axis 0 (``w_gate``,
-    ``w_up`` (count, D, F), ``w_down`` (count, F, D)): experts ``first``
-    to ``first + count - 1`` of the layer.  ``picked`` / ``weights`` are
+    ``experts`` holds ``count`` MLPs stacked on axis 0 (``w_up`` (count,
+    D, F), ``w_down`` (count, F, D), and ``w_gate`` like ``w_up`` where
+    the experts are gated: :func:`expert_mlp`): experts ``first`` to
+    ``first + count - 1`` of the layer.  ``picked`` / ``weights`` are
     the router's picks over ALL experts; a pick that falls on an expert
     held elsewhere adds nothing here (its chip adds it), and no code
     stands in for that chip.
@@ -392,7 +406,7 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
     experts received), ``picks_absent``, ``picks_dropped`` (held picks
     that were not computed: 0 by construction, counted from the group
     sizes the products really ran with)."""
-    count = experts["w_gate"].shape[0]
+    count = experts["w_up"].shape[0]
     n, k = picked.shape
     num_picks = n * k
     with jax.named_scope("experts"):
@@ -421,7 +435,7 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
                 # do the same work whatever the router did, and a step's
                 # time does not follow the seed (PERF.md, PR 28)
                 sizes = sizes.at[-1].add(rows - clipped[-1])
-            out = _gated_rows(experts, x[tokens], sizes, valid)
+            out = _expert_rows(experts, x[tokens], sizes, valid)
             out = out * weight_of[:rows, None].astype(out.dtype)
             y = jnp.zeros_like(x).at[tokens].add(out)
             return y, clipped[-1]

@@ -18,6 +18,7 @@ from __future__ import annotations
 from pytorch_distributed_rnn_tpu.models import (
     AttentionClassifier,
     CharRNN,
+    HybridSsmMoeLM,
     MlaMoeLM,
     MoEClassifier,
     MotionModel,
@@ -26,7 +27,7 @@ from pytorch_distributed_rnn_tpu.models import (
 FAMILIES = {
     cls.family: cls
     for cls in (MotionModel, CharRNN, AttentionClassifier, MoEClassifier,
-                MlaMoeLM)
+                MlaMoeLM, HybridSsmMoeLM)
 }
 
 
@@ -118,9 +119,11 @@ def wrap_trainer(args, trainer_class):
     """The strategy's Trainer class for this family: the class it was
     given (the loss is the model's), unless the strategy has no program
     for the family.  The mesh strategy hands its factory, not a class."""
-    if family_of(args) == "mla_moe" and not isinstance(trainer_class, type):
+    family = family_of(args)
+    if (family in (MlaMoeLM.family, HybridSsmMoeLM.family)
+            and not isinstance(trainer_class, type)):
         raise SystemExit(
-            "--model mla_moe is not wired into the mesh strategy: its "
+            f"--model {family} is not wired into the mesh strategy: its "
             "expert layer computes one chip's share and has no "
             "exchange between chips"
         )

@@ -39,7 +39,8 @@ def _model_and_batch(name):
         "--model", name, "--hidden-units", "16", "--stacked-layer", "1",
         "--dropout", "0", "--num-heads", "2", "--num-experts", "4",
         "--moe-top-k", "2", "--mla-ranks", "8,8", "--mla-head-dims", "8,4,8",
-        "--ffn-dims", "16,8", "--remat", "local",
+        "--ffn-dims", "16,8", "--mamba-dims", "2,4,4,2", "--mamba-chunk",
+        "4", "--gqa-dims", "1,8", "--remat", "local",
     ])
     if FAMILIES[name].data_kind == "tokens":
         rng = np.random.RandomState(0)
@@ -57,7 +58,7 @@ def test_all_ones_weights_give_the_unweighted_loss(name):
     model, batch = _model_and_batch(name)
     params = model.init(jax.random.PRNGKey(0))
     ones = jnp.ones(len(batch[0]))
-    if name == "mla_moe":
+    if name in ("mla_moe", "hybrid_ssm_moe"):
         # as the family refuses --fuse-run
         with pytest.raises(NotImplementedError, match="weighted form"):
             model.loss_and_metrics(params, batch, weights=ones)
@@ -165,8 +166,9 @@ def test_a_family_defined_here_parses_builds_and_trains(
     np.testing.assert_allclose(ddp_loss, local_loss, rtol=1e-5)
 
 
-def test_the_families_are_the_five_classes():
-    assert list(FAMILIES) == ["rnn", "char", "attention", "moe", "mla_moe"]
+def test_the_families_are_the_six_classes():
+    assert list(FAMILIES) == ["rnn", "char", "attention", "moe", "mla_moe",
+                              "hybrid_ssm_moe"]
     assert all(name == cls.family for name, cls in FAMILIES.items())
     assert {cls.data_kind for cls in FAMILIES.values()} == {"har", "tokens"}
 
@@ -174,7 +176,9 @@ def test_the_families_are_the_five_classes():
 # -- (c) the parser the harness builds its args from ------------------------
 
 # (first option string, default, type, choices) of every global flag, as the
-# parser of PR 29 had them
+# parser of PR 29 had them; since PR 32 the three flags both decoder LMs read
+# are main.py's (--ffn-dims with each family's widths as its default) and the
+# hybrid family brings four of its own
 FLAGS = [
     ("--checkpoint-directory", Path("models"), "Path", None),
     ("--dataset-path", Path("data"), "Path", None),
@@ -191,16 +195,22 @@ FLAGS = [
     ("--seed", None, "int", None),
     ("--no-validation", False, None, None),
     ("--cell", "lstm", None, ["lstm", "gru"]),
-    ("--model", "rnn", None, ["rnn", "attention", "char", "moe", "mla_moe"]),
+    ("--model", "rnn", None, ["rnn", "attention", "char", "moe", "mla_moe",
+                              "hybrid_ssm_moe"]),
     ("--seq-length", None, "int", None),
     ("--vocab-size", None, "int", None),
     ("--mla-ranks", "1536,512", None, None),
     ("--mla-head-dims", "128,64,128", None, None),
     ("--rope-theta", 32000000.0, "float", None),
-    ("--ffn-dims", "7168,768", None, None),
+    ("--mtp-weight", 0.3, "float", None),
+    ("--hybrid-pattern",
+     "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME", None, None),
+    ("--mamba-dims", "64,64,128,8", None, None),
+    ("--mamba-chunk", 128, "int", None),
+    ("--gqa-dims", "2,128", None, None),
+    ("--ffn-dims", None, None, None),
     ("--experts-held", None, None, None),
     ("--moe-route-scale", 2.5, "float", None),
-    ("--mtp-weight", 0.3, "float", None),
     ("--num-heads", 4, "int", None),
     ("--num-experts", 4, "int", None),
     ("--moe-top-k", 1, "int", None),

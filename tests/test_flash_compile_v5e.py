@@ -1,6 +1,7 @@
 """The flash kernels compiled for a described TPU v5e (no chip attached) at
-the latent-attention cell's shape, with the tiles and the scoped-VMEM limit
-the picker gives them: what interpret mode cannot show.  The one file under
+the two decoder cells' shapes, with the tiles and the scoped-VMEM limit the
+picker gives them, and the chunked scan's gradient at the hybrid cell's: what
+interpret mode cannot show.  The one file under
 ``tests/`` that loads the TPU's compiler; it does so inside a fixture, so
 every xdist worker collects the same tests."""
 
@@ -68,3 +69,46 @@ def test_picked_tiles_compile_under_the_limit_the_model_sets(
     for kind, block_q, block_k, limit in picks:
         assert (block_q, block_k) == (1024, 1024), kind
         assert limit > pallas_attention._VMEM_DEFAULT, kind
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+def test_grouped_query_kernels_compile_at_the_hybrid_cell_s_shape(
+        chip, precision, monkeypatch):
+    """T 8,192, q / k / v 128 wide, f32, causal, K and V already broadcast
+    over their query heads (two of the 32 here): forward, dq and dk / dv
+    under the names the hybrid decoder's kernel metrics look for."""
+    monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+    on_chip = jax.ShapeDtypeStruct((1, 2, 8192, 128), jnp.float32,
+                                   sharding=chip)
+
+    def loss(q, k, v):
+        return jnp.sum(pallas_attention.flash_attention(
+            q, k, v, causal=True, name="gqa_flash"))
+
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            on_chip, on_chip, on_chip).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for kernel in ("gqa_flash_fwd", "gqa_flash_dq", "gqa_flash_dkv"):
+        assert kernel in text
+
+
+def test_chunked_scan_gradient_compiles_at_the_hybrid_cell_s_shape(chip):
+    """One window of 8,192 through ``ops/ssd.py`` at the published sizes
+    (64 heads of 64, state 128, 8 groups, chunk 128), values and every
+    gradient: XLA's batched products and one loop over the 64 chunk
+    states, no custom call, under 3 GB of temporaries."""
+    from pytorch_distributed_rnn_tpu.ops import ssd
+
+    def on_chip(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+
+    def loss(x, dt, a, b, c, d):
+        return jnp.sum(ssd.ssd_chunked(x, dt, a, b, c, d, chunk=128))
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        on_chip(1, 8192, 64, 64), on_chip(1, 8192, 64), on_chip(64),
+        on_chip(1, 8192, 8, 128), on_chip(1, 8192, 8, 128),
+        on_chip(64)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9

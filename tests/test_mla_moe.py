@@ -358,10 +358,14 @@ def test_the_cli_builds_the_share_it_is_told():
     assert (model.dense_ffn_dim, model.expert_ffn_dim) == (48, 16)
     # the published widths are the defaults
     defaults = build_parser().parse_args(["--model", "mla_moe", "local"])
-    assert (defaults.mla_ranks, defaults.mla_head_dims, defaults.ffn_dims,
+    assert (defaults.mla_ranks, defaults.mla_head_dims,
             defaults.rope_theta, defaults.moe_route_scale,
             defaults.mtp_weight) == (
-        "1536,512", "128,64,128", "7168,768", 32e6, 2.5, 0.3)
+        "1536,512", "128,64,128", 32e6, 2.5, 0.3)
+    # --ffn-dims is main.py's (two families read it): the family's own
+    # widths where it is not given
+    published = families.build_model(_args("--ffn-dims", None), train)
+    assert (published.dense_ffn_dim, published.expert_ffn_dim) == (7168, 768)
     assert families.build_model(
         _args("--experts-held", None), train).held == 32
 
