@@ -132,7 +132,14 @@ def expert_layer(model, p, x):
 
     The grouped products compute ``capacity_factor`` times the rows a
     uniform router sends here while the held picks fit (never a drop:
-    past it the layer computes every pick)."""
+    past it the layer computes every pick).  They follow the model's one
+    ``impl`` switch, as attention does: this repo's grouped kernels where
+    it resolves to ``flash`` (``ops/pallas_grouped.py``),
+    ``jax.lax.ragged_dot`` where it resolves to ``dense``."""
+    from pytorch_distributed_rnn_tpu.ops.pallas_attention import (
+        resolve_attention_impl,
+    )
+
     shape = x.shape
     xt = x.reshape(-1, shape[-1])
     picked, weights = route_sigmoid_topk(
@@ -143,7 +150,8 @@ def expert_layer(model, p, x):
     capacity = max(int(model.capacity_factor * uniform), 8 * model.held)
     routed, counters = held_experts_ffn(
         p["experts"], xt, picked, weights, first=model.experts_first,
-        capacity=-(-capacity // 128) * 128)
+        capacity=-(-capacity // 128) * 128,
+        impl=resolve_attention_impl(model.impl))
     with jax.named_scope("shared_expert"):
         shared = expert_mlp(p["shared"], xt)
     return (shared + routed).reshape(shape), counters

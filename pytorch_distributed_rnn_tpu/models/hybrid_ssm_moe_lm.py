@@ -133,7 +133,7 @@ class HybridSsmMoeLM:
     # as models/mla_moe_lm.py: rows the grouped products compute while the
     # held picks fit, as a multiple of what a uniform router sends here
     capacity_factor: float = 4.0
-    impl: str = "auto"              # attention: "flash" | "dense" | "auto"
+    impl: str = "auto"  # attention and grouped products: flash|dense|auto
     remat: bool = False             # recompute each layer in the backward
 
     def __post_init__(self):

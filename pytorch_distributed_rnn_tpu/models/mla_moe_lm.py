@@ -133,7 +133,7 @@ class MlaMoeLM:
     # 8 experts for every token sends this chip N rows for each of them it
     # holds, and two of them still fit
     capacity_factor: float = 4.0
-    impl: str = "auto"              # attention: "flash" | "dense" | "auto"
+    impl: str = "auto"  # attention and grouped products: flash|dense|auto
     remat: bool = False             # recompute each block in the backward
 
     def __post_init__(self):

@@ -359,27 +359,36 @@ def expert_mlp(p, x):
     return jnp.square(jax.nn.relu(x @ p["w_up"])) @ p["w_down"]
 
 
-def _expert_rows(experts, rows, sizes, valid):
-    """:func:`expert_mlp` of rows sorted by expert: grouped products
-    (``jax.lax.ragged_dot``; XLA's own kernel on the TPU, which keeps the
-    ambient matmul precision), three for the gated form and two for the
-    other.  What a product writes in rows past the last group is not
-    defined, so those rows are zeroed (``valid``) wherever they could
-    reach a result or a gradient."""
+def _expert_rows(experts, rows, sizes, valid, impl):
+    """:func:`expert_mlp` of rows sorted by expert: grouped products, three
+    for the gated form and two for the other, at the ambient matmul
+    precision.  ``impl`` ``flash`` (the TPU) runs them through this repo's
+    kernels (``ops/pallas_grouped.py:grouped_matmul``), which write zeros
+    in the rows past the last group; ``dense`` through
+    ``jax.lax.ragged_dot``, where what those rows hold is not defined, so
+    they are zeroed (``valid``) wherever they could reach a result or a
+    gradient.  The rows themselves are zeroed for both: the spare rows of
+    a padded last group are multiplied like any other."""
     keep = valid[:, None]
     rows = jnp.where(keep, rows, 0)
-    gated = "w_gate" in experts
-    if gated:
-        gate = jnp.where(keep, jax.lax.ragged_dot(
-            rows, experts["w_gate"], sizes), 0)
-    up = jnp.where(keep, jax.lax.ragged_dot(rows, experts["w_up"], sizes), 0)
-    hidden = jax.nn.silu(gate) * up if gated else jnp.square(jax.nn.relu(up))
-    out = jax.lax.ragged_dot(hidden, experts["w_down"], sizes)
-    return jnp.where(keep, out, 0)
+    if impl == "flash":
+        # here and not at the top: the module pulls jax.experimental.pallas
+        from pytorch_distributed_rnn_tpu.ops.pallas_grouped import (
+            grouped_matmul as product,
+        )
+    else:
+        def product(lhs, weights, sizes):
+            return jnp.where(keep, jax.lax.ragged_dot(lhs, weights, sizes), 0)
+    up = product(rows, experts["w_up"], sizes)
+    if "w_gate" in experts:
+        hidden = jax.nn.silu(product(rows, experts["w_gate"], sizes)) * up
+    else:
+        hidden = jnp.square(jax.nn.relu(up))
+    return product(hidden, experts["w_down"], sizes)
 
 
 def held_experts_ffn(experts, x, picked, weights, *, first: int,
-                     capacity: int):
+                     capacity: int, impl: str = "dense"):
     """The routed part of an expert layer that THIS chip computes:
     ``sum over picked experts held here of w_e * Expert_e(x)``.
 
@@ -393,7 +402,10 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
 
     No pick is dropped, whatever the imbalance.  Picks are sorted by
     expert, absent ones last; the held ones are the first ``total`` rows
-    of the sorted list and go through grouped products.  Shapes are
+    of the sorted list and go through grouped products (``impl``:
+    :func:`_expert_rows`; both implementations get the same ``sizes`` and
+    the same ``capacity``; the branch below that computes every pick is
+    ``dense`` whatever ``impl`` says).  Shapes are
     static, so the rows computed are ``capacity`` (the caller's guess:
     some multiple of what a uniform router sends here) while ``total``
     fits - ALWAYS ``capacity`` rows of work, the spare ones zeros, so that
@@ -422,7 +434,7 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
         weight_of = weights.reshape(-1)[order]
         ends = jnp.cumsum(rows_per_expert)
 
-        def compute(rows: int, pad: bool):
+        def compute(rows: int, pad: bool, impl: str = impl):
             tokens = token_of[:rows]
             # group sizes as far as `rows` reaches (all of them whenever
             # this branch is the one taken)
@@ -435,7 +447,7 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
                 # do the same work whatever the router did, and a step's
                 # time does not follow the seed (PERF.md, PR 28)
                 sizes = sizes.at[-1].add(rows - clipped[-1])
-            out = _expert_rows(experts, x[tokens], sizes, valid)
+            out = _expert_rows(experts, x[tokens], sizes, valid, impl)
             out = out * weight_of[:rows, None].astype(out.dtype)
             y = jnp.zeros_like(x).at[tokens].add(out)
             return y, clipped[-1]
@@ -443,9 +455,13 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
         if capacity >= num_picks:
             y, computed = compute(num_picks, pad=False)
         else:
+            # the branch that computes every pick is the rare one and
+            # stays on XLA's kernel: a second set of this repo's kernels at
+            # its row count doubles what a start traces, lowers and loads
+            # (+1.9 s of the hybrid cell's 22 s warm set-up: PERF.md, PR 33)
             y, computed = jax.lax.cond(
                 total <= capacity, lambda: compute(capacity, pad=True),
-                lambda: compute(num_picks, pad=False))
+                lambda: compute(num_picks, pad=False, impl="dense"))
     f32 = jnp.float32
     return y, {
         "rows_max": jnp.max(rows_per_expert).astype(f32),

@@ -2,7 +2,7 @@
 """Compile every shipped Pallas kernel on the TPU and compare it with its
 reference, forward and backward, at one caller shape each.
 
-    python scripts/chip_kernel_check.py [--only SUBSTR] [--provoke]
+    python scripts/chip_kernel_check.py [--only SUBSTR]... [--provoke]
                                         [--out chiprun_out/kernel_check.json]
 
 Cases (ISSUE 21, tentpole 6): the fused LSTM at the motion default
@@ -16,7 +16,13 @@ attention cell's shape (32 heads, T=4096, q / k 192 wide, v 128 wide, causal,
 f32; once more at JAX's default precision, where the kernel's f32 products
 are bf16 passes and the bf16 tolerance applies; and a sweep that times the
 three kernels alone there over a list of tiles, beside what the block
-schedule says each tile costs in grid steps and fetched bytes).  Everything
+schedule says each tile costs in grid steps and fetched bytes), and the
+held experts' grouped products at both decoder cells' shapes
+(``ops/pallas_grouped.py``: forward, dlhs and drhs against
+``jax.lax.ragged_dot`` and its own gradients, at JAX's default precision
+and at "highest", ``sizes`` drawn as a fresh router draws them and padded
+as ``held_experts_ffn`` pads them, ms a call of each side, both sides'
+distance from a float64 host product; then a tile sweep).  Everything
 else runs under
 ``jax.default_matmul_precision("highest")``
 and the reference always computes in float32 - on the kernel's own
@@ -230,6 +236,190 @@ def _flash_sweep(batch, heads, seq, head_dim, v_dim, *, tiles=SWEEP_TILES,
             "ok": ok}
 
 
+# the two decoder cells' grouped products: rows a layer (the expert layer's
+# capacity), model width, expert width, held experts, experts in all, picks
+# a token, tokens a step (benchmarks/configs/*_1of16.json)
+GROUPED_SHAPES = {
+    "hybrid_ssm_moe cell": dict(rows=12288, d=2688, f=1856, held=8,
+                                experts=128, k=6, tokens=8192),
+    "mla_moe cell": dict(rows=16384, d=2048, f=768, held=16,
+                         experts=256, k=8, tokens=8192),
+}
+# (tm, share of the contraction, share of the width) the sweep times each
+# kernel at, besides the picker's own
+GROUPED_SWEEP = ((128, 1, 1), (256, 1, 1), (512, 1, 1), (1024, 1, 1),
+                 (512, 1, 3), (512, 3, 1), (256, 1, 3), (1024, 1, 3))
+
+
+def _router_sizes(shape, seed):
+    """Group sizes as ``held_experts_ffn`` hands them to the products on
+    fresh weights: sigmoid top-k over ALL experts of tokens that share most
+    of their direction (so a few experts lead, as a fresh model's hidden
+    states make them), the held experts' counts, the spare rows joined to
+    the last group."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_rnn_tpu.ops.moe import route_sigmoid_topk
+
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = (jax.random.normal(keys[0], (1, shape["d"]))
+         + 0.5 * jax.random.normal(keys[1], (shape["tokens"], shape["d"])))
+    router = 0.02 * jax.random.normal(keys[2], (shape["d"], shape["experts"]))
+    picked, _ = route_sigmoid_topk(
+        router, jnp.zeros((shape["experts"],)), x, shape["k"], 1.0)
+    counts = jnp.bincount(picked.reshape(-1), length=shape["experts"])
+    counts = counts[:shape["held"]]
+    clipped = jnp.minimum(jnp.cumsum(counts), shape["rows"])
+    sizes = jnp.diff(clipped, prepend=0)
+    sizes = sizes.at[-1].add(shape["rows"] - clipped[-1])
+    return sizes.astype(jnp.int32), counts
+
+
+def _grouped_operands(cell, product, seed):
+    """``((m, k, n, groups), sizes, held counts, (rows, weights, d_out))``
+    of one grouped product of a decoder cell (``product``: ``up`` D -> F or
+    ``down`` F -> D)."""
+    import jax
+    import jax.numpy as jnp
+
+    shape = GROUPED_SHAPES[cell]
+    m, groups = shape["rows"], shape["held"]
+    k, n = ((shape["d"], shape["f"]) if product == "up"
+            else (shape["f"], shape["d"]))
+    sizes, counts = _router_sizes(shape, seed)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    rows = jax.random.normal(keys[0], (m, k), jnp.float32)
+    weights = 0.02 * jax.random.normal(keys[1], (groups, k, n), jnp.float32)
+    d_out = jax.random.normal(keys[2], (m, n), jnp.float32)
+    return (m, k, n, groups), sizes, counts, (rows, weights, d_out)
+
+
+def _timed(fn, *args, calls=10):
+    import jax
+
+    out = jax.block_until_ready(fn(*args))  # compiles
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return out, 1e3 * (time.perf_counter() - t0) / calls
+
+
+def _grouped_case(cell, product, precision, seed=0):
+    """One grouped product of a decoder cell (``product``: ``up`` D -> F
+    or ``down`` F -> D), its three forms through ``ops/pallas_grouped.py``
+    against ``jax.lax.ragged_dot`` and its own gradient, both under
+    ``precision``: agreement, ms a call of each side, and both sides'
+    distance from a float64 host product over 256 rows (one bf16 pass reads
+    about 2e-3 of the largest entry, three about 1e-5)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_distributed_rnn_tpu.ops import pallas_grouped as pg
+
+    (m, k, n, groups), sizes, counts, (rows, weights, d_out) = (
+        _grouped_operands(cell, product, seed))
+
+    def xla_vjp(which):
+        def run(rows, weights, d_out):
+            _, pull = jax.vjp(
+                lambda r, w: jax.lax.ragged_dot(r, w, sizes), rows, weights)
+            return pull(d_out)[which]
+        return run
+
+    sides = {
+        "fwd": (lambda r, w, d: pg._gmm(r, w, sizes),
+                lambda r, w, d: jax.lax.ragged_dot(r, w, sizes)),
+        "dlhs": (lambda r, w, d: pg._gmm(d, w, sizes, transposed=True),
+                 xla_vjp(0)),
+        "drhs": (lambda r, w, d: pg._tgmm(r, d, sizes), xla_vjp(1)),
+    }
+    row = {"case": f"grouped {cell} {product} {precision}",
+           "dtype": "float32", "m_k_n_groups": [m, k, n, groups],
+           "held_rows": int(counts.sum()),
+           "rows_max_over_mean": round(
+               float(counts.max() / counts.mean()), 2),
+           "gflop": round(2 * m * k * n / 1e9, 1)}
+    kk = {"fwd": (k, n), "dlhs": (n, k), "drhs": (k, n)}
+    names = {"fwd": pg.GMM, "dlhs": pg.DLHS, "drhs": pg.TGMM}
+    errs = {}
+    with jax.default_matmul_precision(precision):
+        for form, (ours, xla) in sides.items():
+            got, ours_ms = _timed(jax.jit(ours), rows, weights, d_out)
+            want, xla_ms = _timed(jax.jit(xla), rows, weights, d_out)
+            errs[form] = _rel_err(got, want)
+            row[f"{form}_ms"] = {"kernel": round(ours_ms, 3),
+                                 "ragged_dot": round(xla_ms, 3)}
+            row[f"{form}_tiles"] = list(pg.pick_tiles(
+                names[form], m, *kk[form], groups, 4)[:3])
+            if form == "fwd":
+                # rows of the first and of the last group against float64
+                take = np.r_[0:128, m - 128:m]
+                group = np.searchsorted(
+                    np.cumsum(np.asarray(sizes)), take, side="right")
+                exact = np.einsum(
+                    "mk,mkn->mn", np.asarray(rows, np.float64)[take],
+                    np.asarray(weights, np.float64)[group])
+                row["off_float64"] = {
+                    "kernel": float(f"{_rel_err(got[take], exact):.3e}"),
+                    "ragged_dot": float(
+                        f"{_rel_err(want[take], exact):.3e}")}
+    row["rel_err"] = {k_: float(f"{v:.3e}") for k_, v in errs.items()}
+    # two sides that each make bf16 passes differ by a bf16 rounding
+    tolerance = TOLERANCE[
+        "float32" if precision == "highest" else "bfloat16"]
+    row["ok"] = max(errs.values()) <= tolerance
+    return row
+
+
+def _grouped_sweep(cell, product, calls=10):
+    """Time the three kernels alone at JAX's default precision (the
+    trainer's) at the picker's tiles and at ``GROUPED_SWEEP``'s; one row a
+    (kernel, tile) with ms a call beside the VMEM model.  Measures,
+    compares nothing: ``ok`` says that the picker's tiles ran."""
+    import jax
+    import jax.numpy as jnp
+
+    from pytorch_distributed_rnn_tpu.ops import pallas_grouped as pg
+
+    (m, k, n, groups), sizes, _, (rows, weights, d_out) = (
+        _grouped_operands(cell, product, 0))
+    runs = {
+        pg.GMM: (k, n, lambda t: pg._gmm(rows, weights, sizes, tiles=t)),
+        pg.DLHS: (n, k, lambda t: pg._gmm(
+            d_out, weights, sizes, transposed=True, tiles=t)),
+        pg.TGMM: (k, n, lambda t: pg._tgmm(rows, d_out, sizes, tiles=t)),
+    }
+    table, ok = [], True
+    with jax.default_matmul_precision("default"):
+        for kind, (kk, nn, run) in runs.items():
+            picked = pg.pick_tiles(kind, m, kk, nn, groups, 4)[:3]
+            tiles = [picked]
+            for tm, k_parts, n_parts in GROUPED_SWEEP:
+                tk = kk // k_parts
+                tn = -(-nn // n_parts // 128) * 128 if n_parts > 1 else nn
+                if kk % k_parts == 0 and (tk % 128 == 0 or k_parts == 1):
+                    tiles.append((tm, tk, tn))
+            for tile in dict.fromkeys(tiles):
+                row = {"kernel": kind, "tile": list(tile),
+                       "picked": tile == picked,
+                       "vmem_model_mib": round(pg.vmem_bytes(
+                           kind, *tile, kk, 4) / 2 ** 20, 1)}
+                try:
+                    row["ms_a_call"] = round(
+                        _timed(lambda t=tile: run(t), calls=calls)[1], 3)
+                except Exception as exc:  # noqa: BLE001 - a refused tile
+                    row["refused"] = str(exc)[-300:]
+                    ok = ok and tile != picked
+                table.append(row)
+                print(json.dumps(row), flush=True)
+    return {"case": f"grouped sweep {cell} {product} default",
+            "dtype": "float32", "calls_timed": calls, "sweep": table,
+            "ok": ok}
+
+
 def _provoke_refusals():
     """Make the installed compiler refuse programs and return what it
     says, verbatim (first 1500 characters)."""
@@ -293,9 +483,9 @@ def _provoke_refusals():
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="chip_kernel_check.py")
     parser.add_argument("--out", default="chiprun_out/kernel_check.json")
-    parser.add_argument("--only", default="", metavar="SUBSTR",
+    parser.add_argument("--only", action="append", metavar="SUBSTR",
                         help="run only the cases whose name contains "
-                        "SUBSTR")
+                        "SUBSTR (given several times: any of them)")
     parser.add_argument("--provoke", action="store_true",
                         help="also provoke compile refusals and record "
                         "the compiler's messages")
@@ -339,7 +529,16 @@ def main(argv=None) -> int:
         "flash attention latent f32 default tile sweep (mla_moe cell)":
             lambda: _flash_sweep(2, 32, 4096, 192, 128),
     }
-    cases = {k: v for k, v in cases.items() if args.only in k}
+    for cell in GROUPED_SHAPES:
+        for product in ("up", "down"):
+            for precision in ("default", "highest"):
+                cases[f"grouped {cell} {product} {precision}"] = (
+                    lambda c=cell, p=product, q=precision:
+                    _grouped_case(c, p, q))
+            cases[f"grouped sweep {cell} {product}"] = (
+                lambda c=cell, p=product: _grouped_sweep(c, p))
+    only = args.only or [""]
+    cases = {k: v for k, v in cases.items() if any(o in k for o in only)}
     if not cases:
         parser.error(f"--only {args.only!r} matches no case")
     rows = []

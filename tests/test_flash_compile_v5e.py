@@ -1,11 +1,13 @@
 """The flash kernels compiled for a described TPU v5e (no chip attached) at
 the two decoder cells' shapes, with the tiles and the scoped-VMEM limit the
-picker gives them, and the chunked scan's gradient at the hybrid cell's: what
-interpret mode cannot show.  The one file under
+picker gives them, the chunked scan's gradient at the hybrid cell's, and the
+three grouped-product kernels at both cells' shapes: what interpret mode
+cannot show.  The one file under
 ``tests/`` that loads the TPU's compiler; it does so inside a fixture, so
 every xdist worker collects the same tests."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,3 +114,61 @@ def test_chunked_scan_gradient_compiles_at_the_hybrid_cell_s_shape(chip):
         on_chip(64)).compile()
     assert "tpu_custom_call" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+# rows a layer, model width, expert width, held experts of the two decoder
+# cells (benchmarks/configs/*_1of16.json)
+GROUPED_CELLS = {"hybrid": (12288, 2688, 1856, 8),
+                 "joyai": (16384, 2048, 768, 16)}
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize("product", ["up", "down"])
+@pytest.mark.parametrize("cell", list(GROUPED_CELLS))
+def test_grouped_kernels_compile_at_both_cells_shapes(
+        chip, cell, product, precision, monkeypatch):
+    """The differentiable grouped product, D -> F (``up``) and F -> D
+    (``down``), value and both gradients: ``moe_gmm``, ``moe_gmm_dlhs`` and
+    ``moe_tgmm`` at the picker's tiles and under the limit its VMEM model
+    sets (1,856 = 14.5 lane tiles is whole or overhanging in every block
+    over it; interpret mode sees neither rule)."""
+    from pytorch_distributed_rnn_tpu.ops import pallas_grouped
+
+    monkeypatch.setattr(pallas_grouped, "_interpret", lambda: False)
+    picks = []
+    pick = pallas_grouped.pick_tiles
+
+    def recording(kind, *args, **kwargs):
+        picks.append((kind, *pick(kind, *args, **kwargs)))
+        return picks[-1][1:]
+
+    monkeypatch.setattr(pallas_grouped, "pick_tiles", recording)
+    rows, d, f, groups = GROUPED_CELLS[cell]
+    k, n = (d, f) if product == "up" else (f, d)
+
+    def on_chip(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def loss(lhs, weights, sizes):
+        return jnp.sum(jnp.sin(
+            pallas_grouped.grouped_matmul(lhs, weights, sizes)))
+
+    with jax.default_matmul_precision(precision):
+        # the launchers' own jit caches would hand a later case this
+        # case's trace: the shapes differ by case, the precision is in
+        # the key
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            on_chip(rows, k), on_chip(groups, k, n),
+            on_chip(groups, dtype=jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for kernel in ("moe_gmm", "moe_gmm_dlhs", "moe_tgmm"):
+        assert re.search(rf"{kernel}\.?\d* = ", text), kernel
+    assert sorted(kind for kind, *_ in picks) == [
+        "moe_gmm", "moe_gmm_dlhs", "moe_tgmm"]
+    for kind, tm, tk, tn, limit in picks:
+        assert rows % tm == 0, kind
+        need = pallas_grouped.vmem_bytes(
+            kind, tm, tk, tn, n if kind == "moe_gmm_dlhs" else k, 4,
+            precision == "highest")
+        assert need <= pallas_grouped._VMEM_MOST, kind
+        assert limit is None or limit >= need, kind

@@ -1,0 +1,301 @@
+"""The grouped-product kernels of ``ops/pallas_grouped.py`` in interpret
+mode on the CPU: each of the three against ``jax.lax.ragged_dot`` or a
+per-group ``einsum`` over ragged ``sizes``, the differentiable product's
+gradients against ``ragged_dot``'s, the picker and its VMEM model, and
+``held_experts_ffn`` giving one answer through both implementations."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pytorch_distributed_rnn_tpu.ops import pallas_grouped as pg
+from pytorch_distributed_rnn_tpu.ops.moe import (
+    held_experts_ffn,
+    route_sigmoid_topk,
+)
+
+# M 384 in row tiles of 128, K 336 and N 232 (the hybrid cell's 2,688 and
+# 1,856 over 8: N is 1.8 lane tiles, K 2.6)
+M, K, N = 384, 336, 232
+SIZES = {
+    # an empty group, a group of one row, boundaries off the row tile
+    "ragged": [100, 0, 1, 130, 53, 100],
+    # rows in no group: 40 rows past the last group read zero
+    "short": [100, 0, 1, 130, 53, 60],
+    # every boundary on a tile's edge, the last group empty
+    "aligned": [128, 0, 256, 0],
+    # one group, and nothing but rows in no group
+    "one": [384],
+    "none": [0, 0, 0],
+}
+# of the largest entry.  At the default precision the kernels round their
+# f32 operands to bf16 (one pass, as the chip's MXU does); the CPU's
+# ``ragged_dot`` beside them multiplies in float32
+TOLERANCE = {"default": 1e-2, "highest": 2e-5}
+
+
+def _operands(groups, m=M, k=K, n=N, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(keys[0], (m, k)),
+            jax.random.normal(keys[1], (groups, k, n)) / np.sqrt(k),
+            jax.random.normal(keys[2], (m, n)))
+
+
+def _group_of_row(sizes, m):
+    ends = np.cumsum(sizes)
+    return np.searchsorted(ends, np.arange(m), side="right")
+
+
+def _ragged(lhs, weights, sizes):
+    """``ragged_dot`` with the rows past the last group defined: zeros."""
+    valid = (jnp.arange(lhs.shape[0]) < jnp.sum(sizes))[:, None]
+    return jnp.where(valid, jax.lax.ragged_dot(
+        jnp.where(valid, lhs, 0), weights, sizes), 0)
+
+
+def _per_group(rows, d_out, sizes):
+    one_hot = jax.nn.one_hot(_group_of_row(sizes, rows.shape[0]),
+                             len(sizes))
+    return jnp.einsum("mg,mk,mn->gkn", one_hot, rows, d_out)
+
+
+def _close(got, want, tolerance):
+    scale = float(jnp.max(jnp.abs(want))) or 1.0
+    assert float(jnp.max(jnp.abs(got - want))) / scale < tolerance
+
+
+# (tm, tk, tn) by kernel: whole widths, and tiles that overhang the result
+# width (232 in blocks of 128) and split the contraction where it has a
+# divisor of 128 lanes (K 256 below)
+TILES = {"whole": (128, None, None), "split": (128, None, 128)}
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize("tiling", list(TILES))
+@pytest.mark.parametrize("case", list(SIZES))
+def test_moe_gmm_is_ragged_dot(case, tiling, precision):
+    sizes = jnp.array(SIZES[case], jnp.int32)
+    rows, weights, _ = _operands(len(SIZES[case]))
+    tm, tk, tn = TILES[tiling]
+    with jax.default_matmul_precision(precision):
+        got = pg._gmm(rows, weights, sizes, tiles=(tm, tk or K, tn or N))
+        want = _ragged(rows, weights, sizes)
+    _close(got, want, TOLERANCE[precision])
+    # rows in no group read exactly zero
+    assert not np.asarray(got)[sum(SIZES[case]):].any()
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize("tiling", list(TILES))
+@pytest.mark.parametrize("case", list(SIZES))
+def test_moe_gmm_dlhs_is_ragged_dot_with_the_weights_transposed(
+        case, tiling, precision):
+    sizes = jnp.array(SIZES[case], jnp.int32)
+    _, weights, d_out = _operands(len(SIZES[case]))
+    tm, tk, tn = TILES[tiling]
+    with jax.default_matmul_precision(precision):
+        # contraction N 232 (whole: no multiple of 128 divides it), result
+        # width K 336
+        got = pg._gmm(d_out, weights, sizes, transposed=True,
+                      tiles=(tm, N, tn or K))
+        want = _ragged(d_out, weights.transpose(0, 2, 1), sizes)
+    _close(got, want, TOLERANCE[precision])
+    assert not np.asarray(got)[sum(SIZES[case]):].any()
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize("tiling", ["whole", "split"])
+@pytest.mark.parametrize("case", list(SIZES))
+def test_moe_tgmm_is_a_product_per_group(case, tiling, precision):
+    sizes = jnp.array(SIZES[case], jnp.int32)
+    rows, _, d_out = _operands(len(SIZES[case]))
+    tiles = (128, K, N) if tiling == "whole" else (128, 128, 128)
+    with jax.default_matmul_precision(precision):
+        got = pg._tgmm(rows, d_out, sizes, tiles=tiles)
+        want = _per_group(rows, d_out, SIZES[case])
+    _close(got, want, TOLERANCE[precision])
+    # an empty group's result is exactly zero
+    for group, size in enumerate(SIZES[case]):
+        if size == 0:
+            assert not np.asarray(got)[group].any()
+
+
+@pytest.mark.parametrize("case", ["ragged", "short"])
+def test_a_split_contraction_accumulates_in_f32(case):
+    """K 256 in two blocks of 128: the accumulator's path."""
+    sizes = jnp.array(SIZES[case], jnp.int32)
+    rows, weights, _ = _operands(len(SIZES[case]), k=256)
+    with jax.default_matmul_precision("highest"):
+        _close(pg._gmm(rows, weights, sizes, tiles=(128, 128, 128)),
+               _ragged(rows, weights, sizes), 2e-5)
+        weights_t = weights.transpose(0, 2, 1)  # (G, N, 256): contract 256
+        _close(pg._gmm(rows, weights_t, sizes, transposed=True,
+                       tiles=(128, 128, 128)),
+               _ragged(rows, weights, sizes), 2e-5)
+
+
+def test_tiles_that_do_not_divide_are_refused():
+    rows, weights, _ = _operands(4)
+    sizes = jnp.array([100, 100, 100, 84], jnp.int32)
+    with pytest.raises(ValueError, match="do not divide"):
+        pg._gmm(rows, weights, sizes, tiles=(128, 128, N))  # 336 % 128
+    with pytest.raises(ValueError, match="do not divide"):
+        pg._tgmm(rows, rows, sizes, tiles=(256, K, K))  # 384 % 256
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+@pytest.mark.parametrize("shape", [(M, K, N), (30, 24, 40), (300, 24, 40)])
+@pytest.mark.parametrize("case", ["ragged", "short"])
+def test_grouped_matmul_and_its_gradients_are_ragged_dot_s(
+        case, shape, precision):
+    """The picker's own tiles, rows that need padding (30 -> 32, 300 ->
+    384), values and both gradients under a cotangent that differs by
+    entry."""
+    m, k, n = shape
+    sizes = np.array(SIZES[case]) * m // M
+    sizes = jnp.array(sizes, jnp.int32)
+    rows, weights, _ = _operands(len(SIZES[case]), m, k, n)
+
+    def loss(product):
+        return lambda r, w: jnp.sum(jnp.sin(product(r, w, sizes)))
+
+    with jax.default_matmul_precision(precision):
+        _close(pg.grouped_matmul(rows, weights, sizes),
+               _ragged(rows, weights, sizes), TOLERANCE[precision])
+        got = jax.jit(jax.grad(loss(pg.grouped_matmul), (0, 1)))(
+            rows, weights)
+        want = jax.grad(loss(_ragged), (0, 1))(rows, weights)
+    for g, w in zip(got, want):
+        _close(g, w, 2.5 * TOLERANCE[precision])
+    assert not np.asarray(got[0])[int(sizes.sum()):].any()
+
+
+def test_the_three_kernels_carry_their_names_and_share_one_trace():
+    """Two equal products inside one program: one traced launcher (the
+    ``jax.jit`` around it), kernels named for a device trace."""
+    sizes = jnp.array(SIZES["ragged"], jnp.int32)
+    rows, weights, _ = _operands(len(SIZES["ragged"]))
+
+    def loss(r, w):
+        once = pg.grouped_matmul(r, w, sizes)
+        return jnp.sum(once * pg.grouped_matmul(r, 2 * w, sizes))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1)))(rows, weights))
+    for name in (pg.GMM, pg.DLHS, pg.TGMM):
+        assert f"name={name}\n" in text
+    lowered = jax.jit(jax.grad(loss, (0, 1))).lower(rows, weights).as_text()
+    # two forward, two dlhs and two drhs calls, one function each
+    assert lowered.count("func.func private @_gmm") == 2  # plain, transposed
+    assert lowered.count("func.func private @_tgmm") == 1
+
+
+# -- the picker ---------------------------------------------------------------
+
+CELLS = {"hybrid": (12288, 2688, 1856, 8), "joyai": (16384, 2048, 768, 16)}
+
+
+@pytest.mark.parametrize("precise", [False, True])
+@pytest.mark.parametrize("kind", [pg.GMM, pg.DLHS, pg.TGMM])
+@pytest.mark.parametrize("product", ["up", "down"])
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_picked_tiles_keep_the_layout_rules_and_the_vmem_cap(
+        cell, product, kind, precise):
+    m, d, f, groups = CELLS[cell]
+    k, n = (d, f) if product == "up" else (f, d)
+    if kind == pg.DLHS:
+        k, n = n, k
+    tm, tk, tn, limit = pg.pick_tiles(kind, m, k, n, groups, 4,
+                                      precise=precise)
+    assert m % tm == 0 and tm % 128 == 0
+    if kind == pg.TGMM:  # K is a result dimension: whole or lane tiles
+        assert tk == k or tk % 128 == 0
+    else:  # the contraction is exact
+        assert k % tk == 0 and (tk == k or tk % 128 == 0)
+    assert tn == n or tn % 128 == 0
+    need = pg.vmem_bytes(kind, tm, tk, tn, k, 4, precise)
+    assert need <= pg._VMEM_MOST
+    assert limit is None or need < limit <= pg._VMEM_MOST * 9 // 8
+
+
+def test_width_tiles_of_the_awkward_width():
+    """1,856 = 14.5 lane tiles: whole as a contraction, whole or
+    overhanging as a result width; 2,688 = 21 x 128 has exact divisors."""
+    assert pg._width_tiles(1856, exact=True) == [1856]
+    assert pg._width_tiles(2688, exact=True) == [128, 384, 896, 2688]
+    loose = pg._width_tiles(1856, exact=False)
+    assert 1856 in loose and 1024 in loose and 640 in loose
+    assert all(t == 1856 or t % 128 == 0 for t in loose)
+
+
+def test_group_visits_list_every_tile_once_a_group_it_touches():
+    sizes = jnp.array([100, 0, 1, 130, 53, 60], jnp.int32)
+    offsets, group_of, tile_of, count = pg._group_visits(
+        sizes, 384, 128, empty_too=False)
+    # groups 0, 2, 3 | 3, 4 | 4, 5 and the 40 rows of no group (index 6)
+    assert int(count[0]) == 8
+    assert list(np.asarray(group_of)[:8]) == [0, 2, 3, 3, 4, 4, 5, 6]
+    assert list(np.asarray(tile_of)[:8]) == [0, 0, 0, 1, 1, 2, 2, 2]
+    assert list(np.asarray(offsets)) == [0, 100, 100, 101, 231, 284, 344, 384]
+    # the spare visits repeat the last real one
+    assert set(np.asarray(group_of)[8:]) == {6}
+    assert set(np.asarray(tile_of)[8:]) == {2}
+    # the per-group product visits the empty group once and no spare row
+    offsets, group_of, tile_of, count = pg._group_visits(
+        sizes, 384, 128, empty_too=True)
+    assert int(count[0]) == 8
+    assert list(np.asarray(group_of)[:8]) == [0, 1, 2, 3, 3, 4, 4, 5]
+    assert list(np.asarray(tile_of)[:8]) == [0, 0, 0, 0, 1, 1, 2, 2]
+
+
+# -- the expert layer through both implementations ----------------------------
+
+def _experts(key, count, d, f, gated):
+    keys = jax.random.split(key, 3)
+    experts = {"w_up": jax.random.normal(keys[0], (count, d, f)) / np.sqrt(d),
+               "w_down": jax.random.normal(keys[1], (count, f, d))
+               / np.sqrt(f)}
+    if gated:
+        experts["w_gate"] = (jax.random.normal(keys[2], (count, d, f))
+                             / np.sqrt(d))
+    return experts
+
+
+@pytest.mark.parametrize("capacity", [16, 64, 128],
+                         ids=["every_pick", "fits", "one_path"])
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "relu2"])
+def test_held_experts_ffn_gives_one_answer_through_both_implementations(
+        gated, capacity):
+    """``y``, the gradients of the experts and of ``x`` and the four
+    counters: in the branch that fits ``capacity`` (64: its spare rows
+    joined to the last group), in the one that computes every pick (16:
+    XLA's kernel whatever ``impl`` says) and where ``capacity`` holds every
+    pick and there is one path (128: the absent picks are rows of no group,
+    which the kernels write as zeros)."""
+    experts = _experts(jax.random.PRNGKey(0), 4, 24, 40, gated)
+    x = jax.random.normal(jax.random.PRNGKey(1), (40, 24))
+    router = jax.random.normal(jax.random.PRNGKey(2), (24, 16))
+    picked, weights = route_sigmoid_topk(router, jnp.zeros((16,)), x, 3, 2.5)
+    cotangent = jax.random.normal(jax.random.PRNGKey(3), x.shape)
+
+    def run(impl):
+        def routed(experts, x):
+            return held_experts_ffn(experts, x, picked, weights, first=4,
+                                    capacity=capacity, impl=impl)
+        (y, counters), pullback = jax.vjp(routed, experts, x)
+        return y, counters, pullback(
+            (cotangent, jax.tree.map(jnp.zeros_like, counters))), routed
+
+    with jax.default_matmul_precision("highest"):
+        y, counters, grads, routed = run("flash")
+        want_y, want_counters, want_grads, _ = run("dense")
+        kernels = str(jax.make_jaxpr(routed)(experts, x)).count("moe_gmm")
+    assert ({k: float(v) for k, v in counters.items()}
+            == {k: float(v) for k, v in want_counters.items()})
+    assert float(counters["picks_dropped"]) == 0
+    assert 16 < float(counters["rows_sum"]) <= 64
+    # this repo's kernels are in the program: two or three products
+    assert kernels == (3 if gated else 2)
+    np.testing.assert_allclose(y, want_y, atol=2e-5)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(got, want, atol=3e-5)
