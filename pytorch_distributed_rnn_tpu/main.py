@@ -143,6 +143,20 @@ def build_parser() -> argparse.ArgumentParser:
         "the normalised weights of the picked experts",
     )
     parser.add_argument(
+        "--moe-route-eps", default=0.0, type=float,
+        help="--model mla_moe / hybrid_ssm_moe: what the family adds to "
+        "the sum of the picked experts' scores before it divides by it "
+        "(lfm2_moe: 1e-6; the default adds nothing)",
+    )
+    parser.add_argument(
+        "--rope-theta", default=None, type=float,
+        help="base of the rotary embedding (rope_theta).  --model "
+        "mla_moe: over interleaved pairs of the rotary part of q / k "
+        "(default 32e6).  --model hybrid_ssm_moe: over the whole head of "
+        "q and k, pairs (i, i + head / 2) (lfm2_moe: 1e6); default none, "
+        "attention without position",
+    )
+    parser.add_argument(
         "--resume", default=None, type=Path, metavar="PATH|auto",
         help="restore params/optimizer state before training.  A path "
         "loads that checkpoint and retrains the full --epochs on top of "

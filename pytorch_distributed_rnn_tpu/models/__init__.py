@@ -2,7 +2,9 @@
 
 The six classes ``training/families.py:FAMILIES`` lists (``MotionModel``,
 ``CharRNN``, ``AttentionClassifier``, ``MoEClassifier``, ``MlaMoeLM``,
-``HybridSsmMoeLM``; the two decoder LMs share ``decoder_common.py``)
+``HybridSsmMoeLM``; the two decoder LMs share ``decoder_common.py``, and
+``HybridSsmMoeLM`` builds more than one published model from its pattern of
+residual parts)
 carry, by convention and with no base class, all that the program knows
 of a family:
 

@@ -1,7 +1,11 @@
-"""What the two decoder LMs that train as ONE chip of an expert-parallel
-deployment share (``models/mla_moe_lm.py``, ``models/hybrid_ssm_moe_lm.py``):
-the norm, the flags' parsing and refusals, parameters made on the device,
-the expert layer around ``ops/moe.py`` and the checkpointed head and loss.
+"""What the decoder LMs that train as ONE chip of an expert-parallel
+deployment share: the norm, the rotary embedding, the flags' parsing and
+refusals, parameters made on the device, the expert layer around
+``ops/moe.py`` and the checkpointed head and loss.  Three users: the
+latent-attention decoder (``models/mla_moe_lm.py``) and the two published
+models that ``models/hybrid_ssm_moe_lm.py`` builds from a pattern of
+residual parts (state-space or short-convolution mixers, attention with or
+without a rotary embedding, dense and routed feed-forward parts).
 """
 
 from __future__ import annotations
@@ -10,6 +14,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from pytorch_distributed_rnn_tpu.ops.moe import (
     expert_mlp,
@@ -24,6 +29,36 @@ def rms_norm(x, weight, eps: float):
     a norm."""
     mean_square = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return x / jnp.sqrt(mean_square + eps) * weight
+
+
+def rotary(x, theta: float, pairing: str = "interleaved"):
+    """Rotary embedding: each pair of ``x``'s last axis at position ``p``
+    turned by ``p * theta ** (-2i / d)``, ``i`` the pair's number.  ``x``:
+    (B, T, ..., d), positions along axis 1.  ``pairing`` says which entries
+    make pair ``i``: ``interleaved`` ``(x[2i], x[2i + 1])`` (DeepSeek-V3's
+    family), ``halves`` ``(x[i], x[i + d / 2])`` ("rotate half").
+
+    The cosines and sines are constants of the program, made on the host
+    in float64: in float32 the angle of position 4,095 is off by 1e-3 rad
+    on the chip (a power and a product of rounded numbers, then a cosine
+    of a large argument), which two implementations round differently."""
+    if pairing not in ("interleaved", "halves"):
+        raise ValueError(f"unknown rotary pairing {pairing!r}")
+    d, t = x.shape[-1], x.shape[1]
+    inv_freq = float(theta) ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    angles = np.arange(t, dtype=np.float64)[:, None] * inv_freq
+    shape = (1, t) + (1,) * (x.ndim - 3) + (d // 2,)
+    cos = jnp.asarray(np.cos(angles).reshape(shape), x.dtype)
+    sin = jnp.asarray(np.sin(angles).reshape(shape), x.dtype)
+    if pairing == "halves":
+        first, second = x[..., :d // 2], x[..., d // 2:]
+        return jnp.concatenate(
+            [first * cos - second * sin, first * sin + second * cos],
+            axis=-1)
+    even, odd = x[..., 0::2], x[..., 1::2]
+    turned = jnp.stack(
+        [even * cos - odd * sin, even * sin + odd * cos], axis=-1)
+    return turned.reshape(x.shape)
 
 
 # -- the command line ---------------------------------------------------------
@@ -126,9 +161,10 @@ def init_on_device(model, key):
 # -- the expert layer ---------------------------------------------------------
 
 def expert_layer(model, p, x):
-    """Router, the held experts' part of the routed sum and the shared
-    expert -> ``(y, counters)``.  The experts' form (gated SiLU or
-    relu squared) is what their parameters hold (``ops/moe.py``).
+    """Router, the held experts' part of the routed sum and, where the
+    parameters hold one, the shared expert -> ``(y, counters)``.  The
+    experts' form (gated SiLU or relu squared) is what their parameters
+    hold (``ops/moe.py``).
 
     The grouped products compute ``capacity_factor`` times the rows a
     uniform router sends here while the held picks fit (never a drop:
@@ -144,7 +180,7 @@ def expert_layer(model, p, x):
     xt = x.reshape(-1, shape[-1])
     picked, weights = route_sigmoid_topk(
         p["router"], p["router_bias"], xt, model.num_selected,
-        model.route_scale)
+        model.route_scale, model.route_eps)
     num_picks = xt.shape[0] * model.num_selected
     uniform = num_picks * model.held / model.num_experts
     capacity = max(int(model.capacity_factor * uniform), 8 * model.held)
@@ -152,9 +188,10 @@ def expert_layer(model, p, x):
         p["experts"], xt, picked, weights, first=model.experts_first,
         capacity=-(-capacity // 128) * 128,
         impl=resolve_attention_impl(model.impl))
-    with jax.named_scope("shared_expert"):
-        shared = expert_mlp(p["shared"], xt)
-    return (shared + routed).reshape(shape), counters
+    if "shared" in p:
+        with jax.named_scope("shared_expert"):
+            routed = expert_mlp(p["shared"], xt) + routed
+    return routed.reshape(shape), counters
 
 
 def moe_stats(counters) -> dict:

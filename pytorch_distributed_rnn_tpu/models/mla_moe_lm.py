@@ -42,7 +42,6 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from pytorch_distributed_rnn_tpu.models.decoder_common import (
     check_share,
@@ -54,6 +53,7 @@ from pytorch_distributed_rnn_tpu.models.decoder_common import (
     moe_stats,
     refuse_flags,
     rms_norm,
+    rotary,
 )
 from pytorch_distributed_rnn_tpu.ops.moe import expert_mlp
 
@@ -62,27 +62,8 @@ KERNEL_NAME = "mla_flash"
 # --ffn-dims DENSE,EXPERT where the flag is not given (intermediate_size,
 # moe_intermediate_size)
 FFN_DIMS = "7168,768"
-
-
-def rotary(x, theta: float):
-    """Rotary embedding over interleaved pairs: ``(x[2i], x[2i+1])`` of
-    position ``p`` turned by ``p * theta ** (-2i / d)``.  ``x``: (B, T, ...,
-    d), positions along axis 1.
-
-    The cosines and sines are constants of the program, made on the host
-    in float64: in float32 the angle of position 4,095 is off by 1e-3 rad
-    on the chip (a power and a product of rounded numbers, then a cosine
-    of a large argument), which two implementations round differently."""
-    d, t = x.shape[-1], x.shape[1]
-    inv_freq = float(theta) ** (-np.arange(0, d, 2, dtype=np.float64) / d)
-    angles = np.arange(t, dtype=np.float64)[:, None] * inv_freq
-    shape = (1, t) + (1,) * (x.ndim - 3) + (d // 2,)
-    cos = jnp.asarray(np.cos(angles).reshape(shape), x.dtype)
-    sin = jnp.asarray(np.sin(angles).reshape(shape), x.dtype)
-    even, odd = x[..., 0::2], x[..., 1::2]
-    turned = jnp.stack(
-        [even * cos - odd * sin, even * sin + odd * cos], axis=-1)
-    return turned.reshape(x.shape)
+# --rope-theta where the flag is not given (rope_theta)
+ROPE_THETA = 32e6
 
 
 @dataclass(frozen=True)
@@ -114,7 +95,7 @@ class MlaMoeLM:
     nope_dim: int = 128             # qk_nope_head_dim
     rope_dim: int = 64              # qk_rope_head_dim
     v_dim: int = 128                # v_head_dim
-    rope_theta: float = 32e6        # rope_theta
+    rope_theta: float = ROPE_THETA  # rope_theta
     dense_ffn_dim: int = 7168       # intermediate_size
     expert_ffn_dim: int = 768       # moe_intermediate_size
     num_experts: int = 256          # n_routed_experts (the router's width)
@@ -124,6 +105,7 @@ class MlaMoeLM:
     shared_experts: int = 1         # n_shared_experts
     dense_layers: int = 1           # first_k_dense_replace
     route_scale: float = 2.5        # routed_scaling_factor
+    route_eps: float = 0.0          # added to the picked scores' sum
     mtp_weight: float = 0.3         # 0: no prediction module
     norm_eps: float = 1e-6          # rms_norm_eps
     init_std: float = 0.02
@@ -162,10 +144,6 @@ class MlaMoeLM:
             "qk_rope_head_dim, v_head_dim)",
         )
         parser.add_argument(
-            "--rope-theta", default=32e6, type=float,
-            help="--model mla_moe: base of the rotary embedding",
-        )
-        parser.add_argument(
             "--mtp-weight", default=0.3, type=float,
             help="--model mla_moe: weight of the multi-token-prediction "
             "module's loss (one module, predicting the token after next); "
@@ -192,12 +170,14 @@ class MlaMoeLM:
                 num_heads=getattr(args, "num_heads", 4),
                 q_rank=q_rank, kv_rank=kv_rank,
                 nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
-                rope_theta=args.rope_theta,
+                rope_theta=(ROPE_THETA if args.rope_theta is None
+                            else args.rope_theta),
                 dense_ffn_dim=dense_ffn, expert_ffn_dim=expert_ffn,
                 num_experts=getattr(args, "num_experts", 4),
                 num_selected=getattr(args, "moe_top_k", 1),
                 experts_first=first, experts_held=held,
                 route_scale=args.moe_route_scale,
+                route_eps=args.moe_route_eps,
                 mtp_weight=args.mtp_weight,
                 remat=getattr(args, "remat", False),
             )

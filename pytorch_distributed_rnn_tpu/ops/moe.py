@@ -330,14 +330,17 @@ def moe_ffn_dense(params, x, *, num_selected: int = 1):
 # ---------------------------------------------------------------------------
 
 
-def route_sigmoid_topk(router, bias, x, k: int, scale: float):
+def route_sigmoid_topk(router, bias, x, k: int, scale: float,
+                       eps: float = 0.0):
     """Sigmoid scores over ALL experts, the ``k`` largest of score + bias
     picked, the picked scores normalised to sum to 1 and scaled:
     ``x (N, D)`` -> ``(picked (N, k) int32, weights (N, k) f32)``.
 
-    ``bias`` (the published ``e_score_correction_bias``) moves the pick
-    and not the weight, and stands outside the gradient.  Scores are
-    computed in f32 whatever ``x`` is: a pick must not quantize."""
+    ``bias`` (the published ``e_score_correction_bias`` / ``expert_bias``)
+    moves the pick and not the weight, and stands outside the gradient.
+    ``eps`` is what a family adds to the sum it divides by (``lfm2_moe``:
+    1e-6; 0 adds nothing to the program).  Scores are computed in f32
+    whatever ``x`` is: a pick must not quantize."""
     with jax.named_scope("router"):
         scores = jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), router.astype(jnp.float32),
@@ -345,7 +348,9 @@ def route_sigmoid_topk(router, bias, x, k: int, scale: float):
         _, picked = jax.lax.top_k(
             scores + jax.lax.stop_gradient(bias), k)
         weights = jnp.take_along_axis(scores, picked, axis=-1)
-        weights = scale * weights / jnp.sum(weights, axis=-1, keepdims=True)
+        scaled = scale * weights
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = scaled / (total + eps if eps else total)
     return picked, weights
 
 

@@ -56,17 +56,18 @@ def _exp_jvp(primals, tangents):
     return y, y * tangents[0]
 
 
-def causal_conv(x, weight, bias):
+def causal_conv(x, weight, bias=None):
     """Causal depthwise convolution over time: ``x`` (B, T, C), ``weight``
-    (K, C), ``bias`` (C,) -> ``out[t] = bias + sum_k weight[k] x[t - (K - 1)
-    + k]``, ``x`` left-padded with ``K - 1`` zeros (tap ``K - 1`` reads the
-    current position, as ``torch.nn.Conv1d(groups=C, padding=K - 1)`` cut to
-    T does)."""
+    (K, C), ``bias`` (C,) or none -> ``out[t] = bias + sum_k weight[k] x[t -
+    (K - 1) + k]``, ``x`` left-padded with ``K - 1`` zeros (tap ``K - 1``
+    reads the current position, as ``torch.nn.Conv1d(groups=C, padding=K -
+    1)`` cut to T does)."""
     taps, t = weight.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     out = bias
     for k in range(taps):
-        out = out + padded[:, k:k + t] * weight[k]
+        tap = padded[:, k:k + t] * weight[k]
+        out = tap if out is None else out + tap
     return out
 
 

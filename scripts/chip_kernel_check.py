@@ -16,8 +16,11 @@ attention cell's shape (32 heads, T=4096, q / k 192 wide, v 128 wide, causal,
 f32; once more at JAX's default precision, where the kernel's f32 products
 are bf16 passes and the bf16 tolerance applies; and a sweep that times the
 three kernels alone there over a list of tiles, beside what the block
-schedule says each tile costs in grid steps and fetched bytes), and the
-held experts' grouped products at both decoder cells' shapes
+schedule says each tile costs in grid steps and fetched bytes), the same
+kernels at the short-convolution decoder cell's shape (2 windows x 32 heads,
+T=8192, q / k / v 64 wide: half a lane tile; the dense side a few heads at
+a time, the whole score matrix of 64 rows would be 17 GB), and the
+held experts' grouped products at the three decoder cells' shapes
 (``ops/pallas_grouped.py``: forward, dlhs and drhs against
 ``jax.lax.ragged_dot`` and its own gradients, at JAX's default precision
 and at "highest", ``sizes`` drawn as a fresh router draws them and padded
@@ -130,10 +133,13 @@ def _rnn_case(cell, hidden, batch, seq, in_dim, dtype_name):
 
 
 def _flash_case(batch, heads, seq, head_dim, dtype_name, *, v_dim=None,
-                causal=False, precision="highest"):
+                causal=False, precision="highest", dense_heads=None):
     """``precision`` is the ambient matmul precision of the KERNEL's side
     (the reference always runs at "highest"); below "highest" an f32
-    kernel multiplies in bf16 passes and is held to the bf16 tolerance."""
+    kernel multiplies in bf16 passes and is held to the bf16 tolerance.
+    ``dense_heads``: the dense side computes that many heads' score
+    matrices at a time, one block after another, where all of them at once
+    do not fit the chip."""
     import functools
 
     import jax
@@ -158,10 +164,20 @@ def _flash_case(batch, heads, seq, head_dim, dtype_name, *, v_dim=None,
         with jax.default_matmul_precision(precision):
             return flash_attention(*args, causal=causal)
 
+    dense = functools.partial(mha_attention, causal=causal)
+
+    @jax.checkpoint
+    def some_heads(block):
+        return dense(*(a[None] for a in block))[0]
+
+    def dense_in_blocks(*args):
+        blocks = [a.reshape(-1, dense_heads, *a.shape[2:]) for a in args]
+        out = jax.lax.map(some_heads, tuple(blocks))
+        return out.reshape(batch, heads, seq, out.shape[-1])
+
     # grad0/grad1/grad2 = the dQ kernel and the two outputs of the dK,dV
     # kernel
-    return _compare(name, fused,
-                    functools.partial(mha_attention, causal=causal),
+    return _compare(name, fused, dense_in_blocks if dense_heads else dense,
                     (q, k, v),
                     dtype_name if precision == "highest" else "bfloat16")
 
@@ -236,14 +252,16 @@ def _flash_sweep(batch, heads, seq, head_dim, v_dim, *, tiles=SWEEP_TILES,
             "ok": ok}
 
 
-# the two decoder cells' grouped products: rows a layer (the expert layer's
+# the three decoder cells' grouped products: rows a layer (the expert layer's
 # capacity), model width, expert width, held experts, experts in all, picks
-# a token, tokens a step (benchmarks/configs/*_1of16.json)
+# a token, tokens a step (benchmarks/configs/*_1of16.json, *_1of8.json)
 GROUPED_SHAPES = {
     "hybrid_ssm_moe cell": dict(rows=12288, d=2688, f=1856, held=8,
                                 experts=128, k=6, tokens=8192),
     "mla_moe cell": dict(rows=16384, d=2048, f=768, held=16,
                          experts=256, k=8, tokens=8192),
+    "hybrid_ssm_moe conv cell": dict(rows=32768, d=2048, f=1536, held=8,
+                                     experts=64, k=4, tokens=16384),
 }
 # (tm, share of the contraction, share of the width) the sweep times each
 # kernel at, besides the picker's own
@@ -528,6 +546,17 @@ def main(argv=None) -> int:
                                 causal=True, precision="default"),
         "flash attention latent f32 default tile sweep (mla_moe cell)":
             lambda: _flash_sweep(2, 32, 4096, 192, 128),
+        "flash attention head 64 f32 highest (hybrid_ssm_moe conv cell)":
+            lambda: _flash_case(2, 32, 8192, 64, "float32", v_dim=64,
+                                causal=True, dense_heads=2),
+        "flash attention head 64 f32 default (hybrid_ssm_moe conv cell)":
+            lambda: _flash_case(2, 32, 8192, 64, "float32", v_dim=64,
+                                causal=True, precision="default",
+                                dense_heads=2),
+        "flash attention head 64 f32 default tile sweep "
+        "(hybrid_ssm_moe conv cell)":
+            lambda: _flash_sweep(2, 32, 8192, 64, 64, tiles=(
+                (512, 512), (1024, 512), (512, 1024), (2048, 1024))),
     }
     for cell in GROUPED_SHAPES:
         for product in ("up", "down"):

@@ -32,7 +32,9 @@ sys.path.insert(0, str(ROOT))
 # `mamba_in_proj` is not read as another's prefix
 SCOPES = sorted(
     ("embed", "mamba_in_proj", "mamba_conv", "ssd", "mamba_gate_norm",
-     "mamba_out_proj", "gqa", "mla", "mtp", "router", "experts",
+     "mamba_out_proj", "short_conv_in_proj", "short_conv",
+     "short_conv_out_proj", "dense_ffn", "gqa", "qk_norm", "rope", "mla",
+     "mtp", "router", "experts",
      "shared_expert", "head", "loss", "optimizer", "grad_reduce",
      "param_gather", "dropout", "recurrence_wgrad", "input_proj",
      "recurrence"), key=len, reverse=True)

@@ -1,9 +1,13 @@
 #!/usr/bin/env python
-"""How close the picks of ``--model mla_moe`` stand to a flip between the
-program and the benchmark's plain reference.
+"""How close the picks of a decoder cell's expert layers stand to a flip
+between the program and the benchmark's plain reference.
 
-    python scripts/mla_moe_routing_check.py [--seeds 8] [--tiny]
+    python scripts/mla_moe_routing_check.py [--cell NAME] [--seeds 8] [--tiny]
                                             [--out chiprun_out/routing.json]
+
+(Named for the first family it read, ``--model mla_moe``, the default cell;
+``--cell`` takes any cell of ``--model mla_moe`` or ``--model hybrid_ssm_moe``.
+Below, 8 and 9 stand for a cell's ``--moe-top-k`` and the next.)
 
 The comparison that decides ``correct`` holds the program's gradients to the
 reference's on one window.  Both sides pick each token's 8 experts from
@@ -29,7 +33,7 @@ weights, "highest" matmul precision):
 
 Needs a TPU at the cell's size; ``--tiny`` runs toy widths anywhere (the
 CPU test's rehearsal).  Sizes and the data come from the benchmark's own
-files for the cell ``joyai_flash_train_t4096_1chip``.
+files for the cell (default ``joyai_flash_train_t4096_1chip``).
 """
 
 from __future__ import annotations
@@ -84,6 +88,57 @@ def all_scores(params, tokens, attention, norm, block):
     return scores
 
 
+def part_scores(params, tokens, norm, part):
+    """Router scores of every expert layer of a model built from a pattern
+    of residual parts (``--model hybrid_ssm_moe``): ``norm`` and ``part``
+    are one side's own functions, ``part(i, p, x)`` the stream after part
+    ``i``."""
+    import jax
+
+    x, scores = params["embed"][tokens[:, :-1]], []
+    for i, p in enumerate(params["layers"]):
+        if "router" in p["mixer"]:
+            routed = norm(x, p["norm"])
+            scores.append(jax.nn.sigmoid(
+                routed.reshape(-1, routed.shape[-1]) @ p["mixer"]["router"]))
+        x = part(i, p, x)
+    return scores
+
+
+def score_programs(model, reference):
+    """``(system, plain)``: each ``(params, tokens) -> [scores a layer]``,
+    for either decoder family and its reference module."""
+    import inspect
+
+    from pytorch_distributed_rnn_tpu.models.decoder_common import rms_norm
+
+    def norm(x, w):
+        return rms_norm(x, w, model.norm_eps)
+
+    if hasattr(model, "pattern"):
+        # the reference's part takes, after (p, x), what its lm_loss takes
+        # after (params, batch): the share's first expert, then the
+        # published constants it defaults to
+        step = getattr(reference, "part", None) or reference.layer
+        constants = [
+            value.default for value in list(inspect.signature(
+                reference.lm_loss).parameters.values())[3:]]
+        return (
+            lambda p, t: part_scores(
+                p, t, norm, lambda i, p_, x: model._layer(
+                    model.pattern[i], p_, x)[0]),
+            lambda p, t: part_scores(
+                p, t, reference.rms_norm, lambda i, p_, x: step(
+                    p_, x, model.experts_first, *constants)))
+    return (
+        lambda p, t: all_scores(
+            p, t, model._attention, norm,
+            lambda p_, x: model._block(p_, x)[0]),
+        lambda p, t: all_scores(
+            p, t, reference.latent_attention, reference.rms_norm,
+            lambda p_, x: reference.block(p_, x, model.experts_first)))
+
+
 def make_report(model, reference):
     """``report(params, tokens) -> dict`` of the numbers above for
     one model and one reference module; the two score programs are
@@ -92,15 +147,8 @@ def make_report(model, reference):
     import jax.numpy as jnp
     import numpy as np
 
-    from pytorch_distributed_rnn_tpu.models.mla_moe_lm import rms_norm
-
-    system_scores = jax.jit(lambda p, t: all_scores(
-        p, t, model._attention,
-        lambda x, w: rms_norm(x, w, model.norm_eps),
-        lambda p_, x: model._block(p_, x)[0]))
-    plain_scores = jax.jit(lambda p, t: all_scores(
-        p, t, reference.latent_attention, reference.rms_norm,
-        lambda p_, x: reference.block(p_, x, model.experts_first)))
+    system_scores, plain_scores = map(
+        jax.jit, score_programs(model, reference))
     k = model.num_selected
     lo, hi = model.experts_first, model.experts_first + model.held
 
@@ -155,6 +203,8 @@ def make_report(model, reference):
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="mla_moe_routing_check.py")
+    parser.add_argument("--cell", default=CELL,
+                        help="a decoder cell of BENCHMARK.json")
     parser.add_argument("--seeds", type=int, default=8)
     parser.add_argument("--first-seed", type=int, default=2147483700)
     parser.add_argument("--seed-list", default=None,
@@ -177,7 +227,7 @@ def main(argv=None) -> int:
         print("mla_moe_routing_check: the cell's size needs a TPU "
               "(--tiny runs toy widths)", file=sys.stderr)
         return 1
-    cell = harness.load_cell(CELL)
+    cell = harness.load_cell(args.cell)
     if args.tiny:
         data = harness.BENCH_DIR / "tests" / "data"
         cell["config"] = json.loads(
@@ -205,7 +255,7 @@ def main(argv=None) -> int:
         print(json.dumps(rows[-1]), flush=True)
     summary = {
         "device": {"platform": device.platform, "kind": device.device_kind},
-        "cell": CELL, "tiny": args.tiny, "seeds": rows,
+        "cell": args.cell, "tiny": args.tiny, "seeds": rows,
         "min_margin": min(r["min_margin"] for r in rows),
         "max_score_diff": max(r["max_score_diff"] for r in rows),
         "flipped_tokens": sum(r["flipped_tokens"] for r in rows),

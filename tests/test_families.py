@@ -178,7 +178,9 @@ def test_the_families_are_the_six_classes():
 # (first option string, default, type, choices) of every global flag, as the
 # parser of PR 29 had them; since PR 32 the three flags both decoder LMs read
 # are main.py's (--ffn-dims with each family's widths as its default) and the
-# hybrid family brings four of its own
+# hybrid family brings four of its own; since PR 34 --rope-theta is main.py's
+# too (no default: each family has its own), --moe-route-eps joins it, and
+# the hybrid family brings five more for the forms LFM2's layers take
 FLAGS = [
     ("--checkpoint-directory", Path("models"), "Path", None),
     ("--dataset-path", Path("data"), "Path", None),
@@ -201,16 +203,22 @@ FLAGS = [
     ("--vocab-size", None, "int", None),
     ("--mla-ranks", "1536,512", None, None),
     ("--mla-head-dims", "128,64,128", None, None),
-    ("--rope-theta", 32000000.0, "float", None),
     ("--mtp-weight", 0.3, "float", None),
     ("--hybrid-pattern",
      "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME", None, None),
     ("--mamba-dims", "64,64,128,8", None, None),
     ("--mamba-chunk", 128, "int", None),
     ("--gqa-dims", "2,128", None, None),
+    ("--qk-norm", False, None, None),
+    ("--conv-taps", 4, "int", None),
+    ("--dense-ffn-dim", 0, "int", None),
+    ("--gated-ffn", False, None, None),
+    ("--tie-embeddings", False, None, None),
     ("--ffn-dims", None, None, None),
     ("--experts-held", None, None, None),
     ("--moe-route-scale", 2.5, "float", None),
+    ("--moe-route-eps", 0.0, "float", None),
+    ("--rope-theta", None, "float", None),
     ("--num-heads", 4, "int", None),
     ("--num-experts", 4, "int", None),
     ("--moe-top-k", 1, "int", None),

@@ -1,7 +1,8 @@
 """The flash kernels compiled for a described TPU v5e (no chip attached) at
-the two decoder cells' shapes, with the tiles and the scoped-VMEM limit the
+the three decoder cells' shapes (q / k 192 and v 128 wide, 128 / 128, and 64 /
+64: half a lane tile), with the tiles and the scoped-VMEM limit the
 picker gives them, the chunked scan's gradient at the hybrid cell's, and the
-three grouped-product kernels at both cells' shapes: what interpret mode
+three grouped-product kernels at the three cells' shapes: what interpret mode
 cannot show.  The one file under
 ``tests/`` that loads the TPU's compiler; it does so inside a fixture, so
 every xdist worker collects the same tests."""
@@ -95,6 +96,41 @@ def test_grouped_query_kernels_compile_at_the_hybrid_cell_s_shape(
         assert kernel in text
 
 
+@pytest.mark.parametrize("precision", ["default", "highest"])
+def test_flash_kernels_compile_at_head_width_64(chip, precision, monkeypatch):
+    """T 8,192, q / k / v 64 wide (half a lane tile: every block's last
+    dimension is the array's own, not a multiple of 128), f32, causal, K and
+    V broadcast over their query heads (two of the 64 rows here): forward,
+    dq and dk / dv at the picker's tiles, the short-convolution decoder
+    cell's attention."""
+    monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+    picks = []
+    pick = pallas_attention.pick_blocks
+
+    def recording(kind, *args, **kwargs):
+        picks.append((kind, *pick(kind, *args, **kwargs)))
+        return picks[-1][1:]
+
+    monkeypatch.setattr(pallas_attention, "pick_blocks", recording)
+    on_chip = jax.ShapeDtypeStruct((1, 2, 8192, 64), jnp.float32,
+                                   sharding=chip)
+
+    def loss(q, k, v):
+        return jnp.sum(pallas_attention.flash_attention(
+            q, k, v, causal=True, name="gqa_flash"))
+
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            on_chip, on_chip, on_chip).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for kernel in ("gqa_flash_fwd", "gqa_flash_dq", "gqa_flash_dkv"):
+        assert kernel in text
+    assert sorted(kind for kind, *_ in picks) == ["dkv", "dq", "fwd"]
+    for kind, block_q, block_k, limit in picks:
+        assert (block_q, block_k) == (1024, 1024), kind
+        assert limit > pallas_attention._VMEM_DEFAULT, kind
+
+
 def test_chunked_scan_gradient_compiles_at_the_hybrid_cell_s_shape(chip):
     """One window of 8,192 through ``ops/ssd.py`` at the published sizes
     (64 heads of 64, state 128, 8 groups, chunk 128), values and every
@@ -116,16 +152,17 @@ def test_chunked_scan_gradient_compiles_at_the_hybrid_cell_s_shape(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
 
 
-# rows a layer, model width, expert width, held experts of the two decoder
-# cells (benchmarks/configs/*_1of16.json)
+# rows a layer, model width, expert width, held experts of the three decoder
+# cells (benchmarks/configs/*_1of16.json, *_1of8.json)
 GROUPED_CELLS = {"hybrid": (12288, 2688, 1856, 8),
-                 "joyai": (16384, 2048, 768, 16)}
+                 "joyai": (16384, 2048, 768, 16),
+                 "conv_hybrid": (32768, 2048, 1536, 8)}
 
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
 @pytest.mark.parametrize("product", ["up", "down"])
 @pytest.mark.parametrize("cell", list(GROUPED_CELLS))
-def test_grouped_kernels_compile_at_both_cells_shapes(
+def test_grouped_kernels_compile_at_the_cells_shapes(
         chip, cell, product, precision, monkeypatch):
     """The differentiable grouped product, D -> F (``up``) and F -> D
     (``down``), value and both gradients: ``moe_gmm``, ``moe_gmm_dlhs`` and

@@ -495,7 +495,7 @@ def test_the_pattern_parser_takes_the_first_layers(pattern, layers, want):
     ("--ffn-dims", "24", "--ffn-dims wants 2 whole numbers"),
     ("--mamba-chunk", "5", "no multiple of --mamba-chunk 5"),
     ("--mamba-chunk", "0", "no multiple of --mamba-chunk 0"),
-    ("--hybrid-pattern", "MEXEM", "made of M, \\* and E"),
+    ("--hybrid-pattern", "MEXEM", "made of M, C, \\*, D and E"),
     ("--stacked-layer", "60", "60 layers asked of a pattern of 52"),
     ("--moe-top-k", "40", "more experts a token than experts"),
     ("--vocab-size", "200", "smaller than the data's vocabulary"),

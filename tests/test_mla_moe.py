@@ -15,7 +15,7 @@ import pytest
 from pytorch_distributed_rnn_tpu.data.text import TextDataset
 from pytorch_distributed_rnn_tpu.main import build_parser
 from pytorch_distributed_rnn_tpu.models import MlaMoeLM
-from pytorch_distributed_rnn_tpu.models.mla_moe_lm import rotary
+from pytorch_distributed_rnn_tpu.models.decoder_common import rotary
 from pytorch_distributed_rnn_tpu.obs import spans
 from pytorch_distributed_rnn_tpu.ops.attention import mha_attention
 from pytorch_distributed_rnn_tpu.ops.moe import (
@@ -361,7 +361,13 @@ def test_the_cli_builds_the_share_it_is_told():
     assert (defaults.mla_ranks, defaults.mla_head_dims,
             defaults.rope_theta, defaults.moe_route_scale,
             defaults.mtp_weight) == (
-        "1536,512", "128,64,128", 32e6, 2.5, 0.3)
+        "1536,512", "128,64,128", None, 2.5, 0.3)
+    # --rope-theta is main.py's too since PR 34: the family's own where it
+    # is not given
+    assert (families.build_model(_args(), train).rope_theta,
+            families.build_model(
+                _args("--rope-theta", "10000"), train).rope_theta) == (
+        32e6, 1e4)
     # --ffn-dims is main.py's (two families read it): the family's own
     # widths where it is not given
     published = families.build_model(_args("--ffn-dims", None), train)
