@@ -1,11 +1,12 @@
 """What the decoder LMs that train as ONE chip of an expert-parallel
 deployment share: the norm, the rotary embedding, the flags' parsing and
 refusals, parameters made on the device, the expert layer around
-``ops/moe.py`` and the checkpointed head and loss.  Three users: the
-latent-attention decoder (``models/mla_moe_lm.py``) and the two published
-models that ``models/hybrid_ssm_moe_lm.py`` builds from a pattern of
-residual parts (state-space or short-convolution mixers, attention with or
-without a rotary embedding, dense and routed feed-forward parts).
+``ops/moe.py`` and the head and loss with their hand-written backward.
+Three users: the latent-attention decoder (``models/mla_moe_lm.py``) and
+the two published models that ``models/hybrid_ssm_moe_lm.py`` builds from a
+pattern of residual parts (state-space or short-convolution mixers,
+attention with or without a rotary embedding, dense and routed feed-forward
+parts).
 """
 
 from __future__ import annotations
@@ -210,18 +211,52 @@ def moe_stats(counters) -> dict:
 
 # -- head and loss ------------------------------------------------------------
 
-@functools.partial(jax.checkpoint, static_argnums=(4,))
+def _head_logits(h, norm, head, eps):
+    with jax.named_scope("head"):
+        return (rms_norm(h, norm, eps) @ head).astype(jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def head_nll(h, norm, head, targets, eps):
     """Final norm, output head and per-position cross entropy (B, T),
-    with the hit of the arg max beside it.  Checkpointed: the backward
-    pass recomputes the (B, T, vocab) logits instead of keeping them, so
-    the main model's and a prediction module's never lie in memory
-    together."""
-    with jax.named_scope("head"):
-        logits = (rms_norm(h, norm, eps) @ head).astype(jnp.float32)
+    with the hit of the arg max beside it.
+
+    Differentiated by hand.  The backward pass is handed ``h``, the norm's
+    weight, the head, the targets and the rows' logsumexp (B, T) f32,
+    never an array with a vocabulary axis: it recomputes the (B, T, vocab)
+    logits, so the main model's and a prediction module's never lie in
+    memory together, and reads the softmax off them as ``exp(logits -
+    logsumexp)``.  No max and no sum over the vocabulary there: as a
+    ``jax.checkpoint`` of ``log_softmax`` the recomputed row max at (2,
+    8192, 8192) lowered to a reduce-window 16,383 wide, 55 ms an execution
+    on a v5e (PERF.md, PR 35)."""
+    return _head_nll_fwd(h, norm, head, targets, eps)[0]
+
+
+def _head_nll_fwd(h, norm, head, targets, eps):
+    logits = _head_logits(h, norm, head, eps)
     with jax.named_scope("loss"):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(
-            logp, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        nll = lse - jnp.take_along_axis(
+            logits, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
     hit = (jnp.argmax(logits, axis=-1) == targets).astype(jnp.float32)
-    return nll, hit
+    return (nll, hit), (h, norm, head, targets, lse)
+
+
+def _head_nll_bwd(eps, residuals, cotangents):
+    h, norm, head, targets, lse = residuals
+    g, _ = cotangents  # ``hit`` is a count: no gradient
+    # as under ``jax.checkpoint``: without it XLA shares the forward's
+    # logits with this pass, and the two heads' then lie in memory together
+    h, norm, head, lse = jax.lax.optimization_barrier((h, norm, head, lse))
+    logits, pull = jax.vjp(
+        functools.partial(_head_logits, eps=eps), h, norm, head)
+    with jax.named_scope("loss"):
+        picked = jax.lax.broadcasted_iota(
+            jnp.int32, logits.shape, logits.ndim - 1) == targets[..., None]
+        dlogits = g[..., None] * (
+            jnp.exp(logits - lse[..., None]) - picked.astype(jnp.float32))
+    return (*pull(dlogits), None)
+
+
+head_nll.defvjp(_head_nll_fwd, _head_nll_bwd)

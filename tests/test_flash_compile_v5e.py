@@ -209,3 +209,67 @@ def test_grouped_kernels_compile_at_the_cells_shapes(
             precision == "highest")
         assert need <= pallas_grouped._VMEM_MOST, kind
         assert limit is None or limit >= need, kind
+
+
+# (B, T, D, vocab rows held, tied head) of the three decoder cells' head
+HEAD_CELLS = {"conv_hybrid": (2, 8192, 2048, 8192, True),
+              "joyai": (2, 4096, 2048, 16160, False),
+              "hybrid": (1, 8192, 2688, 16384, False)}
+
+
+@pytest.mark.parametrize("cell", list(HEAD_CELLS))
+def test_head_loss_gradient_has_no_reduce_window(chip, cell):
+    """``head_nll``'s loss and three gradients at the trainer's precision:
+    three products and the recomputed logits' one, and no reduce-window.  As
+    a ``jax.checkpoint`` of ``log_softmax`` the backward's recomputed row
+    max at (2, 8192, 8192) became a reduce-window of 16,383 over the
+    vocabulary axis, 9 % of the conv hybrid cell's call (PERF.md, PR 35)."""
+    from pytorch_distributed_rnn_tpu.models.decoder_common import head_nll
+
+    batch, seq, width, vocab, tied = HEAD_CELLS[cell]
+
+    def on_chip(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def loss(h, norm, matrix, targets):
+        nll, _ = head_nll(
+            h, norm, matrix.T if tied else matrix, targets, 1e-5)
+        return jnp.mean(nll)
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            on_chip(batch, seq, width), on_chip(width),
+            on_chip(*((vocab, width) if tied else (width, vocab))),
+            on_chip(batch, seq, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "reduce-window(" not in text
+    assert len(re.findall(r" convolution\(", text)) == 4
+    # the logits once (B x T x vocab x 4 bytes), never two arrays of them
+    logits = 4 * batch * seq * vocab
+    assert logits <= compiled.memory_analysis().temp_size_in_bytes < (
+        1.5 * logits)
+
+
+@pytest.mark.parametrize("cell", [
+    "joyai_flash_train_t4096_1chip", "nemotron3_nano_train_t8192_1chip",
+    "lfm2_24b_train_t8192_1chip"])
+def test_epoch_program_compiles_from_shapes(chip, cell, monkeypatch):
+    """``scripts/compile_epoch_v5e.py`` at the tests' stand-in sizes: the
+    trainer's scanned epoch program with the flash and grouped kernels in
+    it, from shapes alone, and what it reports of the text (the windows
+    left are the group sizes' running sums and the scan's, a few numbers
+    each)."""
+    from pathlib import Path
+
+    monkeypatch.syspath_prepend(
+        str(Path(__file__).resolve().parents[1] / "scripts"))
+    import compile_epoch_v5e as script
+
+    loaded = script.load_cell(cell, tiny=True)
+    found = script.report(script.compile_epoch(loaded, chip))
+    assert found["custom_calls"] >= 60
+    assert 0 < found["argument_bytes"] < found["temp_bytes"] < 1e8
+    vocab = loaded["config"]["dataset"]["vocab_size"]
+    for shape in found["reduce_windows"]:
+        sizes = [int(n) for n in re.findall(r"\d+", shape.split("[")[1])]
+        assert vocab not in sizes and max(sizes) <= 8, shape
