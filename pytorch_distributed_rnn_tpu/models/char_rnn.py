@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from pytorch_distributed_rnn_tpu.obs import spans
 from pytorch_distributed_rnn_tpu.ops.initializers import linear_init
 from pytorch_distributed_rnn_tpu.ops.losses import (
     cross_entropy_loss,
@@ -96,7 +97,7 @@ class CharRNN:
         from pytorch_distributed_rnn_tpu.ops.rnn import dtype_of
 
         compute_dtype = dtype_of(self.precision)
-        with jax.named_scope("embed"):
+        with spans.scope("embed"):
             x = params["embed"][tokens]
         outputs, _ = stacked_rnn(
             params["rnn"], x, self.cell, unroll=self.unroll, impl=self.impl,

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pytorch_distributed_rnn_tpu.obs import spans
 from pytorch_distributed_rnn_tpu.ops.moe import (
     expert_mlp,
     held_experts_ffn,
@@ -190,7 +191,7 @@ def expert_layer(model, p, x):
         capacity=-(-capacity // 128) * 128,
         impl=resolve_attention_impl(model.impl))
     if "shared" in p:
-        with jax.named_scope("shared_expert"):
+        with spans.scope("shared_expert"):
             routed = expert_mlp(p["shared"], xt) + routed
     return routed.reshape(shape), counters
 
@@ -212,7 +213,7 @@ def moe_stats(counters) -> dict:
 # -- head and loss ------------------------------------------------------------
 
 def _head_logits(h, norm, head, eps):
-    with jax.named_scope("head"):
+    with spans.scope("head"):
         return (rms_norm(h, norm, eps) @ head).astype(jnp.float32)
 
 
@@ -235,11 +236,11 @@ def head_nll(h, norm, head, targets, eps):
 
 def _head_nll_fwd(h, norm, head, targets, eps):
     logits = _head_logits(h, norm, head, eps)
-    with jax.named_scope("loss"):
+    with spans.scope("loss"):
         lse = jax.nn.logsumexp(logits, axis=-1)
         nll = lse - jnp.take_along_axis(
             logits, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
-    hit = (jnp.argmax(logits, axis=-1) == targets).astype(jnp.float32)
+        hit = (jnp.argmax(logits, axis=-1) == targets).astype(jnp.float32)
     return (nll, hit), (h, norm, head, targets, lse)
 
 
@@ -251,7 +252,7 @@ def _head_nll_bwd(eps, residuals, cotangents):
     h, norm, head, lse = jax.lax.optimization_barrier((h, norm, head, lse))
     logits, pull = jax.vjp(
         functools.partial(_head_logits, eps=eps), h, norm, head)
-    with jax.named_scope("loss"):
+    with spans.scope("loss"):
         picked = jax.lax.broadcasted_iota(
             jnp.int32, logits.shape, logits.ndim - 1) == targets[..., None]
         dlogits = g[..., None] * (

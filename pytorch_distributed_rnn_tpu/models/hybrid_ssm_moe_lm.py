@@ -75,6 +75,7 @@ from pytorch_distributed_rnn_tpu.models.decoder_common import (
     rms_norm,
     rotary,
 )
+from pytorch_distributed_rnn_tpu.obs import spans
 from pytorch_distributed_rnn_tpu.ops.moe import expert_mlp
 from pytorch_distributed_rnn_tpu.ops.ssd import (
     causal_conv,
@@ -85,6 +86,9 @@ from pytorch_distributed_rnn_tpu.ops.ssd import (
 
 # what a device trace calls the attention kernels: gqa_flash_fwd / _dq / _dkv
 KERNEL_NAME = "gqa_flash"
+# the device scope a residual part lies under, by its pattern character
+PART_SCOPES = {"M": "mamba_mixer", "C": "short_conv_mixer", "*": "gqa",
+               "D": "dense_ffn", "E": "moe"}
 # hybrid_override_pattern as published: 23 M, 23 E, 6 *
 PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 # --ffn-dims SHARED,EXPERT where the flag is not given
@@ -412,36 +416,36 @@ class HybridSsmMoeLM:
         b, t, _ = u.shape
         inner, heads = self.inner_dim, self.mamba_heads
         groups, state = self.mamba_groups, self.state_dim
-        with jax.named_scope("mamba_in_proj"):
+        with spans.scope("mamba_in_proj"):
             zxbcdt = u @ p["w_in"]
             z, xbc, dt = jnp.split(
                 zxbcdt, [inner, zxbcdt.shape[-1] - heads], axis=-1)
-        with jax.named_scope("mamba_conv"):
+        with spans.scope("mamba_conv"):
             xbc = jax.nn.silu(causal_conv(xbc, p["conv_w"], p["conv_b"]))
             x, b_in, c_in = jnp.split(
                 xbc, [inner, inner + groups * state], axis=-1)
-        with jax.named_scope("ssd"):
+        with spans.scope("ssd"):
             y = ssd_chunked(
                 x.reshape(b, t, heads, self.mamba_head_dim),
                 jax.nn.softplus(dt + p["dt_bias"]), -exp(p["a_log"]),
                 b_in.reshape(b, t, groups, state),
                 c_in.reshape(b, t, groups, state), p["d"], self.chunk)
-        with jax.named_scope("mamba_gate_norm"):
+        with spans.scope("mamba_gate_norm"):
             y = gated_group_rms_norm(
                 y.reshape(b, t, inner), z, p["norm"], groups, self.norm_eps)
-        with jax.named_scope("mamba_out_proj"):
+        with spans.scope("mamba_out_proj"):
             return y @ p["w_out"]
 
     def _short_conv(self, p, u):
-        with jax.named_scope("short_conv_in_proj"):
+        with spans.scope("short_conv_in_proj"):
             b_gate, c_gate, h = jnp.split(u @ p["w_in"], 3, axis=-1)
-        with jax.named_scope("short_conv"):
+        with spans.scope("short_conv"):
             y = c_gate * causal_conv(b_gate * h, p["conv_w"])
-        with jax.named_scope("short_conv_out_proj"):
+        with spans.scope("short_conv_out_proj"):
             return y @ p["w_out"]
 
     def _dense(self, p, u):
-        with jax.named_scope("dense_ffn"):
+        with spans.scope("dense_ffn"):
             return expert_mlp(p, u)
 
     def _attention(self, p, u):
@@ -458,14 +462,14 @@ class HybridSsmMoeLM:
             x = (u @ p[f"w_{name}"]).reshape(b, t, count, width)
             if name in "qk":
                 if self.qk_norm:
-                    with jax.named_scope("qk_norm"):
+                    with spans.scope("qk_norm"):
                         x = rms_norm(x, p[f"{name}_norm"], self.norm_eps)
                 if self.rope_theta is not None:
-                    with jax.named_scope("rope"):
+                    with spans.scope("rope"):
                         x = rotary(x, self.rope_theta, "halves")
             return x.transpose(0, 2, 1, 3)
 
-        with jax.named_scope("gqa"):
+        with spans.scope("gqa"):
             q = heads("q", h)
             # query head i reads key-value head i // (h / kv)
             k, v = (jnp.repeat(heads(name, kv), h // kv, axis=1)
@@ -485,20 +489,24 @@ class HybridSsmMoeLM:
             return o.transpose(0, 2, 1, 3).reshape(b, t, h * width) @ p["w_o"]
 
     def _layer(self, kind, p, x):
-        """One residual part -> (x, the expert layer's counters or None)."""
-        u = rms_norm(x, p["norm"], self.norm_eps)
-        if kind == "E":
-            y, counters = expert_layer(self, p["mixer"], u)
-        else:
-            part = {"M": self._mamba, "C": self._short_conv,
-                    "*": self._attention, "D": self._dense}[kind]
-            y, counters = part(p["mixer"], u), None
-        return x + y, counters
+        """One residual part -> (x, the expert layer's counters or None).
+        The part lies whole under a scope named after its kind: what its
+        inner scopes leave out (the norm before it, the residual add)
+        reads under that name on the device."""
+        with spans.scope(PART_SCOPES[kind]):
+            u = rms_norm(x, p["norm"], self.norm_eps)
+            if kind == "E":
+                y, counters = expert_layer(self, p["mixer"], u)
+            else:
+                part = {"M": self._mamba, "C": self._short_conv,
+                        "*": self._attention, "D": self._dense}[kind]
+                y, counters = part(p["mixer"], u), None
+            return x + y, counters
 
     def hidden(self, params, tokens):
         """tokens (B, T) -> (the last layer's output before the final
         norm (B, T, D), one counters dict per expert layer)."""
-        with jax.named_scope("embed"):
+        with spans.scope("embed"):
             x = params["embed"][tokens]
         layer = (jax.checkpoint(self._layer, static_argnums=0)
                  if self.remat else self._layer)
@@ -527,8 +535,10 @@ class HybridSsmMoeLM:
         nll, hit = head_nll(
             h, params["final_norm"], self._head(params), tokens[:, 1:],
             self.norm_eps)
-        return jnp.mean(nll), {"correct": jnp.sum(jnp.mean(hit, axis=1)),
-                               **moe_stats(counters)}
+        with spans.scope("loss"):
+            return jnp.mean(nll), {
+                "correct": jnp.sum(jnp.mean(hit, axis=1)),
+                **moe_stats(counters)}
 
     def loss_and_metrics(self, params, batch, dropout_key=None, weights=None):
         """:meth:`loss_and_stats` over a ``(tokens, dummy labels)`` batch;

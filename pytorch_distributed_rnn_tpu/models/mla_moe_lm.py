@@ -55,6 +55,7 @@ from pytorch_distributed_rnn_tpu.models.decoder_common import (
     rms_norm,
     rotary,
 )
+from pytorch_distributed_rnn_tpu.obs import spans
 from pytorch_distributed_rnn_tpu.ops.moe import expert_mlp
 
 # what a device trace calls the attention kernels: mla_flash_fwd / _dq / _dkv
@@ -254,7 +255,7 @@ class MlaMoeLM:
 
         b, t, _ = x.shape
         h, nope, rope = self.num_heads, self.nope_dim, self.rope_dim
-        with jax.named_scope("mla"):
+        with spans.scope("mla"):
             c_q = rms_norm(x @ p["w_qa"], p["q_norm"], self.norm_eps)
             q = (c_q @ p["w_qb"]).reshape(b, t, h, nope + rope)
             kv_a = x @ p["w_kva"]
@@ -288,14 +289,19 @@ class MlaMoeLM:
 
     def _block(self, p, x):
         """One decoder block -> (x, the expert layer's counters or None)."""
-        x = x + self._attention(
-            p["attn"], rms_norm(x, p["attn_norm"], self.norm_eps))
-        y = rms_norm(x, p["ffn_norm"], self.norm_eps)
-        if "router" in p["ffn"]:
-            y, counters = expert_layer(self, p["ffn"], y)
-        else:
-            y, counters = expert_mlp(p["ffn"], y), None
-        return x + y, counters
+        # each residual part whole under its scope: the norm before it
+        # and the add after it read under the part's name on the device
+        with spans.scope("mla"):
+            x = x + self._attention(
+                p["attn"], rms_norm(x, p["attn_norm"], self.norm_eps))
+        routed = "router" in p["ffn"]
+        with spans.scope("moe" if routed else "dense_ffn"):
+            y = rms_norm(x, p["ffn_norm"], self.norm_eps)
+            if routed:
+                y, counters = expert_layer(self, p["ffn"], y)
+            else:
+                y, counters = expert_mlp(p["ffn"], y), None
+            return x + y, counters
 
     def _run_block(self, p, x):
         block = jax.checkpoint(self._block) if self.remat else self._block
@@ -304,7 +310,7 @@ class MlaMoeLM:
     def hidden(self, params, tokens):
         """tokens (B, T) -> (the last layer's output before the final
         norm (B, T, D), one counters dict per expert layer)."""
-        with jax.named_scope("embed"):
+        with spans.scope("embed"):
             x = params["embed"][tokens]
         counters = []
         for p in params["layers"]:
@@ -324,7 +330,7 @@ class MlaMoeLM:
         main model's hidden, ``next_tokens`` (B, T) the token AFTER each
         position."""
         p = params["mtp"]
-        with jax.named_scope("mtp"):
+        with spans.scope("mtp"):
             merged = jnp.concatenate(
                 [rms_norm(params["embed"][next_tokens], p["embed_norm"],
                           self.norm_eps),
@@ -349,7 +355,8 @@ class MlaMoeLM:
         h, counters = self.hidden(params, inputs)
         nll, hit = head_nll(
             h, params["final_norm"], params["head"], targets, self.norm_eps)
-        loss = jnp.mean(nll)
+        with spans.scope("loss"):
+            loss = jnp.mean(nll)
         if self.mtp_weight:
             h_mtp, c = self._mtp_hidden(params, h, targets)
             counters.append(c)
@@ -357,9 +364,11 @@ class MlaMoeLM:
             nll_mtp, _ = head_nll(
                 h_mtp[:, :-1], params["mtp"]["final_norm"], params["head"],
                 targets[:, 1:], self.norm_eps)
-            loss = loss + self.mtp_weight * jnp.mean(nll_mtp)
-        return loss, {"correct": jnp.sum(jnp.mean(hit, axis=1)),
-                      **moe_stats(counters)}
+            with spans.scope("loss"):
+                loss = loss + self.mtp_weight * jnp.mean(nll_mtp)
+        with spans.scope("loss"):
+            return loss, {"correct": jnp.sum(jnp.mean(hit, axis=1)),
+                          **moe_stats(counters)}
 
     def loss_and_metrics(self, params, batch, dropout_key=None, weights=None):
         """:meth:`loss_and_stats` over a ``(tokens, dummy labels)`` batch.

@@ -16,6 +16,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from pytorch_distributed_rnn_tpu.obs import spans
 from pytorch_distributed_rnn_tpu.ops.initializers import linear_init
 from pytorch_distributed_rnn_tpu.ops.losses import (
     classification_loss_and_metrics,
@@ -91,7 +92,7 @@ class MotionModel:
             compute_dtype=compute_dtype, remat=self.remat,
             dropout=self.dropout, dropout_key=dropout_key,
         )
-        with jax.named_scope("head"):
+        with spans.scope("head"):
             last = outputs[:, -1, :].astype(jnp.float32)
             return last @ params["fc"]["weight"].T + params["fc"]["bias"]
 

@@ -245,13 +245,19 @@ class MetricsRecorder:
                  sample_every: int = _DEFAULT_SAMPLE_EVERY,
                  flush_threshold: int = _FLUSH_THRESHOLD,
                  meta: dict | None = None,
-                 heartbeat_every_s: float = _DEFAULT_HEARTBEAT_S):
+                 heartbeat_every_s: float = _DEFAULT_HEARTBEAT_S,
+                 clock=time.perf_counter):
         if sample_every < 1:
             raise ValueError(
                 f"metrics sample cadence must be >= 1, got {sample_every}"
             )
         self.rank = int(rank)
         self.sample_every = int(sample_every)
+        # the monotonic clock every event's ``tm`` is read from (the
+        # meta head's too); a test hands in one it sets itself, so that
+        # what the ledger derives from the stamps does not depend on how
+        # loaded the machine is
+        self._clock = clock
         self.path = rank_suffixed(path, self.rank)
         self.path.parent.mkdir(parents=True, exist_ok=True)
         # lock-order: MetricsRecorder._io_lock -> MetricsRecorder._lock
@@ -282,7 +288,7 @@ class MetricsRecorder:
         # wall<->monotonic anchor: t and tm below describe the SAME
         # instant, so anchor + any event's tm reconstructs its wall time
         # on THIS rank's clock (obs/timeline.py aligns across ranks)
-        t_wall, t_mono = time.time(), time.perf_counter()
+        t_wall, t_mono = time.time(), clock()
         self._anchor = t_wall - t_mono
         # meta is the FIRST line, written synchronously: a sidecar that
         # exists always declares its schema, even if the run dies before
@@ -340,7 +346,7 @@ class MetricsRecorder:
         # describe the SAME instant (the invariant the timeline's
         # cross-rank alignment and any t - tm anchor math rest on)
         event = {
-            "kind": kind, "t": time.time(), "tm": time.perf_counter(),
+            "kind": kind, "t": time.time(), "tm": self._clock(),
             "rank": self.rank,
         }
         if "tm" in fields and "t" not in fields:

@@ -11,6 +11,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from pytorch_distributed_rnn_tpu.obs import spans
+
 
 def cross_entropy_loss(logits, labels, reduction: str = "mean"):
     """Softmax cross entropy on integer labels.
@@ -18,7 +20,7 @@ def cross_entropy_loss(logits, labels, reduction: str = "mean"):
     ``logits``: (N, C) float; ``labels``: (N,) int.  ``mean`` averages over
     the batch like torch's default ``CrossEntropyLoss``.
     """
-    with jax.named_scope("loss"):
+    with spans.scope("loss"):
         log_probs = jax.nn.log_softmax(logits, axis=-1)
         nll = -jnp.take_along_axis(
             log_probs, labels[:, None].astype(jnp.int32), axis=-1)[:, 0]

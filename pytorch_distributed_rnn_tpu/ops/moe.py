@@ -29,6 +29,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from pytorch_distributed_rnn_tpu.obs import spans
 from pytorch_distributed_rnn_tpu.ops.initializers import linear_init
 
 
@@ -341,7 +342,7 @@ def route_sigmoid_topk(router, bias, x, k: int, scale: float,
     ``eps`` is what a family adds to the sum it divides by (``lfm2_moe``:
     1e-6; 0 adds nothing to the program).  Scores are computed in f32
     whatever ``x`` is: a pick must not quantize."""
-    with jax.named_scope("router"):
+    with spans.scope("router"):
         scores = jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), router.astype(jnp.float32),
             preferred_element_type=jnp.float32))
@@ -426,7 +427,7 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
     count = experts["w_up"].shape[0]
     n, k = picked.shape
     num_picks = n * k
-    with jax.named_scope("experts"):
+    with spans.scope("experts"):
         local = picked.reshape(-1) - first
         # an absent pick sorts behind every held one
         group = jnp.where((local >= 0) & (local < count), local, count)

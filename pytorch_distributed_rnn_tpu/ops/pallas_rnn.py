@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from pytorch_distributed_rnn_tpu.obs import spans
+
 
 def _interpret() -> bool:
     """Pallas interpret mode on the CPU only, so the CPU test mesh runs
@@ -317,6 +319,7 @@ def _lstm_bwd_pallas(x_proj, h_all, c_all, h0, c0, w_hh_t,
             pltpu.VMEM((block_b, hidden), jnp.float32),
         ],
         interpret=_interpret(),
+        name="lstm_bwd",
     )(x_proj, h_all, c_all, c_all, dh_all, dh_T, dc_T, w_hh_t, h0, c0)
     return dx_proj, dh0, dc0
 
@@ -354,7 +357,7 @@ def _fused_bwd(block_b, residuals, cotangents):
     # weight grad as one big MXU matmul over all (t, b) at once: for the
     # LSTM the emitted gate cotangents ARE dx_proj, so
     # dw_hh = sum_t d_gates[t]^T h_prev[t]  ->  (4H, H), f32 accumulate
-    with jax.named_scope("recurrence_wgrad"):
+    with spans.scope("recurrence_wgrad"):
         h_prev_all = jnp.concatenate([h0[None], h_all[:-1]], axis=0)
         dw_hh = jnp.einsum(
             "tbg,tbh->gh", dx_proj, h_prev_all,
@@ -376,18 +379,13 @@ def lstm_layer_fused(params, x, h0=None, c0=None, *, block_b=None,
     """Drop-in replacement for ``ops.rnn.lstm_layer`` running the time loop
     as a fused Pallas kernel.  Same params (torch layout), same results.
 
-    ``scope`` names the XLA code around the kernels in a profiler trace.
-    The forward kernel names itself (``lstm_fwd``: ``jvp_lstm_fwd_.N`` on
-    the chip, ``lstm_fwd.N`` in evaluation and under ``jax.checkpoint``).
-    The backward kernel has no name and the call into it sits under no
-    scope, so it keeps the label of the name stack it is traced under
-    (``transpose_jvp___.N``): the chip's compiler names a Pallas call
-    after the INNERMOST scope, JAX wraps only the FIRST one in
-    ``transpose(jvp(``, and ``benchmarks/trace_reduce.py`` counts every
-    custom call whose label does not start with ``transpose_jvp`` as a
-    forward kernel - a bare ``lstm_bwd.N``, which any enclosing scope or
-    ``jax.checkpoint`` makes of a named one, would be counted twice
-    (tests/test_spans.py holds the rule; PERF.md section 7).
+    ``scope`` names the layer in a profiler trace: the XLA code around
+    the kernels and the kernels' calls lie under ``<scope>/recurrence``.
+    Both kernels name themselves, and the chip's compiler names a Pallas
+    call after the INNERMOST scope, so the device shows ``lstm_fwd.N``
+    and ``lstm_bwd.N`` in training, in evaluation and under
+    ``jax.checkpoint`` alike; ``benchmarks/trace_reduce.py`` tells the
+    two apart by those names (tests/test_spans.py holds the rule).
     """
     batch, _, _ = x.shape
     hidden = params["w_hh"].shape[1]
@@ -400,10 +398,10 @@ def lstm_layer_fused(params, x, h0=None, c0=None, *, block_b=None,
     from pytorch_distributed_rnn_tpu.ops.rnn import lstm_input_proj
 
     # to time-major after the shared one-big-matmul input projection
-    with jax.named_scope(f"{scope}/input_proj"):
+    with spans.scope(f"{scope}/input_proj"):
         x_proj = jnp.swapaxes(lstm_input_proj(params, x), 0, 1)  # (T, B, 4H)
 
-    with jax.named_scope(f"{scope}/recurrence"):
+    with spans.scope(f"{scope}/recurrence"):
         if batch_p != batch:
             x_proj = jnp.pad(x_proj, ((0, 0), (0, batch_p - batch), (0, 0)))
         if h0 is None:
@@ -414,10 +412,7 @@ def lstm_layer_fused(params, x, h0=None, c0=None, *, block_b=None,
             h0 = jnp.pad(h0, ((0, batch_p - batch), (0, 0)))
             c0 = jnp.pad(c0, ((0, batch_p - batch), (0, 0)))
         w_hh_t = params["w_hh"].T
-
-    h_all, (h_T, c_T) = fused_lstm_scan(x_proj, w_hh_t, h0, c0, block_b)
-
-    with jax.named_scope(f"{scope}/recurrence"):
+        h_all, (h_T, c_T) = fused_lstm_scan(x_proj, w_hh_t, h0, c0, block_b)
         outputs = jnp.swapaxes(h_all, 0, 1)[:batch]
         return outputs, (h_T[:batch], c_T[:batch])
 
@@ -558,6 +553,7 @@ def _gru_bwd_pallas(x_proj, h_all, h0, w_hh_t, b_hh, dh_all, dh_T, *,
         ],
         scratch_shapes=[pltpu.VMEM((block_b, hidden), jnp.float32)],
         interpret=_interpret(),
+        name="gru_bwd",
     )(x_proj, h_all, dh_all, dh_T, w_hh_t, b_hh, h0)
 
 
@@ -582,7 +578,7 @@ def _gru_bwd(block_b, residuals, cotangents):
         x_proj, h_all, h0, w_hh_t, b_hh, dh_all, dh_T, block_b=block_b
     )
     # weight/bias grads as big MXU matmuls over all (t, b) at once
-    with jax.named_scope("recurrence_wgrad"):
+    with spans.scope("recurrence_wgrad"):
         h_prev_all = jnp.concatenate([h0[None], h_all[:-1]], axis=0)
         dw_hh = jnp.einsum("tbg,tbh->gh", dhgates, h_prev_all)  # (3H, H)
         db_hh = jnp.sum(dhgates, axis=(0, 1))[None]             # (1, 3H)
@@ -596,9 +592,8 @@ def gru_layer_fused(params, x, h0=None, *, block_b=None,
                     scope: str = "gru_layer"):
     """Drop-in replacement for ``ops.rnn.gru_layer`` running the time loop
     as a fused Pallas kernel.  Same params (torch layout, gate order
-    r, z, n), same results.  ``scope`` as in :func:`lstm_layer_fused`:
-    the call into the kernels (``gru_fwd`` and its unnamed backward)
-    stays outside it."""
+    r, z, n), same results.  ``scope`` as in :func:`lstm_layer_fused`;
+    the kernels are ``gru_fwd`` and ``gru_bwd``."""
     batch, _, _ = x.shape
     hidden = params["w_hh"].shape[1]
     dtype = x.dtype
@@ -611,10 +606,10 @@ def gru_layer_fused(params, x, h0=None, *, block_b=None,
     from pytorch_distributed_rnn_tpu.ops.rnn import gru_input_proj
 
     # shared input projection (b_ih only; b_hh joins inside the kernel)
-    with jax.named_scope(f"{scope}/input_proj"):
+    with spans.scope(f"{scope}/input_proj"):
         x_proj = jnp.swapaxes(gru_input_proj(params, x), 0, 1)  # (T, B, 3H)
 
-    with jax.named_scope(f"{scope}/recurrence"):
+    with spans.scope(f"{scope}/recurrence"):
         if batch_p != batch:
             x_proj = jnp.pad(x_proj, ((0, 0), (0, batch_p - batch), (0, 0)))
         if h0 is None:
@@ -622,8 +617,5 @@ def gru_layer_fused(params, x, h0=None, *, block_b=None,
         if batch_p != batch:
             h0 = jnp.pad(h0, ((0, batch_p - batch), (0, 0)))
         w_hh_t, b_hh = params["w_hh"].T, params["b_hh"][None]
-
-    h_all, h_T = fused_gru_scan(x_proj, w_hh_t, b_hh, h0, block_b)
-
-    with jax.named_scope(f"{scope}/recurrence"):
+        h_all, h_T = fused_gru_scan(x_proj, w_hh_t, b_hh, h0, block_b)
         return jnp.swapaxes(h_all, 0, 1)[:batch], h_T[:batch]

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from pytorch_distributed_rnn_tpu.obs import spans
 from pytorch_distributed_rnn_tpu.ops.initializers import lstm_uniform
 
 
@@ -113,10 +114,10 @@ def lstm_layer(params, x, h0=None, c0=None, *, unroll: int = 1,
     hidden = params["w_hh"].shape[1]
     dtype = x.dtype
 
-    with jax.named_scope(f"{scope}/input_proj"):
+    with spans.scope(f"{scope}/input_proj"):
         x_proj = lstm_input_proj(params, x)
 
-    with jax.named_scope(f"{scope}/recurrence"):
+    with spans.scope(f"{scope}/recurrence"):
         w_hh_t = params["w_hh"].T  # (H, 4H)
 
         # carry lives in f32 regardless of compute dtype (lstm_step contract)
@@ -171,10 +172,10 @@ def gru_layer(params, x, h0=None, *, unroll: int = 1,
     hidden = params["w_hh"].shape[1]
     dtype = x.dtype
 
-    with jax.named_scope(f"{scope}/input_proj"):
+    with spans.scope(f"{scope}/input_proj"):
         x_proj = gru_input_proj(params, x)
 
-    with jax.named_scope(f"{scope}/recurrence"):
+    with spans.scope(f"{scope}/recurrence"):
         w_hh_t = params["w_hh"].T  # (H, 3H)
         b_hh = params["b_hh"]
 
@@ -364,7 +365,7 @@ def head_logits(head, h):
     by the char/MoE model families and the serving adapters so batched
     serving can never drift from single-request ``generate`` numerics.
     ``head``: ``{"weight", "bias"}``; ``h``: (..., H) -> (..., vocab)."""
-    with jax.named_scope("head"):
+    with spans.scope("head"):
         return h.astype(jnp.float32) @ head["weight"].T + head["bias"]
 
 
@@ -373,7 +374,7 @@ def interlayer_dropout(out, dropout_key, dropout: float):
     by the unsharded stack above and the sp relay stacks
     (``parallel/sp.py``) - its placement/scaling being identical across
     paths is a tested contract.  Returns ``(masked_out, next_key)``."""
-    with jax.named_scope("dropout"):
+    with spans.scope("dropout"):
         dropout_key, sub = jax.random.split(dropout_key)
         keep = 1.0 - dropout
         mask = jax.random.bernoulli(sub, keep, out.shape)

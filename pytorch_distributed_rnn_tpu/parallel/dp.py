@@ -28,6 +28,7 @@ import optax
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
+from pytorch_distributed_rnn_tpu.obs import spans
 from pytorch_distributed_rnn_tpu.parallel.collectives import (
     broadcast_from,
     pmean_tree,
@@ -68,7 +69,7 @@ def distributed_optimizer(optimizer, axis: str = "dp"):
         return optimizer.init(params)
 
     def update(grads, state, params=None):
-        with jax.named_scope("grad_reduce"):
+        with spans.scope("grad_reduce"):
             grads = pmean_tree(grads, axis)
         return optimizer.update(grads, state, params)
 
@@ -117,9 +118,9 @@ def _make_grad_step(loss_and_metrics, optimizer, axis: str, sync: str,
             loss_and_metrics, has_aux=True
         )(params, batch, *extra)
         if sync == "backward":
-            with jax.named_scope("grad_reduce"):
+            with spans.scope("grad_reduce"):
                 grads = pmean_tree(grads, axis)
-        with jax.named_scope("optimizer"):
+        with spans.scope("optimizer"):
             updates, opt_state = opt.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
         return params, opt_state, loss, metrics
@@ -216,7 +217,8 @@ def make_spmd_idx_train_step(
         check_vma=False,
     )
     def train_step(params, opt_state, features, labels, idx, *extra):
-        batch = (features[idx], labels[idx])
+        with spans.scope("input_gather"):
+            batch = (features[idx], labels[idx])
         params, opt_state, loss, metrics = grad_step(
             params, opt_state, batch, *extra
         )
@@ -273,7 +275,8 @@ def make_spmd_epoch_fn(
             params, opt_state = carry
             idx = step_in[0] if with_key else step_in
             extra = (step_in[1],) if with_key else ()
-            batch = (features[idx], labels[idx])
+            with spans.scope("input_gather"):
+                batch = (features[idx], labels[idx])
             params, opt_state, loss, metrics = grad_step(
                 params, opt_state, batch, *extra
             )
@@ -335,7 +338,8 @@ def make_spmd_run_fn(
             params, opt_state = carry
             idx, w = step_in[0], step_in[1]
             extra = (step_in[2],) if with_key else ()
-            batch = (features[idx], labels[idx])
+            with spans.scope("input_gather"):
+                batch = (features[idx], labels[idx])
             params, opt_state, loss, metrics = grad_step(
                 params, opt_state, batch, w, *extra
             )

@@ -44,6 +44,8 @@ import optax
 from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from pytorch_distributed_rnn_tpu.obs import spans
+
 
 class ShardedUpdate:
     """Padded-ravel bookkeeping + the sharded update body for ONE
@@ -91,7 +93,7 @@ class ShardedUpdate:
         the trailing allgather."""
         # named for the profiler trace: grad_reduce / optimizer /
         # param_gather are the three parts of the sharded schedule
-        with jax.named_scope("grad_reduce"):
+        with spans.scope("grad_reduce"):
             flat_g, _ = ravel_pytree(grads)
             flat_g = jnp.pad(flat_g, (0, self.padded - self.size))
             # psum_scatter(tiled): this shard's slice of the summed
@@ -107,7 +109,7 @@ class ShardedUpdate:
                 )
                 g_shard = jnp.where(
                     bad > 0, jnp.full_like(g_shard, jnp.nan), g_shard)
-        with jax.named_scope("optimizer"):
+        with spans.scope("optimizer"):
             flat_p, unravel = ravel_pytree(params)
             r = jax.lax.axis_index(self.axis)
             p_shard = jax.lax.dynamic_slice(
@@ -117,7 +119,7 @@ class ShardedUpdate:
             updates, opt_state = self.optimizer.update(
                 g_shard, opt_state, p_shard)
             p_shard = optax.apply_updates(p_shard, updates)
-        with jax.named_scope("param_gather"):
+        with spans.scope("param_gather"):
             flat_new = jax.lax.all_gather(p_shard, self.axis, tiled=True)
             return unravel(flat_new[: self.size]), opt_state
 

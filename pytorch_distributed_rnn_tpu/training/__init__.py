@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import json
 import logging
+from pathlib import Path
 
 from pytorch_distributed_rnn_tpu.training.base import Trainer
 from pytorch_distributed_rnn_tpu.training.distributed import (
@@ -263,6 +264,13 @@ def _run_trainer(args, trainer_class, model, datasets):
         recorder.close()
         if plane is not None:
             plane.close()
+    if profile_dir and jax.process_index() == 0:
+        # what joins the trace's instructions with the program's scopes
+        # (scripts/device_time_by_scope.py)
+        from pytorch_distributed_rnn_tpu.obs import spans
+
+        Path(profile_dir).mkdir(parents=True, exist_ok=True)
+        spans.write_program_scopes(Path(profile_dir) / "program_scopes.json")
     history = {
         "train_history": train_history,
         "validation_history": validation_history,

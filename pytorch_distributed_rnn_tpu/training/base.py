@@ -34,6 +34,7 @@ import optax
 
 from pytorch_distributed_rnn_tpu.data.loader import DataLoader
 from pytorch_distributed_rnn_tpu.obs.recorder import NULL_RECORDER
+from pytorch_distributed_rnn_tpu.obs import spans
 from pytorch_distributed_rnn_tpu.obs.spans import span
 from pytorch_distributed_rnn_tpu.data.prefetch import prefetch
 from pytorch_distributed_rnn_tpu.data.sampler import DistributedSampler
@@ -53,6 +54,24 @@ def _fence(value):
     zero-overhead guard test can count fences (disabled telemetry must
     never add a per-step host sync)."""
     jax.block_until_ready(value)
+
+
+def _gather(features, labels, idx):
+    """A step's batch, gathered inside the program from the resident
+    training set."""
+    with spans.scope("input_gather"):
+        return features[idx], labels[idx]
+
+
+def _launch(launch_span, jitted, *args):
+    """Call a jitted program inside its launch span.  The launch under
+    which JAX compiled (warm-up, a new shape) registers the program for
+    ``spans.program_scopes``; every other one tests that one flag."""
+    with launch_span:
+        out = jitted(*args)
+    if launch_span.compiled:
+        spans.register_program(jitted, args)
+    return out
 
 
 def _correct_count(value) -> int:
@@ -320,13 +339,16 @@ class Trainer:
         dropout key threaded in train mode only (evaluation passes none)
         and folded per rank.  ``weights``: the whole-run program's 0/1
         mask over its zero-padded batches; all-ones weights give the
-        unweighted loss."""
+        unweighted loss.  The loss passes ``spans.stamp``: every program
+        the trainer launches calls this hook, so every one carries the
+        scope layout it was traced under in its compile-cache key."""
         if key is not None and self._dropout > 0.0:
             key = self._fold_rank(key)
         else:
             key = None
-        return self.model.loss_and_metrics(
+        loss, metrics = self.model.loss_and_metrics(
             params, batch, dropout_key=key, weights=weights)
+        return spans.stamp(loss), metrics
 
     def _make_grad_step(self, loss_and_metrics):
         """The shared grad+update body: ``step(params, opt_state, batch,
@@ -345,7 +367,7 @@ class Trainer:
             (loss, metrics), grads = jax.value_and_grad(
                 loss_and_metrics, has_aux=True
             )(params, batch, *extra)
-            with jax.named_scope("optimizer"):
+            with spans.scope("optimizer"):
                 updates, opt_state = self.optimizer.update(
                     grads, opt_state, params)
                 params = optax.apply_updates(params, updates)
@@ -414,7 +436,7 @@ class Trainer:
                 body, (zeros_g, jnp.zeros(()), zeros_m), xs
             )
             grads = jax.tree.map(lambda g: g / k, g_sum)
-            with jax.named_scope("optimizer"):
+            with spans.scope("optimizer"):
                 updates, opt_state = self.optimizer.update(
                     grads, opt_state, params)
                 params = optax.apply_updates(params, updates)
@@ -444,7 +466,7 @@ class Trainer:
 
         def train_step(params, opt_state, features, labels, idx, *extra):
             return grad_step(
-                params, opt_state, (features[idx], labels[idx]), *extra
+                params, opt_state, _gather(features, labels, idx), *extra
             )
 
         return train_step
@@ -466,7 +488,7 @@ class Trainer:
                 idx = step_in[0] if with_key else step_in
                 extra = (step_in[1],) if with_key else ()
                 params, opt_state, loss, metrics = grad_step(
-                    *carry, (features[idx], labels[idx]), *extra
+                    *carry, _gather(features, labels, idx), *extra
                 )
                 return (params, opt_state), (loss, metrics)
 
@@ -496,7 +518,7 @@ class Trainer:
                 idx, w = step_in[0], step_in[1]
                 extra = (step_in[2],) if with_key else ()
                 params, opt_state, loss, metrics = grad_step(
-                    *carry, (features[idx], labels[idx]), w, *extra
+                    *carry, _gather(features, labels, idx), w, *extra
                 )
                 return (params, opt_state), (loss, metrics["correct"])
 
@@ -553,15 +575,19 @@ class Trainer:
             out_shardings=self._data_sharding(),
         )
 
-    def _device_dropout_keys(self, epoch: int, full: int, remainder: bool):
+    def _device_dropout_keys(self, epoch: int, full: int, remainder: bool,
+                             launch_span=None):
         """``(key_mat, remainder_key)`` of :meth:`_build_key_fn` for one
         epoch, derived deterministically from (seed, epoch, batch index)
         so the batched scan path and the per-batch paths produce
-        identical numerics."""
+        identical numerics.  ``launch_span``: the span to launch the
+        program in (:func:`_launch`)."""
         if self._key_fn is None:
             self._key_fn = self._build_key_fn()
-        return self._key_fn(
-            self._dropout_key, np.uint32(epoch), full, remainder)
+        args = (self._dropout_key, np.uint32(epoch), full, remainder)
+        if launch_span is None:
+            return self._key_fn(*args)
+        return _launch(launch_span, self._key_fn, *args)
 
     def _epoch_dropout_keys(self, epoch: int, num_batches: int):
         """All of an epoch's keys as one host matrix, for the loops that
@@ -990,11 +1016,11 @@ class Trainer:
         w_mat = np.stack(w_rows)
         extra = (np.concatenate(key_rows),) if self._dropout > 0.0 else ()
 
-        with span("epoch.launch", program="train_run"):
-            self.params, self.opt_state, losses, correct = self._run_fn(
-                self.params, self.opt_state, features, labels, idx_mat,
-                w_mat, *extra,
-            )
+        self.params, self.opt_state, losses, correct = _launch(
+            span("epoch.launch", program="train_run"), self._run_fn,
+            self.params, self.opt_state, features, labels, idx_mat, w_mat,
+            *extra,
+        )
         # the fused run's ONE host visit: the guard decides here - the
         # in-program apply_if_finite already rejected every non-finite
         # update, so the late check only delays the abort, never
@@ -1159,11 +1185,12 @@ class Trainer:
                     remainder = self._put_indices(remainder)
         keys = None
         if self._dropout > 0.0:
-            with span("epoch.dropout_keys", self.recorder):
-                if scan:
-                    keys = self._device_dropout_keys(
-                        epoch, len(full), remainder is not None)
-                else:
+            keys_span = span("epoch.dropout_keys", self.recorder)
+            if scan:
+                keys = self._device_dropout_keys(
+                    epoch, len(full), remainder is not None, keys_span)
+            else:
+                with keys_span:
                     keys = self._epoch_dropout_keys(epoch, len(batches))
         return _EpochInputs(epoch, batches, idx_mat, remainder, keys)
 
@@ -1307,25 +1334,18 @@ class Trainer:
             epoch_extra, step_extra = (
                 [(key,) for key in keys] if keys is not None else [(), ()])
             launched = []
-            with span("epoch.launch", program="train_epoch"):
-                (
-                    self.params,
-                    self.opt_state,
-                    loss_sum,
-                    metrics_sum,
-                ) = self._epoch_fn(
-                    self.params, self.opt_state, features, labels,
-                    inputs.idx_mat, *epoch_extra,
-                )
+            self.params, self.opt_state, loss_sum, metrics_sum = _launch(
+                span("epoch.launch", program="train_epoch"), self._epoch_fn,
+                self.params, self.opt_state, features, labels,
+                inputs.idx_mat, *epoch_extra,
+            )
             launched.append(("train_epoch", loss_sum, metrics_sum))
             if inputs.remainder is not None:
-                with span("epoch.launch", program="train_step"):
-                    (
-                        self.params, self.opt_state, loss, metrics,
-                    ) = self._idx_step_fn(
-                        self.params, self.opt_state, features, labels,
-                        inputs.remainder, *step_extra,
-                    )
+                self.params, self.opt_state, loss, metrics = _launch(
+                    span("epoch.launch", program="train_step"),
+                    self._idx_step_fn, self.params, self.opt_state,
+                    features, labels, inputs.remainder, *step_extra,
+                )
                 launched.append(("train_step", loss, metrics))
             self._launch_validation()
             if self._epoch + 1 < self._epochs:
@@ -1554,9 +1574,10 @@ class Trainer:
                 batch = self._prepare_batch(features, labels)
             cached = (dataset, batch)
             self._eval_data_cache[key] = cached
-        with span("eval.launch", self.recorder, cat="eval",
-                  program="eval_step"):
-            loss, metrics = self._eval_step_fn(self.params, cached[1])
+        loss, metrics = _launch(
+            span("eval.launch", self.recorder, cat="eval",
+                 program="eval_step"),
+            self._eval_step_fn, self.params, cached[1])
         return loss, metrics["correct"]
 
     def _launch_validation(self):

@@ -44,8 +44,13 @@ def train_set():
 def _write_metrics(path, rank=0, step_s=0.01, steps=8, memory=400.0,
                    duration=2.0, sample_every=2):
     """A synthetic sidecar through the REAL recorder (the writer path is
-    part of what these tests pin)."""
-    rec = MetricsRecorder(path, rank=rank, sample_every=sample_every)
+    part of what these tests pin), on a clock the run sets itself: a step
+    starts where the last one ended, so the run's wall - which the ledger
+    reads off the stamps (``badput_frac``, ``data_wait_frac``) - is
+    ``steps * step_s`` however loaded the machine is."""
+    now = [100.0]
+    rec = MetricsRecorder(path, rank=rank, sample_every=sample_every,
+                          clock=lambda: now[0])
     for i in range(steps):
         rec.record(
             "step", step=i, epoch=0, loss=2.0 - 0.1 * i,
@@ -53,8 +58,9 @@ def _write_metrics(path, rank=0, step_s=0.01, steps=8, memory=400.0,
             data_wait_s=step_s / 10,
             fenced_s=step_s if rec.is_sample_step(i) else None,
         )
+        now[0] += step_s
     rec.record("epoch", epoch=0, steps=steps, loss=1.5, acc=0.5,
-               wall_s=steps * step_s, path="step")
+               wall_s=steps * step_s, path="step", tm=100.0)
     rec.record("run_summary", memory_mb=memory, duration_s=duration,
                device_peaks_mb={}, steps=steps, epochs=1,
                nan_skipped=0, faults_fired={})

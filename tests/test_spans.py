@@ -538,28 +538,30 @@ class TestDeviceNames:
                       "jvp(lstm_layer1/input_proj)", "jvp(dropout)",
                       "jvp(head)", "jvp(loss)",
                       "transpose(jvp(lstm_layer0/input_proj))",
-                      "transpose(jvp(recurrence_wgrad))",
+                      "transpose(jvp(lstm_layer0/recurrence))/"
+                      "recurrence_wgrad",
                       "transpose(jvp(head))", "optimizer"):
             assert any(stack.startswith(scope) for stack in seen), scope
 
-    def test_the_forward_kernel_has_a_name_and_no_kernel_a_scope(
+    def test_both_kernels_have_a_name_inside_their_layer_s_scope(
             self, stacks):
         """The chip's compiler names a Pallas call after the innermost
-        scope: ``jvp(lstm_fwd)`` -> ``jvp_lstm_fwd_``.  The backward
-        kernel keeps the bare ``transpose(jvp())`` ->
-        ``transpose_jvp___`` it has on the parent: with a name or a layer
-        scope around the call it could read ``lstm_bwd``, which the
-        benchmark's forward pattern matches too
-        (benchmarks/tests/test_span_metrics.py)."""
+        scope, which is the kernel's own ``name=``: ``lstm_fwd.N`` and
+        ``lstm_bwd.N`` on the device (compiled for a described v5e by
+        hand, PR 36), which ``benchmarks/trace_reduce.py`` tells apart by
+        those names.  The layer's scope round the calls is what
+        ``spans.classify`` reads their phase from."""
         kernels = sorted(stack for name, stack in stacks
                          if name == "pallas_call")
-        assert kernels == (["jvp(lstm_fwd)"] * 2
-                           + ["transpose(jvp())"] * 2)
+        assert kernels == [
+            "jvp(lstm_layer0/recurrence)/lstm_fwd",
+            "jvp(lstm_layer1/recurrence)/lstm_fwd",
+            "transpose(jvp(lstm_layer0/recurrence))/lstm_bwd",
+            "transpose(jvp(lstm_layer1/recurrence))/lstm_bwd"]
 
-    def test_under_remat_no_kernel_label_ends_in_a_backward_name(self):
+    def test_under_remat_the_kernels_keep_their_names(self):
         """``jax.checkpoint`` puts its own wrappers FIRST in the name
-        stack, so a kernel's name comes last and alone: fine for
-        ``lstm_fwd``, and why the backward kernel has none."""
+        stack, so a kernel's name still comes last."""
         from pytorch_distributed_rnn_tpu.ops.losses import cross_entropy_loss
 
         x = np.zeros((8, 16, 9), np.float32)
@@ -571,12 +573,12 @@ class TestDeviceNames:
             lambda p: cross_entropy_loss(model.apply(p, x), y)))(params)
         kernels = [s for n, s in name_stacks(closed.jaxpr)
                    if n == "pallas_call"]
-        # forward, rematerialized forward (`lstm_fwd.N` on the chip),
-        # backward (`checkpoint.N` there, as on the parent)
+        # forward, rematerialized forward, backward
         assert kernels == [
-            "jvp(lstm_fwd)",
-            "transpose(jvp(jvp()))/rematted_computation/lstm_fwd",
-            "transpose(jvp(jvp()))"]
+            "jvp(lstm_layer0/recurrence)/lstm_fwd",
+            "transpose(jvp(jvp()))/rematted_computation/lstm_layer0/"
+            "recurrence/lstm_fwd",
+            "transpose(jvp(jvp()))/lstm_layer0/recurrence/lstm_bwd"]
 
     def test_gru_kernels_and_the_scan_path_are_named_too(self):
         from pytorch_distributed_rnn_tpu.ops.losses import cross_entropy_loss
@@ -584,7 +586,8 @@ class TestDeviceNames:
         x = np.zeros((8, 16, 9), np.float32)
         y = np.zeros((8,), np.int32)
         for impl, expected in (
-            ("fused", ["jvp(gru_fwd)", "transpose(jvp())"]),
+            ("fused", ["jvp(gru_layer0/recurrence)/gru_fwd",
+                       "transpose(jvp(gru_layer0/recurrence))/gru_bwd"]),
             ("scan", []),
         ):
             model = MotionModel(input_dim=9, hidden_dim=8, layer_dim=1,
