@@ -198,7 +198,8 @@ def expert_layer(model, p, x):
 
 def moe_stats(counters) -> dict:
     """The expert layers' routing counters, summed over layers
-    (``moe_rows_max``: the busiest held expert of any layer)."""
+    (``moe_rows_max``: the busiest held expert of any layer;
+    ``moe_overflows``: the layers that computed every pick)."""
     if not counters:
         return {}
     return dict(
@@ -207,6 +208,7 @@ def moe_stats(counters) -> dict:
         moe_rows_sum=sum(c["rows_sum"] for c in counters),
         moe_picks_absent=sum(c["picks_absent"] for c in counters),
         moe_picks_dropped=sum(c["picks_dropped"] for c in counters),
+        moe_overflows=sum(c["overflows"] for c in counters),
     )
 
 

@@ -417,13 +417,18 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
     fits - ALWAYS ``capacity`` rows of work, the spare ones zeros, so that
     the time is the same for every routing that fits - and the held ones
     among ALL ``N * k`` picks when it does not: a ``lax.cond`` between two
-    programs of the same mathematics.
+    programs of the same mathematics.  Differentiated, the every-pick side
+    saves its inputs alone (``x``, the sort's token numbers and weights,
+    the group ends, ``experts``: arrays made outside the ``cond``) and
+    runs its forward again inside its backward, so the side that fits
+    writes no placeholder of ``N * k`` rows for it.
 
     Returns ``(y (N, D), counters)``; the counters are f32 scalars:
     ``rows_max`` / ``rows_sum`` (rows the busiest held expert / all held
     experts received), ``picks_absent``, ``picks_dropped`` (held picks
     that were not computed: 0 by construction, counted from the group
-    sizes the products really ran with)."""
+    sizes the products really ran with), ``overflows`` (1 where the held
+    picks did not fit ``capacity`` and every pick was computed)."""
     count = experts["w_up"].shape[0]
     n, k = picked.shape
     num_picks = n * k
@@ -440,7 +445,7 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
         weight_of = weights.reshape(-1)[order]
         ends = jnp.cumsum(rows_per_expert)
 
-        def compute(rows: int, pad: bool, impl: str = impl):
+        def compute(rows: int, pad: bool, impl: str = impl, experts=experts):
             tokens = token_of[:rows]
             # group sizes as far as `rows` reaches (all of them whenever
             # this branch is the one taken)
@@ -465,13 +470,28 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
             # stays on XLA's kernel: a second set of this repo's kernels at
             # its row count doubles what a start traces, lowers and loads
             # (+1.9 s of the hybrid cell's 22 s warm set-up: PERF.md, PR 33)
+            def every_pick(experts):
+                return compute(
+                    num_picks, pad=False, impl="dense", experts=experts)
+
+            # ... and keeps no residuals of its own.  A `cond` saves the
+            # union of its branches' residuals, and the branch that runs
+            # writes zeros in the other's places: as a plain closure this
+            # one had the taken branch write 3.7 GB of zeros a layer in the
+            # conv hybrid cell (PERF.md, PR 37).  Under `jax.checkpoint` it
+            # saves its inputs and runs its forward again in its backward.
+            # The weights are its argument so that it lists them as the
+            # taken branch does (`w_gate` before `w_up`, one type: places
+            # are shared by type, in order): XLA then hands them through
+            # the `conditional` where it would copy them
             y, computed = jax.lax.cond(
                 total <= capacity, lambda: compute(capacity, pad=True),
-                lambda: compute(num_picks, pad=False, impl="dense"))
+                lambda: jax.checkpoint(every_pick)(experts))
     f32 = jnp.float32
     return y, {
         "rows_max": jnp.max(rows_per_expert).astype(f32),
         "rows_sum": total.astype(f32),
         "picks_absent": (num_picks - total).astype(f32),
         "picks_dropped": (total - computed).astype(f32),
+        "overflows": (total > capacity).astype(f32),
     }

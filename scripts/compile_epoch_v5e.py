@@ -6,8 +6,11 @@ a DESCRIBED TPU v5e: no chip, nothing runs, no array is made.
         [--cell lfm2_24b_train_t8192_1chip] [--tiny] [--text epoch.txt]
 
 Prints one JSON line: XLA's own count of the program's bytes
-(``compiled.memory_analysis()``), its custom calls, and every
-``reduce-window`` in its text by the shape it writes.  A reduce-window over a
+(``compiled.memory_analysis()``), its custom calls, every ``reduce-window``
+in its text by the shape it writes, and what the expert layers' taken
+``conditional`` branch writes for the branch it did not take
+(``taken_branch_fill``: PR 37 left two vectors and one ``x``-sized array a
+layer where 2.1 - 3.7 GB of zeros were).  A reduce-window over a
 large array is worth a look: PR 35 found the conv hybrid cell's largest
 operation (9 % of a call) to be the head loss's recomputed row max, lowered
 to a window of 16,383 over the vocabulary axis of (2, 8192, 8192) logits.
@@ -98,6 +101,38 @@ def compile_epoch(cell: dict, device_sharding):
         pallas_attention._interpret, pallas_grouped._interpret = interpret
 
 
+def taken_branch_fill(text: str) -> dict:
+    """What the expert layers' taken branch writes beside its products: in
+    every ``conditional`` of ``text``, the ``broadcast``s and ``copy``s at
+    the top of the branch computation that holds the grouped kernels
+    (``moe_gmm``), by opcode and shape.  A ``cond``'s branch fills the
+    other branch's residual places with ``broadcast``s of zero (rows ``N *
+    k``, were that branch to save its intermediates) and copies a
+    pass-through array it cannot hand on (PERF.md, PR 37); its own row mask
+    (``pred[capacity, D]``) is among them."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name and not line.startswith("}"):
+            bodies[name].append(line)
+    found = {}
+    for branches in re.findall(
+            r" conditional\(.*branch_computations=\{([^}]*)\}", text):
+        for branch in branches.split(","):
+            lines = bodies.get(branch.strip(), [])
+            if not any("moe_gmm" in line for line in lines):
+                continue
+            for shape, opcode in re.findall(
+                    r"= (\w+\[[\d,]*\])\S* (broadcast|copy)\(",
+                    "\n".join(lines)):
+                key = f"{opcode} {shape}"
+                found[key] = found.get(key, 0) + 1
+    return found
+
+
 def report(compiled) -> dict:
     text = compiled.as_text()
     memory = compiled.memory_analysis()
@@ -109,6 +144,7 @@ def report(compiled) -> dict:
         "temp_bytes": memory.temp_size_in_bytes,
         "custom_calls": text.count('custom_call_target="tpu_custom_call"'),
         "reduce_windows": windows,
+        "taken_branch_fill": taken_branch_fill(text),
     }
 
 

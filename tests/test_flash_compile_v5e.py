@@ -258,7 +258,9 @@ def test_epoch_program_compiles_from_shapes(chip, cell, monkeypatch):
     trainer's scanned epoch program with the flash and grouped kernels in
     it, from shapes alone, and what it reports of the text (the windows
     left are the group sizes' running sums and the scan's, a few numbers
-    each)."""
+    each; the expert layers' taken branch writes no matrix of ``N * k`` rows
+    for the branch that computes every pick, and copies no expert weight:
+    both branches list their residuals in one order, ``ops/moe.py``)."""
     from pathlib import Path
 
     monkeypatch.syspath_prepend(
@@ -273,3 +275,16 @@ def test_epoch_program_compiles_from_shapes(chip, cell, monkeypatch):
     for shape in found["reduce_windows"]:
         sizes = [int(n) for n in re.findall(r"\d+", shape.split("[")[1])]
         assert vocab not in sizes and max(sizes) <= 8, shape
+    fill = {key: [int(n) for n in re.findall(r"\d+", key.split("[")[1])]
+            for key in found["taken_branch_fill"]}
+    # the sort's token numbers, a place both branches' residual lists hold
+    picks = [dims[0] for key, dims in fill.items()
+             if key.startswith("broadcast s32[") and len(dims) == 1]
+    assert picks and max(picks) % loaded["config"]["dataset"]["seq_length"] == 0
+    cli = loaded["config"]["cli"]
+    held = int(cli[cli.index("--experts-held") + 1].split(":")[1])
+    for key, dims in fill.items():
+        assert not (len(dims) > 1 and dims[0] == max(picks)), key
+        # (held, D, F): an expert weight
+        assert not (key.startswith("copy") and len(dims) == 3
+                    and dims[0] == held), key

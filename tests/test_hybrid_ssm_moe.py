@@ -336,25 +336,33 @@ def _dense_sum(experts, x, picked, weights, first):
 def test_both_expert_forms_match_the_sum_over_every_held_expert(
         gated, capacity):
     """relu squared (two grouped products) and gated SiLU (three) through
-    the one sorted, fixed-capacity path, values and gradients; capacity 16
-    forces the branch that computes every pick."""
+    the one sorted, fixed-capacity path, values and gradients (``x``, every
+    expert leaf, the picks' weights); capacity 16 forces the branch that
+    computes every pick, which says so and runs its forward again inside its
+    own backward: no cell takes it, so this comparison is what holds it."""
     p = _share(_expert_layer_params(jax.random.PRNGKey(0), gated=gated), 8, 8)
     x = jax.random.normal(jax.random.PRNGKey(1), (40, 16))
     picked, weights = route_sigmoid_topk(
         p["router"], p["router_bias"], x, 6, 2.5)
 
-    def routed(experts, x):
+    def routed(experts, x, weights):
         return held_experts_ffn(
             experts, x, picked, weights, first=8, capacity=capacity)
 
-    (out, counters), pullback = jax.vjp(routed, p["experts"], x)
-    want, want_pullback = jax.vjp(
-        lambda e, x: _dense_sum(e, x, picked, weights, 8), p["experts"], x)
+    with jax.default_matmul_precision("highest"):
+        (out, counters), pullback = jax.vjp(
+            routed, p["experts"], x, weights)
+        want, want_pullback = jax.vjp(
+            lambda e, x, w: _dense_sum(e, x, picked, w, 8),
+            p["experts"], x, weights)
+        cotangent = jax.random.normal(jax.random.PRNGKey(2), out.shape)
+        got_grads = pullback(
+            (cotangent, jax.tree.map(jnp.zeros_like, counters)))
     assert float(counters["picks_dropped"]) == 0
     assert float(counters["rows_sum"] + counters["picks_absent"]) == 40 * 6
+    assert 16 < float(counters["rows_sum"])
+    assert float(counters["overflows"]) == float(capacity == 16)
     np.testing.assert_allclose(out, want, atol=3e-5)
-    cotangent = jax.random.normal(jax.random.PRNGKey(2), out.shape)
-    got_grads = pullback((cotangent, jax.tree.map(jnp.zeros_like, counters)))
     assert set(got_grads[0]) == ({"w_up", "w_down", "w_gate"} if gated
                                  else {"w_up", "w_down"})
     assert _worst(got_grads, want_pullback(cotangent)) < 3e-5
@@ -537,4 +545,5 @@ def test_trainer_learns_and_notes_the_counters_on_the_fetch_it_makes():
         assert attrs["moe_picks_dropped"] == 0
         assert (attrs["moe_rows_sum"] + attrs["moe_picks_absent"]
                 == steps * picks)
+        assert attrs["moe_overflows"] == 0
         assert attrs["moe_rows_max"] >= attrs["moe_rows_sum"] / (2 * 4)

@@ -318,6 +318,35 @@ def test_loss_and_every_gradient_match_the_plain_reference(
     assert model.apply(params, tokens[:, :-1]).shape == (2, 32, 50)
 
 
+@pytest.mark.parametrize("capacity_factor,overflows", [(1e-9, 4), (100., 0)])
+def test_a_layer_past_its_capacity_is_counted_and_differentiates_the_same(
+        capacity_factor, overflows):
+    """A router bias that sends every token's four picks to the four held
+    experts: 256 held picks a layer against the least capacity, 128 rows
+    (and against one that holds every pick, where there is no branch).
+    Past it each expert layer computes every pick and says so
+    (``moe_overflows``: layers a step), and that branch's backward, its own
+    forward run again under the part's ``jax.checkpoint``, gives the
+    reference's gradients like the other."""
+    model = HybridSsmMoeLM(**TINY, impl="dense", remat=True,
+                           capacity_factor=capacity_factor)
+    params = model.init(jax.random.PRNGKey(0))
+    bias = jnp.zeros(16).at[:4].set(10.0)
+    for kind, layer in zip(PATTERN, params["layers"]):
+        if kind == "E":
+            layer["mixer"]["router_bias"] = bias
+    tokens = _tokens()
+    (loss, stats), grads = jax.jit(jax.value_and_grad(
+        model.loss_and_stats, has_aux=True))(params, tokens)
+    want_loss, want = jax.jit(jax.value_and_grad(_reference_loss()))(
+        params, (tokens, None))
+    assert float(stats["moe_overflows"]) == overflows
+    assert float(stats["moe_picks_dropped"]) == 0
+    assert float(stats["moe_rows_sum"]) == 4 * 2 * 32 * 4
+    assert abs(float(loss) - float(want_loss)) < 1e-5 * float(want_loss)
+    assert _worst(grads, want) < 3e-5
+
+
 def test_a_layer_is_two_parts_each_with_its_own_norm_and_the_head_is_tied():
     model = HybridSsmMoeLM(**TINY)
     params = model.init(jax.random.PRNGKey(0))
@@ -479,6 +508,7 @@ def test_trainer_learns_and_notes_the_counters_on_the_fetch_it_makes():
         assert attrs["moe_picks_dropped"] == 0
         assert (attrs["moe_rows_sum"] + attrs["moe_picks_absent"]
                 == steps * picks)
+        assert attrs["moe_overflows"] == 0
 
 
 @pytest.mark.parametrize("cell,picks,expert_layers", [
@@ -517,22 +547,23 @@ def test_routing_check_script_reads_a_model_built_from_a_pattern(
 # adds beside them may not move an operation of the programs the accepted
 # configurations lower to (the order of operations in ``route_sigmoid_topk``
 # is part of the text).  A PR that means to change them records the new
-# text's hash here and says so.  Taken on PR 35's tree, which gave the three
-# programs' head loss its hand-written backward (``decoder_common.head_nll``);
-# the two older configurations' had stood since d3bb465 (PR 33).
+# text's hash here and says so.  Taken on PR 37's tree, which put the expert
+# layer's every-pick branch under ``jax.checkpoint`` (``ops/moe.py``): against
+# PR 35's texts the branches of each layer's ``cond`` lose the zeros written
+# in that branch's residuals' places and gain the barrier on its inputs.
 PARENT_LOWERED = {
     ("joyai_llm_flash_1of16", "dense"):
-        "f3799bc24a569c15c5d9339d89238ea7a3efaef7d98fd4cee747334e8af3f57f",
+        "c1ab762b83c7dbc7f52a9a22985e19f164ab3fa0498de422bcc010b222a322d4",
     ("joyai_llm_flash_1of16", "flash"):
-        "7fdcf36ff841386b9e46db616f900b82f21b0fc6080417f9bdd7fde2f709842b",
+        "ab858f1284bc47f44bd7d7e378dad0b2b044cdacd5e5074c5852e829cabc17eb",
     ("nemotron3_nano_30b_a3b_1of16", "dense"):
-        "7d45cd6170e9f4c7318a020470c2bca4ab24151dba04519bc71f9bd798ac2ae6",
+        "a66d00a5585080614bfc704799df9fac623f4fa5e0ddb8fcc1550f4110ebd7c2",
     ("nemotron3_nano_30b_a3b_1of16", "flash"):
-        "9f47c7f32cfbf7a30abb542a908c725482431b166e88e0cce5a363e2e035b549",
+        "b8e4f5993c8986e01a1199f355b7cab4a8b1c5d3dcc08a974569e77ac7e2efc5",
     ("lfm2_24b_a2b_1of8", "dense"):
-        "ec10fa5d15b714b00d08f4f6e749edc08eb33c533759a35317f3f289322c50de",
+        "0c3abde621be59bd36af4dbd19f14ccefe834993dd23d03d0eeb4942130e7b12",
     ("lfm2_24b_a2b_1of8", "flash"):
-        "f7834f1d40f12b0b6b6fe2fb7bcfef0d40e577d643dd99af3ab5ee163db1f0bd",
+        "0a3b4904a3768da0249c7293659243b681ae62a19a49f0afca5a54cb5109b871",
 }
 
 

@@ -77,6 +77,33 @@ def test_loss_and_gradients_match_the_plain_reference(impl, remat, first):
     assert float(stats["moe_rows_sum"] + stats["moe_picks_absent"]) == picks
 
 
+@pytest.mark.parametrize("capacity_factor,overflows", [(1e-9, 3), (4.0, 0)])
+def test_a_layer_past_its_capacity_is_counted_and_differentiates_the_same(
+        capacity_factor, overflows):
+    """A router bias that sends every token to all four held experts: 256
+    held picks a layer, which the default capacity holds exactly and the
+    least one (128 rows) does not.  Past it each expert layer computes
+    every pick and says so (``moe_overflows``: layers a step), and that
+    branch's backward, its own forward run again under the block's
+    ``jax.checkpoint``, gives the reference's gradients like the other."""
+    model = MlaMoeLM(**TINY, impl="dense", remat=True,
+                     capacity_factor=capacity_factor)
+    params = model.init(jax.random.PRNGKey(0))
+    bias = jnp.zeros(32).at[:4].set(10.0)
+    for block in (*params["layers"][1:], params["mtp"]["block"]):
+        block["ffn"]["router_bias"] = bias
+    tokens = _tokens(batch=4)
+    (loss, stats), grads = jax.jit(jax.value_and_grad(
+        model.loss_and_stats, has_aux=True))(params, tokens)
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda p, b: REFERENCE.lm_loss(p, b, 0)))(params, (tokens, None))
+    assert float(stats["moe_overflows"]) == overflows
+    assert float(stats["moe_picks_dropped"]) == 0
+    assert float(stats["moe_rows_sum"]) == 3 * 4 * 16 * 4
+    assert abs(float(loss) - float(want_loss)) < 1e-5 * float(want_loss)
+    assert _worst(grads, want) < 2e-5
+
+
 def test_prediction_module_adds_its_weighted_loss():
     tokens = _tokens()
     with_mtp = MlaMoeLM(**TINY, impl="dense")
@@ -184,7 +211,9 @@ def test_no_pick_is_dropped_when_every_token_picks_the_same_held_expert(
         capacity):
     """All 40 tokens pick held expert 2 (and seven absent ones): 40 rows
     for one expert, whatever ``capacity`` the layer was compiled for
-    (16 forces the branch that computes every pick)."""
+    (16 forces the branch that computes every pick, which says so and
+    whose backward, its own forward run again, is held to the reference's
+    like the others': ``x``, every expert leaf and the picks' weights)."""
     p = _share(_expert_layer_params(jax.random.PRNGKey(2)), 0, 4)
     x = jnp.abs(jax.random.normal(jax.random.PRNGKey(3), (40, 16))) + 0.1
     favoured = jnp.array([2] + list(range(10, 17)))
@@ -193,22 +222,33 @@ def test_no_pick_is_dropped_when_every_token_picks_the_same_held_expert(
     picked, weights = route_sigmoid_topk(router, p["router_bias"], x, 8, 2.5)
     assert bool(jnp.all(jnp.sort(picked, axis=1) == jnp.sort(favoured)))
 
-    def routed(experts, x):
+    def routed(experts, x, weights):
         return held_experts_ffn(experts, x, picked, weights, first=0,
                                 capacity=capacity)
 
-    (out, counters), pullback = jax.vjp(routed, p["experts"], x)
-    want, want_pullback = jax.vjp(
-        lambda e, x: REFERENCE.expert_layer(
-            {**p, "experts": e}, x, first=0, top_k=8, scale=2.5,
-            shared=False), p["experts"], x)
+    with jax.default_matmul_precision("highest"):
+        (out, counters), pullback = jax.vjp(
+            routed, p["experts"], x, weights)
+        want, want_pullback = jax.vjp(
+            lambda e, x: REFERENCE.expert_layer(
+                {**p, "experts": e}, x, first=0, top_k=8, scale=2.5,
+                shared=False), p["experts"], x)
+        cotangent = jax.random.normal(jax.random.PRNGKey(4), out.shape)
+        *got_grads, got_weights = pullback(
+            (cotangent, jax.tree.map(jnp.zeros_like, counters)))
+        want_grads = want_pullback(cotangent)
+        # a pick's weight scales its expert's output: expert 2's for the
+        # one held pick of a token, nothing for the seven absent ones
+        favoured_out = REFERENCE.gated_mlp(
+            *(p["experts"][k][2] for k in ("w_gate", "w_up", "w_down")), x)
     assert {k: float(v) for k, v in counters.items()} == {
         "rows_max": 40.0, "rows_sum": 40.0, "picks_absent": 280.0,
-        "picks_dropped": 0.0}
+        "picks_dropped": 0.0, "overflows": float(capacity == 16)}
     np.testing.assert_allclose(out, want, atol=2e-5)
-    cotangent = jax.random.normal(jax.random.PRNGKey(4), out.shape)
-    got_grads = pullback((cotangent, jax.tree.map(jnp.zeros_like, counters)))
-    assert _worst(got_grads, want_pullback(cotangent)) < 2e-5
+    assert _worst(tuple(got_grads), want_grads) < 2e-5
+    want_weights = jnp.where(
+        picked == 2, jnp.sum(cotangent * favoured_out, axis=1)[:, None], 0)
+    assert _worst(got_weights, want_weights) < 2e-5
 
 
 def test_routing_counters_against_a_hand_count():
@@ -223,7 +263,7 @@ def test_routing_counters_against_a_hand_count():
     # expert 2: tokens 0, 1, 3, 5; expert 3: tokens 1, 4; expert 4: token 3
     assert {k: float(v) for k, v in counters.items()} == {
         "rows_max": 4.0, "rows_sum": 7.0, "picks_absent": 5.0,
-        "picks_dropped": 0.0}
+        "picks_dropped": 0.0, "overflows": 0.0}
     assert float(jnp.max(jnp.abs(out[2]))) == 0  # token 2 picked none here
     by_hand = 0.5 * (
         REFERENCE.gated_mlp(*(experts[k][0] for k in
@@ -447,6 +487,8 @@ def test_trainer_learns_and_notes_the_counters_on_the_fetch_it_makes():
         assert attrs["moe_picks_dropped"] == 0
         assert (attrs["moe_rows_sum"] + attrs["moe_picks_absent"]
                 == steps * picks)
+        # fresh weights at four times the uniform share: every layer fits
+        assert attrs["moe_overflows"] == 0
         assert attrs["moe_rows_max"] >= attrs["moe_rows_sum"] / (2 * 4)
 
 

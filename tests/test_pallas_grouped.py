@@ -261,41 +261,142 @@ def _experts(key, count, d, f, gated):
     return experts
 
 
+def _every_expert_sum(experts, x, picked, weights, first):
+    """The plain reference: every held expert over every token, weighted
+    by the picks that fall on it (none for most)."""
+    count = experts["w_up"].shape[0]
+    select = jnp.einsum(
+        "nk,nke->ne", weights,
+        jax.nn.one_hot(picked - first, count, dtype=x.dtype))
+    up = jnp.einsum("nd,edf->nef", x, experts["w_up"])
+    if "w_gate" in experts:
+        hidden = jax.nn.silu(
+            jnp.einsum("nd,edf->nef", x, experts["w_gate"])) * up
+    else:
+        hidden = jnp.square(jax.nn.relu(up))
+    return jnp.einsum(
+        "ne,nef,efd->nd", select, hidden, experts["w_down"])
+
+
+def _layer_inputs(gated):
+    experts = _experts(jax.random.PRNGKey(0), 4, 24, 40, gated)
+    x = jax.random.normal(jax.random.PRNGKey(1), (40, 24))
+    router = jax.random.normal(jax.random.PRNGKey(2), (24, 16))
+    picked, weights = route_sigmoid_topk(router, jnp.zeros((16,)), x, 3, 2.5)
+    return experts, x, picked, weights
+
+
 @pytest.mark.parametrize("capacity", [16, 64, 128],
                          ids=["every_pick", "fits", "one_path"])
 @pytest.mark.parametrize("gated", [True, False], ids=["gated", "relu2"])
 def test_held_experts_ffn_gives_one_answer_through_both_implementations(
         gated, capacity):
-    """``y``, the gradients of the experts and of ``x`` and the four
-    counters: in the branch that fits ``capacity`` (64: its spare rows
-    joined to the last group), in the one that computes every pick (16:
-    XLA's kernel whatever ``impl`` says) and where ``capacity`` holds every
-    pick and there is one path (128: the absent picks are rows of no group,
-    which the kernels write as zeros)."""
-    experts = _experts(jax.random.PRNGKey(0), 4, 24, 40, gated)
-    x = jax.random.normal(jax.random.PRNGKey(1), (40, 24))
-    router = jax.random.normal(jax.random.PRNGKey(2), (24, 16))
-    picked, weights = route_sigmoid_topk(router, jnp.zeros((16,)), x, 3, 2.5)
+    """``y``, the gradients of the experts, of ``x`` and of the picks'
+    weights, and the five counters: in the branch that fits ``capacity``
+    (64: its spare rows joined to the last group), in the one that computes
+    every pick (16: XLA's kernel whatever ``impl`` says, its forward run
+    again inside its own backward, which no cell ever runs: only the
+    comparison with the plain reference here holds it) and where
+    ``capacity`` holds every pick and there is one path (128: the absent
+    picks are rows of no group, which the kernels write as zeros)."""
+    experts, x, picked, weights = _layer_inputs(gated)
     cotangent = jax.random.normal(jax.random.PRNGKey(3), x.shape)
 
     def run(impl):
-        def routed(experts, x):
+        def routed(experts, x, weights):
             return held_experts_ffn(experts, x, picked, weights, first=4,
                                     capacity=capacity, impl=impl)
-        (y, counters), pullback = jax.vjp(routed, experts, x)
+        (y, counters), pullback = jax.vjp(routed, experts, x, weights)
         return y, counters, pullback(
             (cotangent, jax.tree.map(jnp.zeros_like, counters))), routed
 
     with jax.default_matmul_precision("highest"):
         y, counters, grads, routed = run("flash")
         want_y, want_counters, want_grads, _ = run("dense")
-        kernels = str(jax.make_jaxpr(routed)(experts, x)).count("moe_gmm")
+        kernels = str(jax.make_jaxpr(routed)(
+            experts, x, weights)).count("moe_gmm")
+        plain_y, plain_pullback = jax.vjp(
+            lambda e, x, w: _every_expert_sum(e, x, picked, w, 4),
+            experts, x, weights)
+        plain_grads = plain_pullback(cotangent)
     assert ({k: float(v) for k, v in counters.items()}
             == {k: float(v) for k, v in want_counters.items()})
     assert float(counters["picks_dropped"]) == 0
     assert 16 < float(counters["rows_sum"]) <= 64
+    # the layer says when it left the fixed-capacity path
+    assert float(counters["overflows"]) == (1.0 if capacity == 16 else 0.0)
     # this repo's kernels are in the program: two or three products
     assert kernels == (3 if gated else 2)
     np.testing.assert_allclose(y, want_y, atol=2e-5)
-    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+    np.testing.assert_allclose(want_y, plain_y, atol=2e-5)
+    assert (jax.tree.structure(grads) == jax.tree.structure(want_grads)
+            == jax.tree.structure(plain_grads))
+    for got, want, plain in zip(*map(
+            jax.tree.leaves, (grads, want_grads, plain_grads))):
         np.testing.assert_allclose(got, want, atol=3e-5)
+        np.testing.assert_allclose(want, plain, atol=3e-5)
+
+
+def _conds(jaxpr, found):
+    """Every ``cond`` of the program, the kernels' own bodies left out."""
+    from pytorch_distributed_rnn_tpu.lint.jaxpr_pass import _subjaxprs
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            found.append(eqn)
+        if eqn.primitive.name != "pallas_call":
+            for sub in _subjaxprs(eqn):
+                _conds(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "saved"])
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+def test_the_taken_branch_writes_no_placeholder_of_every_pick_s_rows(
+        impl, remat):
+    """A ``cond``'s residuals are the union of both branches', and the
+    branch that runs writes zeros in the other's places.  The branch that
+    computes every pick (``N * k`` = 120 rows here, capacity 64) saves its
+    inputs alone, so no output of any differentiated ``cond`` is a matrix
+    of 120 rows: under ``jax.checkpoint`` (the cells' ``--remat``: the
+    recomputed forward makes the residuals) and without (the first forward
+    does).  What stays are the two vectors both branches index, the sort's
+    token numbers and the picks' weights."""
+    experts, x, picked, weights = _layer_inputs(gated=True)
+
+    def loss(experts, x, weights):
+        y, _ = held_experts_ffn(experts, x, picked, weights, first=4,
+                                capacity=64, impl=impl)
+        return jnp.sum(jnp.square(y))
+
+    grad = jax.grad(jax.checkpoint(loss) if remat else loss, (0, 1, 2))
+    conds = _conds(jax.make_jaxpr(grad)(experts, x, weights).jaxpr, [])
+    outs = [v.aval for eqn in conds for v in eqn.outvars]
+    # the forward with its residuals and the backward, and under remat the
+    # first forward too
+    assert len(conds) == (3 if remat else 2)
+    assert any(a.shape[:1] == (64,) and a.ndim == 2 for a in outs)
+    assert [a for a in outs if a.shape[:1] == (120,) and a.ndim > 1] == []
+    assert sorted(str(a.dtype) for a in outs if a.shape == (120,)) == [
+        "float32", "float32", "int32"]
+    if not remat:
+        return
+    # residuals of one type share a place in the order each branch lists
+    # them, and the experts' weights are residuals of both branches
+    # (``w_gate`` and ``w_up`` of one type): on the cells' path each leaves
+    # both branches in the same place, so that XLA hands the array through
+    # the ``conditional`` and copies nothing (PERF.md, PR 37).  Without the
+    # outer ``jax.checkpoint`` the taken branch lists them the other way
+    # round; only tests differentiate the layer so
+    forward = max(conds, key=lambda eqn: len(eqn.outvars))
+    forwarded = []
+    for branch in forward.params["branches"]:
+        place = {v: i for i, v in enumerate(branch.jaxpr.invars)}
+        forwarded.append([place.get(v) for v in branch.jaxpr.outvars])
+    weights_out = [i for i, v in enumerate(forward.outvars)
+                   if v.aval.ndim == 3]
+    assert len(weights_out) == 3
+    for every_pick, taken in zip(*forwarded):
+        assert every_pick is None or taken is None or every_pick == taken
+    assert all(forwarded[0][i] is not None and forwarded[1][i] is not None
+               for i in weights_out)
