@@ -199,7 +199,9 @@ def expert_layer(model, p, x):
 def moe_stats(counters) -> dict:
     """The expert layers' routing counters, summed over layers
     (``moe_rows_max``: the busiest held expert of any layer;
-    ``moe_overflows``: the layers that computed every pick)."""
+    ``moe_overflows``: the layers that computed every pick;
+    ``moe_rows_compiled``: the rows the products that ran are compiled
+    for, of which ``moe_rows_sum`` held picks)."""
     if not counters:
         return {}
     return dict(
@@ -209,6 +211,7 @@ def moe_stats(counters) -> dict:
         moe_picks_absent=sum(c["picks_absent"] for c in counters),
         moe_picks_dropped=sum(c["picks_dropped"] for c in counters),
         moe_overflows=sum(c["overflows"] for c in counters),
+        moe_rows_compiled=sum(c["rows_compiled"] for c in counters),
     )
 
 

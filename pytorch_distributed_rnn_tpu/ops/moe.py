@@ -365,32 +365,87 @@ def expert_mlp(p, x):
     return jnp.square(jax.nn.relu(x @ p["w_up"])) @ p["w_down"]
 
 
-def _expert_rows(experts, rows, sizes, valid, impl):
-    """:func:`expert_mlp` of rows sorted by expert: grouped products, three
-    for the gated form and two for the other, at the ambient matmul
-    precision.  ``impl`` ``flash`` (the TPU) runs them through this repo's
-    kernels (``ops/pallas_grouped.py:grouped_matmul``), which write zeros
-    in the rows past the last group; ``dense`` through
-    ``jax.lax.ragged_dot``, where what those rows hold is not defined, so
-    they are zeroed (``valid``) wherever they could reach a result or a
-    gradient.  The rows themselves are zeroed for both: the spare rows of
-    a padded last group are multiplied like any other."""
-    keep = valid[:, None]
-    rows = jnp.where(keep, rows, 0)
+def _first_rows(x, held):
+    """``x``'s first ``held`` rows, the rest selected to zeros."""
+    return jnp.where(jnp.arange(x.shape[0])[:, None] < held, x, 0)
+
+
+@jax.custom_vjp
+def _kept_rows(x, held):
+    """:func:`_first_rows`, and its cotangent likewise.  The backward
+    keeps the count: a plain select's keeps its predicate as wide as
+    ``x``, which a ``lax.cond`` branch that makes the residuals writes as
+    one more array of its rows a layer."""
+    return _first_rows(x, held)
+
+
+def _kept_rows_fwd(x, held):
+    return _first_rows(x, held), held
+
+
+def _kept_rows_bwd(held, d_x):
+    return _first_rows(d_x, held), None
+
+
+_kept_rows.defvjp(_kept_rows_fwd, _kept_rows_bwd)
+
+
+@jax.custom_vjp
+def _weighted_rows(out, weight, held):
+    """``out (M, D)`` times ``weight (M,)`` in the first ``held`` rows,
+    zeros past them; neither gradient takes anything from those rows,
+    whatever ``out`` holds there.  The backward keeps ``out`` itself, the
+    product's own array, where a select before the product would keep the
+    selected copy (and its predicate): more arrays of the branch's rows."""
+    return _first_rows(out * weight[:, None].astype(out.dtype), held)
+
+
+def _weighted_rows_fwd(out, weight, held):
+    return _weighted_rows(out, weight, held), (out, weight, held)
+
+
+def _weighted_rows_bwd(residuals, d_y):
+    out, weight, held = residuals
+    d_y = _first_rows(d_y, held)
+    return (d_y * weight[:, None].astype(out.dtype),
+            jnp.sum(_first_rows(d_y * out, held), axis=-1).astype(
+                weight.dtype), None)
+
+
+_weighted_rows.defvjp(_weighted_rows_fwd, _weighted_rows_bwd)
+
+
+def _expert_rows(experts, rows, weight, sizes, held, impl):
+    """:func:`expert_mlp` of rows sorted by expert, each scaled by its
+    pick's ``weight``: grouped products, three for the gated form and two
+    for the other, at the ambient matmul precision.  ``impl`` ``flash``
+    (the TPU) runs them through this repo's kernels
+    (``ops/pallas_grouped.py:grouped_matmul``), ``dense`` through
+    ``jax.lax.ragged_dot``.  The groups hold the first ``held`` rows.
+    Under both, what a product gives in the rows past them is not defined,
+    and one mask keeps it from every result: the rows themselves, the
+    hidden rows (:func:`_kept_rows`) and the down product's
+    (:func:`_weighted_rows`) are selected to zeros in the elementwise
+    expressions that make and consume them.  In the backward pass the
+    selects zero those rows' cotangents; what the tail's cotangents hold
+    past that (``0 * NaN`` may be among it) reaches no row of a group,
+    since the products keep rows apart and the per-group product selects a
+    shared tile's tail rows away."""
+    rows = _kept_rows(rows, held)
     if impl == "flash":
         # here and not at the top: the module pulls jax.experimental.pallas
         from pytorch_distributed_rnn_tpu.ops.pallas_grouped import (
             grouped_matmul as product,
         )
     else:
-        def product(lhs, weights, sizes):
-            return jnp.where(keep, jax.lax.ragged_dot(lhs, weights, sizes), 0)
+        product = jax.lax.ragged_dot
     up = product(rows, experts["w_up"], sizes)
     if "w_gate" in experts:
         hidden = jax.nn.silu(product(rows, experts["w_gate"], sizes)) * up
     else:
         hidden = jnp.square(jax.nn.relu(up))
-    return product(hidden, experts["w_down"], sizes)
+    out = product(_kept_rows(hidden, held), experts["w_down"], sizes)
+    return _weighted_rows(out, weight, held)
 
 
 def held_experts_ffn(experts, x, picked, weights, *, first: int,
@@ -412,12 +467,13 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
     :func:`_expert_rows`; both implementations get the same ``sizes`` and
     the same ``capacity``; the branch below that computes every pick is
     ``dense`` whatever ``impl`` says).  Shapes are
-    static, so the rows computed are ``capacity`` (the caller's guess:
-    some multiple of what a uniform router sends here) while ``total``
-    fits - ALWAYS ``capacity`` rows of work, the spare ones zeros, so that
-    the time is the same for every routing that fits - and the held ones
-    among ALL ``N * k`` picks when it does not: a ``lax.cond`` between two
-    programs of the same mathematics.  Differentiated, the every-pick side
+    static, so the products are compiled for ``capacity`` rows (the
+    caller's guess: some multiple of what a uniform router sends here)
+    while ``total`` fits, and compute the ``total`` held ones alone (the
+    spare rows are in no group: the kernels neither read nor write them,
+    so the time follows the routing), and for ALL ``N * k`` picks when it
+    does not: a ``lax.cond`` between two programs of the same
+    mathematics.  Differentiated, the every-pick side
     saves its inputs alone (``x``, the sort's token numbers and weights,
     the group ends, ``experts``: arrays made outside the ``cond``) and
     runs its forward again inside its backward, so the side that fits
@@ -428,7 +484,10 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
     experts received), ``picks_absent``, ``picks_dropped`` (held picks
     that were not computed: 0 by construction, counted from the group
     sizes the products really ran with), ``overflows`` (1 where the held
-    picks did not fit ``capacity`` and every pick was computed)."""
+    picks did not fit ``capacity`` and every pick was computed),
+    ``rows_compiled`` (the rows the branch that ran is compiled for:
+    ``capacity``, or ``N * k`` where the picks did not fit or there is one
+    path)."""
     count = experts["w_up"].shape[0]
     n, k = picked.shape
     num_picks = n * k
@@ -445,34 +504,27 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
         weight_of = weights.reshape(-1)[order]
         ends = jnp.cumsum(rows_per_expert)
 
-        def compute(rows: int, pad: bool, impl: str = impl, experts=experts):
+        def compute(rows: int, impl: str = impl, experts=experts):
             tokens = token_of[:rows]
             # group sizes as far as `rows` reaches (all of them whenever
-            # this branch is the one taken)
+            # this branch is the one taken); the rows past the last held
+            # pick are in no group
             clipped = jnp.minimum(ends, rows)
             sizes = jnp.diff(clipped, prepend=0)
-            valid = jnp.arange(rows) < clipped[-1]
-            if pad:
-                # the rows past the last held pick (zeroed, so they add
-                # nothing anywhere) join the last group: the products then
-                # do the same work whatever the router did, and a step's
-                # time does not follow the seed (PERF.md, PR 28)
-                sizes = sizes.at[-1].add(rows - clipped[-1])
-            out = _expert_rows(experts, x[tokens], sizes, valid, impl)
-            out = out * weight_of[:rows, None].astype(out.dtype)
+            out = _expert_rows(experts, x[tokens], weight_of[:rows], sizes,
+                               clipped[-1], impl)
             y = jnp.zeros_like(x).at[tokens].add(out)
             return y, clipped[-1]
 
         if capacity >= num_picks:
-            y, computed = compute(num_picks, pad=False)
+            y, computed = compute(num_picks)
         else:
             # the branch that computes every pick is the rare one and
             # stays on XLA's kernel: a second set of this repo's kernels at
             # its row count doubles what a start traces, lowers and loads
             # (+1.9 s of the hybrid cell's 22 s warm set-up: PERF.md, PR 33)
             def every_pick(experts):
-                return compute(
-                    num_picks, pad=False, impl="dense", experts=experts)
+                return compute(num_picks, impl="dense", experts=experts)
 
             # ... and keeps no residuals of its own.  A `cond` saves the
             # union of its branches' residuals, and the branch that runs
@@ -485,7 +537,7 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
             # are shared by type, in order): XLA then hands them through
             # the `conditional` where it would copy them
             y, computed = jax.lax.cond(
-                total <= capacity, lambda: compute(capacity, pad=True),
+                total <= capacity, lambda: compute(capacity),
                 lambda: jax.checkpoint(every_pick)(experts))
     f32 = jnp.float32
     return y, {
@@ -494,4 +546,6 @@ def held_experts_ffn(experts, x, picked, weights, *, first: int,
         "picks_absent": (num_picks - total).astype(f32),
         "picks_dropped": (total - computed).astype(f32),
         "overflows": (total > capacity).astype(f32),
+        "rows_compiled": jnp.where(
+            total > capacity, num_picks, min(capacity, num_picks)).astype(f32),
     }

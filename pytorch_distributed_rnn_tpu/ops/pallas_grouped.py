@@ -29,10 +29,14 @@ in this repo's idiom):
   boundary falling inside a tile (``M / tm + G`` visits).  A visit past the
   last real one names the blocks already in VMEM (no copy) and computes
   nothing; what is left of it is the grid step's own fixed cost.
-- Rows that lie in no group (``sum(sizes) < M``) are a last group of their
-  own with no weights: ``moe_gmm`` writes them as zeros and multiplies
-  nothing, ``moe_tgmm`` never visits them.  An empty group costs
-  ``moe_gmm`` nothing and ``moe_tgmm`` one visit that stores zeros.
+- Rows that lie in no group (``sum(sizes) < M``, the tail) are no
+  kernel's: no visit reads, multiplies or writes a tile that lies in the
+  tail, and the tail rows of a tile a group shares keep what its block held.
+  What the result holds there is not defined, as in ``ragged_dot``'s
+  contract; the caller masks it.  A visit touches its own rows only, so a
+  tail row's value (NaN included) never reaches a row of a group.  An empty
+  group costs ``moe_gmm`` nothing and ``moe_tgmm`` one visit that stores
+  zeros.
 - The contraction is exact: a block over it is the whole dimension or a
   multiple of 128 that divides it.  A block over a result width may overhang
   (Pallas clips what is written past the edge), so a width like 1,856 =
@@ -86,28 +90,28 @@ def _group_visits(sizes, m: int, tm: int, *, empty_too: bool):
     tiles of ``tm`` (``tm`` divides ``m``): ``(offsets, group_of, tile_of,
     count)``, all int32.
 
-    ``offsets[g]`` to ``offsets[g + 1]`` are group ``g``'s rows.  Without
-    ``empty_too`` (the row-wise products) the rows past the last group are
-    one more group, index ``G``, and an empty group is not visited; with it
-    (the per-group product) those rows are no group and an empty group has
-    one visit, in which its result is zeroed.  ``group_of`` / ``tile_of``
-    have ``m // tm + G`` entries, of which the first ``count`` are real and
-    the rest repeat the last real one."""
+    ``offsets[g]`` to ``offsets[g + 1]`` are group ``g``'s rows; the rows
+    past the last group are in none and no visit lists them.  Without
+    ``empty_too`` (the row-wise products) an empty group is not visited;
+    with it (the per-group product) it has one visit, in which its result
+    is zeroed.  ``group_of`` / ``tile_of`` have ``m // tm + G`` entries, of
+    which the first ``count`` are real and the rest repeat the last real
+    one (where none is, every group is empty: tile 0 of the last group)."""
+    groups = sizes.shape[0]
     ends = jnp.minimum(jnp.cumsum(sizes.astype(jnp.int32)), m)
-    if not empty_too:
-        ends = jnp.concatenate([ends, jnp.full((1,), m, jnp.int32)])
     starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
     tiles = jnp.where(ends == starts, int(empty_too),
                       (ends + tm - 1) // tm - starts // tm)
     upto = jnp.cumsum(tiles)
     count = upto[-1]
-    visit = jnp.minimum(
-        jnp.arange(m // tm + sizes.shape[0], dtype=jnp.int32), count - 1)
-    group_of = jnp.sum(upto[None, :] <= visit[:, None], axis=1,
-                       dtype=jnp.int32)
+    visit = jnp.minimum(jnp.arange(m // tm + groups, dtype=jnp.int32),
+                        jnp.maximum(count - 1, 0))
+    group_of = jnp.minimum(
+        jnp.sum(upto[None, :] <= visit[:, None], axis=1, dtype=jnp.int32),
+        groups - 1)
     tile_of = (starts // tm - (upto - tiles))[group_of] + visit
     offsets = jnp.concatenate([starts, ends[-1:]])
-    return (offsets, group_of, jnp.minimum(tile_of, m // tm - 1),
+    return (offsets, group_of, jnp.clip(tile_of, 0, m // tm - 1),
             count.reshape(1))
 
 
@@ -131,10 +135,11 @@ def _row_mask(first, end, shape):
 
 
 def _gmm_kernel(offsets, group_of, tile_of, count, lhs_ref, rhs_ref,
-                out_ref, *acc, tm, groups, dims, k_tiles):
+                out_ref, *acc, tm, dims, k_tiles):
     """One visit of ``moe_gmm`` / ``moe_gmm_dlhs``: grid (result column
     tiles, visits, contraction tiles).  ``acc`` is there where the
-    contraction has more than one tile."""
+    contraction has more than one tile.  The store keeps the rows of the
+    block that are not the visit's group's."""
     visit, ki = pl.program_id(1), pl.program_id(2)
     group, first, end = _rows_of_visit(offsets, group_of, tile_of, visit, tm)
     live = visit < count[0]
@@ -151,7 +156,7 @@ def _gmm_kernel(offsets, group_of, tile_of, count, lhs_ref, rhs_ref,
                 _row_mask(first, end, out_ref.shape),
                 block.astype(out_ref.dtype), out_ref[...])
 
-    @pl.when(live & (group < groups))
+    @pl.when(live)
     def _():
         product = _mxu_dot(lhs_ref[...], rhs_ref[...], dims)
         if not acc:
@@ -170,11 +175,6 @@ def _gmm_kernel(offsets, group_of, tile_of, count, lhs_ref, rhs_ref,
         @pl.when(ki == k_tiles - 1)
         def _():
             store(acc_ref[...])
-
-    # the rows past the last group: zeros, nothing multiplied
-    @pl.when(live & (group == groups) & (ki == k_tiles - 1))
-    def _():
-        store(jnp.zeros(out_ref.shape, jnp.float32))
 
 
 def _tgmm_kernel(offsets, group_of, tile_of, count, lhs_ref, rhs_ref,
@@ -367,7 +367,7 @@ def _params(semantics, vmem_limit):
 @functools.partial(jax.jit, static_argnames=("transposed", "tiles"))
 def _gmm(lhs, weights, sizes, *, transposed=False, tiles=None):
     """``lhs (M, K) @ weights[g] (K, N)`` or, ``transposed``, ``lhs (M, N)
-    @ weights[g]^T``: the result's rows in no group are zeros.  ``tiles``
+    @ weights[g]^T``: the result's rows in no group are not defined.  ``tiles``
     ``(tm, tk, tn)`` are the picker's unless a test or a sweep gives
     them."""
     m = lhs.shape[0]
@@ -389,16 +389,14 @@ def _gmm(lhs, weights, sizes, *, transposed=False, tiles=None):
         return tile_of[visit], k_of(visit, ki, count)
 
     def rhs_map(ni, visit, ki, offsets, group_of, tile_of, count):
-        group = jnp.minimum(group_of[visit], groups - 1)
-        ki = k_of(visit, ki, count)
+        group, ki = group_of[visit], k_of(visit, ki, count)
         return (group, ni, ki) if transposed else (group, ki, ni)
 
     def out_map(ni, visit, ki, offsets, group_of, tile_of, count):
         return tile_of[visit], ni
 
     out = pl.pallas_call(
-        functools.partial(_gmm_kernel, tm=tm, groups=groups,
-                          k_tiles=k_tiles,
+        functools.partial(_gmm_kernel, tm=tm, k_tiles=k_tiles,
                           dims=_MATMUL_NT if transposed else _MATMUL),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -469,9 +467,11 @@ def _tgmm(lhs, rhs, sizes, *, tiles=None):
 def grouped_matmul(rows, weights, sizes):
     """``jax.lax.ragged_dot(rows, weights, sizes)`` through this module's
     kernels: ``rows (M, K)`` sorted by group, ``weights (G, K, N)``,
-    ``sizes (G,)`` int32 (traced) -> ``(M, N)``.  Rows past the last group
-    give zeros and take no gradient; differentiable in ``rows`` and
-    ``weights``, and the residuals are the three arguments."""
+    ``sizes (G,)`` int32 (traced) -> ``(M, N)``.  As in ``ragged_dot``'s
+    contract the rows past the last group are not defined, in the result
+    and in the gradient of ``rows``: the caller masks them, and ``weights``
+    takes no gradient from them whatever they hold.  Differentiable in
+    ``rows`` and ``weights``; the residuals are the three arguments."""
     return _gmm(rows, weights, sizes)
 
 

@@ -92,6 +92,10 @@ def compile_epoch(cell: dict, device_sharding):
     opt_state = placed(jax.eval_shape(trainer.optimizer.init, params))
     interpret = pallas_attention._interpret, pallas_grouped._interpret
     pallas_attention._interpret = pallas_grouped._interpret = lambda: False
+    # the kernels' launchers are `jax.jit`s, which would serve a trace made
+    # at the same shapes in the other mode earlier in the process: cleared
+    # on the way in and on the way out
+    jax.clear_caches()
     try:
         return jax.jit(trainer._make_epoch_fn(), donate_argnums=(0, 1)).lower(
             params, opt_state, on_chip((windows, seq + 1), jnp.int32),
@@ -99,6 +103,7 @@ def compile_epoch(cell: dict, device_sharding):
             on_chip((steps, args.batch_size), jnp.int32)).compile()
     finally:
         pallas_attention._interpret, pallas_grouped._interpret = interpret
+        jax.clear_caches()
 
 
 def taken_branch_fill(text: str) -> dict:

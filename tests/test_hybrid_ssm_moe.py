@@ -546,4 +546,7 @@ def test_trainer_learns_and_notes_the_counters_on_the_fetch_it_makes():
         assert (attrs["moe_rows_sum"] + attrs["moe_picks_absent"]
                 == steps * picks)
         assert attrs["moe_overflows"] == 0
+        # the capacity (256 rows) holds a layer's 192 picks: one path,
+        # compiled for every pick
+        assert attrs["moe_rows_compiled"] == steps * picks
         assert attrs["moe_rows_max"] >= attrs["moe_rows_sum"] / (2 * 4)

@@ -343,6 +343,8 @@ def test_a_layer_past_its_capacity_is_counted_and_differentiates_the_same(
     assert float(stats["moe_overflows"]) == overflows
     assert float(stats["moe_picks_dropped"]) == 0
     assert float(stats["moe_rows_sum"]) == 4 * 2 * 32 * 4
+    # every pick's rows either way: past the capacity, or on the one path
+    assert float(stats["moe_rows_compiled"]) == 4 * 2 * 32 * 4
     assert abs(float(loss) - float(want_loss)) < 1e-5 * float(want_loss)
     assert _worst(grads, want) < 3e-5
 
@@ -509,6 +511,8 @@ def test_trainer_learns_and_notes_the_counters_on_the_fetch_it_makes():
         assert (attrs["moe_rows_sum"] + attrs["moe_picks_absent"]
                 == steps * picks)
         assert attrs["moe_overflows"] == 0
+        # the capacity holds every pick: one path, compiled for all
+        assert attrs["moe_rows_compiled"] == steps * picks
 
 
 @pytest.mark.parametrize("cell,picks,expert_layers", [
@@ -547,23 +551,26 @@ def test_routing_check_script_reads_a_model_built_from_a_pattern(
 # adds beside them may not move an operation of the programs the accepted
 # configurations lower to (the order of operations in ``route_sigmoid_topk``
 # is part of the text).  A PR that means to change them records the new
-# text's hash here and says so.  Taken on PR 37's tree, which put the expert
-# layer's every-pick branch under ``jax.checkpoint`` (``ops/moe.py``): against
-# PR 35's texts the branches of each layer's ``cond`` lose the zeros written
-# in that branch's residuals' places and gain the barrier on its inputs.
+# text's hash here and says so.  Taken on the tree whose held-expert
+# products leave the rows past the last group to one mask of the first held
+# rows (``ops/moe.py:_expert_rows``): against the texts before, the taken
+# branch of each layer's ``cond`` no longer pads the last group with those
+# rows, the selects on the rows, the hidden rows and the weighted output
+# differentiate by hand, and the every-pick branch selects the hidden rows
+# where it selected each product.
 PARENT_LOWERED = {
     ("joyai_llm_flash_1of16", "dense"):
-        "c1ab762b83c7dbc7f52a9a22985e19f164ab3fa0498de422bcc010b222a322d4",
+        "e05fc1aa12e10aa5ccfc2aa8c5eaf3fdefc2dca7b7dbe47d83a595d984fcf467",
     ("joyai_llm_flash_1of16", "flash"):
-        "ab858f1284bc47f44bd7d7e378dad0b2b044cdacd5e5074c5852e829cabc17eb",
+        "8ef850234c3f654db6a41b6b526ffd87b8209b985faf97b38095b49ec257d1fc",
     ("nemotron3_nano_30b_a3b_1of16", "dense"):
-        "a66d00a5585080614bfc704799df9fac623f4fa5e0ddb8fcc1550f4110ebd7c2",
+        "e15560ec95b0108319b2b638d5676eade61669ffa577fcbfe79ac3301e015966",
     ("nemotron3_nano_30b_a3b_1of16", "flash"):
-        "b8e4f5993c8986e01a1199f355b7cab4a8b1c5d3dcc08a974569e77ac7e2efc5",
+        "a1606abe7cf5de24102f0c65891410f5c1b9d14777c5a1c3480349991520fe75",
     ("lfm2_24b_a2b_1of8", "dense"):
-        "0c3abde621be59bd36af4dbd19f14ccefe834993dd23d03d0eeb4942130e7b12",
+        "da001c0f0a901c14e8217f3c5fa9e4eb38acfacc0429e3072d1cf33b9a0dea9f",
     ("lfm2_24b_a2b_1of8", "flash"):
-        "0a3b4904a3768da0249c7293659243b681ae62a19a49f0afca5a54cb5109b871",
+        "783a2f3307f89f10cf2b5a5d9e503ff56a63be0c6d8f6c6b57d537754c278366",
 }
 
 

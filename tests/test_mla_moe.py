@@ -100,6 +100,10 @@ def test_a_layer_past_its_capacity_is_counted_and_differentiates_the_same(
     assert float(stats["moe_overflows"]) == overflows
     assert float(stats["moe_picks_dropped"]) == 0
     assert float(stats["moe_rows_sum"]) == 3 * 4 * 16 * 4
+    # the rows the products that ran are compiled for: every pick (64
+    # tokens x 8) in a layer past its capacity, else the capacity
+    assert float(stats["moe_rows_compiled"]) == 3 * (
+        64 * 8 if overflows else 256)
     assert abs(float(loss) - float(want_loss)) < 1e-5 * float(want_loss)
     assert _worst(grads, want) < 2e-5
 
@@ -243,7 +247,10 @@ def test_no_pick_is_dropped_when_every_token_picks_the_same_held_expert(
             *(p["experts"][k][2] for k in ("w_gate", "w_up", "w_down")), x)
     assert {k: float(v) for k, v in counters.items()} == {
         "rows_max": 40.0, "rows_sum": 40.0, "picks_absent": 280.0,
-        "picks_dropped": 0.0, "overflows": float(capacity == 16)}
+        "picks_dropped": 0.0, "overflows": float(capacity == 16),
+        # the products that ran: compiled for the capacity where it is
+        # the branch taken, else for all 40 x 8 picks
+        "rows_compiled": float(64 if capacity == 64 else 320)}
     np.testing.assert_allclose(out, want, atol=2e-5)
     assert _worst(tuple(got_grads), want_grads) < 2e-5
     want_weights = jnp.where(
@@ -263,7 +270,7 @@ def test_routing_counters_against_a_hand_count():
     # expert 2: tokens 0, 1, 3, 5; expert 3: tokens 1, 4; expert 4: token 3
     assert {k: float(v) for k, v in counters.items()} == {
         "rows_max": 4.0, "rows_sum": 7.0, "picks_absent": 5.0,
-        "picks_dropped": 0.0, "overflows": 0.0}
+        "picks_dropped": 0.0, "overflows": 0.0, "rows_compiled": 8.0}
     assert float(jnp.max(jnp.abs(out[2]))) == 0  # token 2 picked none here
     by_hand = 0.5 * (
         REFERENCE.gated_mlp(*(experts[k][0] for k in
@@ -489,6 +496,9 @@ def test_trainer_learns_and_notes_the_counters_on_the_fetch_it_makes():
                 == steps * picks)
         # fresh weights at four times the uniform share: every layer fits
         assert attrs["moe_overflows"] == 0
+        # ... the 256 rows the products are compiled for
+        assert attrs["moe_rows_compiled"] == steps * 2 * 256
+        assert attrs["moe_rows_sum"] <= attrs["moe_rows_compiled"]
         assert attrs["moe_rows_max"] >= attrs["moe_rows_sum"] / (2 * 4)
 
 

@@ -8,9 +8,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import Literal
 
 from pytorch_distributed_rnn_tpu.ops import pallas_grouped as pg
 from pytorch_distributed_rnn_tpu.ops.moe import (
+    _expert_rows,
     held_experts_ffn,
     route_sigmoid_topk,
 )
@@ -21,8 +23,10 @@ M, K, N = 384, 336, 232
 SIZES = {
     # an empty group, a group of one row, boundaries off the row tile
     "ragged": [100, 0, 1, 130, 53, 100],
-    # rows in no group: 40 rows past the last group read zero
+    # rows in no group: 40 rows past the last group, in a tile it shares
     "short": [100, 0, 1, 130, 53, 60],
+    # a third of the rows in a group: the last tile lies in no group
+    "sparse": [100, 0, 1, 30, 0, 0],
     # every boundary on a tile's edge, the last group empty
     "aligned": [128, 0, 256, 0],
     # one group, and nothing but rows in no group
@@ -65,6 +69,15 @@ def _close(got, want, tolerance):
     assert float(jnp.max(jnp.abs(got - want))) / scale < tolerance
 
 
+def _close_in_groups(got, want, sizes, tolerance):
+    """``_close`` over the rows that lie in a group: past them a row-wise
+    product's result is not defined (``ragged_dot``'s contract)."""
+    rows = int(np.sum(sizes))
+    assert got.shape == want.shape
+    if rows:
+        _close(got[:rows], want[:rows], tolerance)
+
+
 # (tm, tk, tn) by kernel: whole widths, and tiles that overhang the result
 # width (232 in blocks of 128) and split the contraction where it has a
 # divisor of 128 lanes (K 256 below)
@@ -81,9 +94,7 @@ def test_moe_gmm_is_ragged_dot(case, tiling, precision):
     with jax.default_matmul_precision(precision):
         got = pg._gmm(rows, weights, sizes, tiles=(tm, tk or K, tn or N))
         want = _ragged(rows, weights, sizes)
-    _close(got, want, TOLERANCE[precision])
-    # rows in no group read exactly zero
-    assert not np.asarray(got)[sum(SIZES[case]):].any()
+    _close_in_groups(got, want, SIZES[case], TOLERANCE[precision])
 
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
@@ -100,8 +111,7 @@ def test_moe_gmm_dlhs_is_ragged_dot_with_the_weights_transposed(
         got = pg._gmm(d_out, weights, sizes, transposed=True,
                       tiles=(tm, N, tn or K))
         want = _ragged(d_out, weights.transpose(0, 2, 1), sizes)
-    _close(got, want, TOLERANCE[precision])
-    assert not np.asarray(got)[sum(SIZES[case]):].any()
+    _close_in_groups(got, want, SIZES[case], TOLERANCE[precision])
 
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
@@ -127,12 +137,14 @@ def test_a_split_contraction_accumulates_in_f32(case):
     sizes = jnp.array(SIZES[case], jnp.int32)
     rows, weights, _ = _operands(len(SIZES[case]), k=256)
     with jax.default_matmul_precision("highest"):
-        _close(pg._gmm(rows, weights, sizes, tiles=(128, 128, 128)),
-               _ragged(rows, weights, sizes), 2e-5)
+        _close_in_groups(
+            pg._gmm(rows, weights, sizes, tiles=(128, 128, 128)),
+            _ragged(rows, weights, sizes), SIZES[case], 2e-5)
         weights_t = weights.transpose(0, 2, 1)  # (G, N, 256): contract 256
-        _close(pg._gmm(rows, weights_t, sizes, transposed=True,
-                       tiles=(128, 128, 128)),
-               _ragged(rows, weights, sizes), 2e-5)
+        _close_in_groups(
+            pg._gmm(rows, weights_t, sizes, transposed=True,
+                    tiles=(128, 128, 128)),
+            _ragged(rows, weights, sizes), SIZES[case], 2e-5)
 
 
 def test_tiles_that_do_not_divide_are_refused():
@@ -146,12 +158,15 @@ def test_tiles_that_do_not_divide_are_refused():
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
 @pytest.mark.parametrize("shape", [(M, K, N), (30, 24, 40), (300, 24, 40)])
-@pytest.mark.parametrize("case", ["ragged", "short"])
+@pytest.mark.parametrize("case", ["ragged", "short", "sparse"])
 def test_grouped_matmul_and_its_gradients_are_ragged_dot_s(
         case, shape, precision):
     """The picker's own tiles, rows that need padding (30 -> 32, 300 ->
     384), values and both gradients under a cotangent that differs by
-    entry."""
+    entry.  The loss reads every row, so the cotangent of the rows past
+    the last group is whatever the kernels left there (NaN in interpret
+    mode): the weights' gradient takes nothing from it, and the rows'
+    gradient is compared in the groups."""
     m, k, n = shape
     sizes = np.array(SIZES[case]) * m // M
     sizes = jnp.array(sizes, jnp.int32)
@@ -161,14 +176,15 @@ def test_grouped_matmul_and_its_gradients_are_ragged_dot_s(
         return lambda r, w: jnp.sum(jnp.sin(product(r, w, sizes)))
 
     with jax.default_matmul_precision(precision):
-        _close(pg.grouped_matmul(rows, weights, sizes),
-               _ragged(rows, weights, sizes), TOLERANCE[precision])
+        _close_in_groups(pg.grouped_matmul(rows, weights, sizes),
+                         _ragged(rows, weights, sizes), sizes,
+                         TOLERANCE[precision])
         got = jax.jit(jax.grad(loss(pg.grouped_matmul), (0, 1)))(
             rows, weights)
         want = jax.grad(loss(_ragged), (0, 1))(rows, weights)
-    for g, w in zip(got, want):
-        _close(g, w, 2.5 * TOLERANCE[precision])
-    assert not np.asarray(got[0])[int(sizes.sum()):].any()
+    _close_in_groups(got[0], want[0], sizes, 2.5 * TOLERANCE[precision])
+    assert np.isfinite(np.asarray(got[1])).all()
+    _close(got[1], want[1], 2.5 * TOLERANCE[precision])
 
 
 def test_the_three_kernels_carry_their_names_and_share_one_trace():
@@ -232,20 +248,51 @@ def test_group_visits_list_every_tile_once_a_group_it_touches():
     sizes = jnp.array([100, 0, 1, 130, 53, 60], jnp.int32)
     offsets, group_of, tile_of, count = pg._group_visits(
         sizes, 384, 128, empty_too=False)
-    # groups 0, 2, 3 | 3, 4 | 4, 5 and the 40 rows of no group (index 6)
-    assert int(count[0]) == 8
-    assert list(np.asarray(group_of)[:8]) == [0, 2, 3, 3, 4, 4, 5, 6]
-    assert list(np.asarray(tile_of)[:8]) == [0, 0, 0, 1, 1, 2, 2, 2]
-    assert list(np.asarray(offsets)) == [0, 100, 100, 101, 231, 284, 344, 384]
+    # groups 0, 2, 3 | 3, 4 | 4, 5; the 40 rows past group 5 are no visit's
+    assert int(count[0]) == 7
+    assert list(np.asarray(group_of)[:7]) == [0, 2, 3, 3, 4, 4, 5]
+    assert list(np.asarray(tile_of)[:7]) == [0, 0, 0, 1, 1, 2, 2]
+    assert list(np.asarray(offsets)) == [0, 100, 100, 101, 231, 284, 344]
     # the spare visits repeat the last real one
-    assert set(np.asarray(group_of)[8:]) == {6}
-    assert set(np.asarray(tile_of)[8:]) == {2}
+    assert set(np.asarray(group_of)[7:]) == {5}
+    assert set(np.asarray(tile_of)[7:]) == {2}
     # the per-group product visits the empty group once and no spare row
     offsets, group_of, tile_of, count = pg._group_visits(
         sizes, 384, 128, empty_too=True)
     assert int(count[0]) == 8
     assert list(np.asarray(group_of)[:8]) == [0, 1, 2, 3, 3, 4, 4, 5]
     assert list(np.asarray(tile_of)[:8]) == [0, 0, 0, 0, 1, 1, 2, 2]
+
+
+@pytest.mark.parametrize("empty_too", [False, True],
+                         ids=["row_wise", "per_group"])
+@pytest.mark.parametrize("tm", [128, 384])
+@pytest.mark.parametrize("case", list(SIZES))
+def test_group_visits_list_no_tile_past_the_last_group(case, tm, empty_too):
+    """The visits are the tiles each group touches, in order, and an empty
+    group once where ``empty_too``: no tile that holds only rows past the
+    last group is visited, and the spare visits repeat the last real one
+    (all of them the last group's tile 0 where no group has a row)."""
+    sizes = SIZES[case]
+    offsets, group_of, tile_of, count = pg._group_visits(
+        jnp.array(sizes, jnp.int32), M, tm, empty_too=empty_too)
+    ends = np.cumsum(sizes)
+    want = []
+    for group, (start, end) in enumerate(zip(ends - sizes, ends)):
+        if end > start:
+            want += [(group, t) for t in range(start // tm,
+                                               (end - 1) // tm + 1)]
+        elif empty_too:
+            want.append((group, min(start // tm, M // tm - 1)))
+    got = list(zip(np.asarray(group_of).tolist(),
+                   np.asarray(tile_of).tolist()))
+    assert int(count[0]) == len(want)
+    assert got[:len(want)] == want
+    assert len(got) == M // tm + len(sizes)
+    assert set(got[len(want):]) <= {want[-1] if want
+                                    else (len(sizes) - 1, 0)}
+    assert all(tile * tm < max(ends[-1], 1) for _, tile in got)
+    assert list(np.asarray(offsets)) == [0, *ends.tolist()]
 
 
 # -- the expert layer through both implementations ----------------------------
@@ -286,19 +333,21 @@ def _layer_inputs(gated):
     return experts, x, picked, weights
 
 
-@pytest.mark.parametrize("capacity", [16, 64, 128],
-                         ids=["every_pick", "fits", "one_path"])
+@pytest.mark.parametrize("capacity", [16, 64, 96, 128],
+                         ids=["every_pick", "fits", "mostly_spare",
+                              "one_path"])
 @pytest.mark.parametrize("gated", [True, False], ids=["gated", "relu2"])
 def test_held_experts_ffn_gives_one_answer_through_both_implementations(
         gated, capacity):
     """``y``, the gradients of the experts, of ``x`` and of the picks'
-    weights, and the five counters: in the branch that fits ``capacity``
-    (64: its spare rows joined to the last group), in the one that computes
-    every pick (16: XLA's kernel whatever ``impl`` says, its forward run
-    again inside its own backward, which no cell ever runs: only the
-    comparison with the plain reference here holds it) and where
-    ``capacity`` holds every pick and there is one path (128: the absent
-    picks are rows of no group, which the kernels write as zeros)."""
+    weights, and the six counters: in the branch that fits ``capacity``
+    (64; 96, where the 23 held picks fill under 30 % of it: its spare rows
+    are in no group, and what the products leave there is masked), in the
+    one that computes every pick (16: XLA's kernel whatever ``impl`` says,
+    its forward run again inside its own backward, which no cell ever
+    runs: only the comparison with the plain reference here holds it) and
+    where ``capacity`` holds every pick and there is one path (128: the
+    absent picks are rows of no group)."""
     experts, x, picked, weights = _layer_inputs(gated)
     cotangent = jax.random.normal(jax.random.PRNGKey(3), x.shape)
 
@@ -323,8 +372,11 @@ def test_held_experts_ffn_gives_one_answer_through_both_implementations(
             == {k: float(v) for k, v in want_counters.items()})
     assert float(counters["picks_dropped"]) == 0
     assert 16 < float(counters["rows_sum"]) <= 64
-    # the layer says when it left the fixed-capacity path
+    # the layer says when it left the fixed-capacity path, and how many
+    # rows the products that ran are compiled for
     assert float(counters["overflows"]) == (1.0 if capacity == 16 else 0.0)
+    assert float(counters["rows_compiled"]) == (
+        capacity if capacity in (64, 96) else 120)
     # this repo's kernels are in the program: two or three products
     assert kernels == (3 if gated else 2)
     np.testing.assert_allclose(y, want_y, atol=2e-5)
@@ -335,6 +387,76 @@ def test_held_experts_ffn_gives_one_answer_through_both_implementations(
             jax.tree.leaves, (grads, want_grads, plain_grads))):
         np.testing.assert_allclose(got, want, atol=3e-5)
         np.testing.assert_allclose(want, plain, atol=3e-5)
+
+
+@jax.custom_vjp
+def _poison_tail(rows, valid):
+    """``rows`` with NaN in every row ``valid`` leaves out, forward and in
+    the cotangent: the worst a product may leave past the last group."""
+    return jnp.where(valid[:, None], rows, jnp.nan)
+
+
+def _poison_fwd(rows, valid):
+    return _poison_tail(rows, valid), valid
+
+
+def _poison_bwd(valid, d_rows):
+    return jnp.where(valid[:, None], d_rows, jnp.nan), None
+
+
+_poison_tail.defvjp(_poison_fwd, _poison_bwd)
+
+
+def _with_nan_tail(product):
+    """``product`` whose rows past the last group read NaN in its result
+    and in the gradient of its rows, which ``ragged_dot``'s contract
+    allows."""
+    def poisoned(rows, weights, sizes):
+        valid = jnp.arange(rows.shape[0]) < jnp.sum(sizes)
+        return _poison_tail(
+            product(_poison_tail(rows, valid), weights, sizes), valid)
+    return poisoned
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "relu2"])
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+def test_a_product_s_nan_tail_reaches_no_result_and_no_gradient(
+        impl, gated, monkeypatch):
+    """``_expert_rows`` over 384 rows of which 151 lie in a group, with
+    each product's tail rows NaN in its result and in its rows' gradient:
+    the one mask keeps them from ``y`` and from every gradient (the
+    experts', the rows' and the picks' weights'), which equal plain
+    ``ragged_dot``'s, and all of it is finite."""
+    sizes = jnp.array([100, 0, 31, 20], jnp.int32)
+    experts = _experts(jax.random.PRNGKey(0), 4, 24, 40, gated)
+    rows = jax.random.normal(jax.random.PRNGKey(1), (M, 24))
+    weight = jax.random.uniform(jax.random.PRNGKey(3), (M,))
+    cotangent = jax.random.normal(jax.random.PRNGKey(2), (M, 24))
+
+    def run(impl):
+        return jax.vjp(
+            lambda e, r, w: _expert_rows(e, r, w, sizes, 151, impl),
+            experts, rows, weight)
+
+    with jax.default_matmul_precision("highest"):
+        want_y, pullback = run("dense")
+        want = pullback(cotangent)
+        monkeypatch.setattr(pg, "grouped_matmul",
+                            _with_nan_tail(pg.grouped_matmul))
+        monkeypatch.setattr(jax.lax, "ragged_dot",
+                            _with_nan_tail(jax.lax.ragged_dot))
+        y, pullback = run(impl)
+        got = pullback(cotangent)
+    assert not np.asarray(want_y)[151:].any()
+    for value in (y, *jax.tree.leaves(got)):
+        assert np.isfinite(np.asarray(value)).all()
+    # the rows past the groups take no gradient
+    for value in (y, *got[1:]):
+        assert not np.asarray(value)[151:].any()
+    np.testing.assert_allclose(y, want_y, atol=2e-5)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, atol=3e-5)
 
 
 def _conds(jaxpr, found):
@@ -392,7 +514,10 @@ def test_the_taken_branch_writes_no_placeholder_of_every_pick_s_rows(
     forwarded = []
     for branch in forward.params["branches"]:
         place = {v: i for i, v in enumerate(branch.jaxpr.invars)}
-        forwarded.append([place.get(v) for v in branch.jaxpr.outvars])
+        # a literal is a placeholder for the other branch's scalar
+        forwarded.append([
+            None if isinstance(v, Literal) else place.get(v)
+            for v in branch.jaxpr.outvars])
     weights_out = [i for i, v in enumerate(forward.outvars)
                    if v.aval.ndim == 3]
     assert len(weights_out) == 3
